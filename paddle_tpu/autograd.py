@@ -27,10 +27,8 @@ import jax.numpy as jnp
 
 _state = threading.local()
 
-try:  # jax >= 0.4.x keeps this in _src; public alias was removed in 0.9
-    from jax._src.core import trace_state_clean as _trace_state_clean
-except ImportError:  # pragma: no cover - future jax relocation
-    _trace_state_clean = None
+# jax 0.9.0 has no public spelling of "is any trace active"
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 
 def in_jax_trace(arrs=()) -> bool:
@@ -40,15 +38,10 @@ def in_jax_trace(arrs=()) -> bool:
     already owns differentiation, and a nested ``jax.vjp`` both bloats the
     jaxpr and breaks ``custom_vjp`` ops (Pallas kernels hit
     ``_pallas_call_jvp_rule`` asserts when a vjp is opened inside another
-    vjp inside ``jax.grad``). Detection is two-tier: the global trace-state
-    flag, plus a Tracer scan of the inputs as a fallback.
+    vjp inside ``jax.grad``). `arrs` is accepted for the callers that
+    pass their inputs; the global trace-state flag decides.
     """
-    if _trace_state_clean is not None:
-        try:
-            return not _trace_state_clean()
-        except Exception:  # pragma: no cover
-            pass
-    return any(isinstance(a, jax.core.Tracer) for a in arrs)
+    return not _trace_state_clean()
 
 
 def is_grad_enabled() -> bool:

@@ -39,8 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..mesh import pvary_compat
-
 from ...nn.layer import Layer
 
 
@@ -54,22 +52,18 @@ def unstack_stage_params(stacked, n_stages: int):
             for i in range(n_stages)]
 
 
+def _varying(x, axis):
+    """Type a scan carry as varying over the manual `axis` (the ticks
+    write per-stage values into it)."""
+    return jax.lax.pcast(x, (axis,), to="varying")
+
+
 def _pipeline_local(stage_params, x, *, stage_fn, n_stages, n_micro,
-                    axis, remat, sharded_params=True):
-    """Runs INSIDE shard_map over `axis`. With sharded_params (new-jax
-    partial-auto path) stage_params leaves are the local [1, ...]
-    shard; on the old-jax full-manual path they arrive REPLICATED
-    ([S_total, ...] everywhere) and each rank dynamically slices its
-    own stage — 0.4.x's partitioner mis-shards a jnp.stack product
-    feeding a manual-region operand (see shard_map_compat), so the
-    stacked tree must not cross the boundary with a sharded spec
-    there. x is the full (pp-replicated) batch."""
+                    axis, remat):
+    """Runs INSIDE shard_map over `axis`: stage_params leaves are the
+    local [1, ...] shard, x is the full (pp-replicated) batch."""
     stage = jax.lax.axis_index(axis)
-    if sharded_params:
-        local = jax.tree_util.tree_map(lambda a: a[0], stage_params)
-    else:
-        local = jax.tree_util.tree_map(
-            lambda a: jnp.take(a, stage, axis=0), stage_params)
+    local = jax.tree_util.tree_map(lambda a: a[0], stage_params)
     mb = x.shape[0] // n_micro
     micro = x.reshape((n_micro, mb) + x.shape[1:])
     f = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -92,8 +86,8 @@ def _pipeline_local(stage_params, x, *, stage_fn, n_stages, n_micro,
         nxt = jax.lax.ppermute(out, axis, fwd_perm) if n_stages > 1 else out
         return (nxt, outbuf), None
 
-    act0 = pvary_compat(jnp.zeros_like(micro[0]), (axis,))
-    outbuf0 = pvary_compat(jnp.zeros_like(micro), (axis,))
+    act0 = _varying(jnp.zeros_like(micro[0]), axis)
+    outbuf0 = _varying(jnp.zeros_like(micro), axis)
     (_, outbuf), _ = jax.lax.scan(tick, (act0, outbuf0),
                                   jnp.arange(n_ticks))
     # replicate the last stage's outputs to every pp rank so downstream
@@ -148,20 +142,12 @@ def pipeline_cost(n_stages: int, n_micro: int, n_virtual: int = 1):
 
 
 def _pipeline_local_interleaved(stage_params, x, *, stage_fn, n_stages,
-                                n_chunks, n_micro, axis, remat,
-                                sharded_params=True):
+                                n_chunks, n_micro, axis, remat):
     """Interleaved virtual-stage schedule; runs INSIDE shard_map over
-    `axis`. With sharded_params, stage_params leaves are the local
-    [v, ...] chunk shards (device s holds global stages c*p + s, c in
-    [0, v)); on the old-jax full-manual path they arrive replicated
-    ([p*v, ...], device-major rows) and each rank slices rows
-    [s*v, (s+1)*v) — see _pipeline_local."""
+    `axis`. stage_params leaves are the local [v, ...] chunk shards
+    (device s holds global stages c*p + s, c in [0, v))."""
     p, v, m = n_stages, n_chunks, n_micro
     s = jax.lax.axis_index(axis)
-    if not sharded_params:
-        stage_params = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, s * v, v, axis=0),
-            stage_params)
     mb = x.shape[0] // m
     micro = x.reshape((m, mb) + x.shape[1:])
     f = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -193,8 +179,8 @@ def _pipeline_local_interleaved(stage_params, x, *, stage_fn, n_stages,
         nxt = jax.lax.ppermute(out, axis, ring) if p > 1 else out
         return (nxt, outbuf), None
 
-    act0 = pvary_compat(jnp.zeros_like(micro[0]), (axis,))
-    outbuf0 = pvary_compat(jnp.zeros_like(micro), (axis,))
+    act0 = _varying(jnp.zeros_like(micro[0]), axis)
+    outbuf0 = _varying(jnp.zeros_like(micro), axis)
     (_, outbuf), _ = jax.lax.scan(tick, (act0, outbuf0),
                                   jnp.arange(n_ticks))
     outbuf = jax.lax.psum(
@@ -244,24 +230,19 @@ def pipeline_apply(mesh, stage_params, x, stage_fn: Callable, *,
         local_fn, local_kw = _pipeline_local, dict(
             stage_fn=stage_fn, n_stages=p, n_micro=n_micro, axis=axis,
             remat=remat)
-    from ..mesh import shard_map_compat
-    # new jax: shard the stacked params over `axis` (each rank holds its
-    # stage rows). old jax (no jax.shard_map): its partitioner
-    # mis-shards a stack built inside the jit when it feeds a manual
-    # region with a sharded spec — pass the stack REPLICATED and let
-    # each rank slice its rows in-body instead (CPU-test path only).
-    sharded_params = hasattr(jax, "shard_map")
-    if sharded_params:
-        param_specs = jax.tree_util.tree_map(
-            lambda a: P(axis, *([None] * (a.ndim - 1))), stage_params)
-    else:
-        param_specs = jax.tree_util.tree_map(lambda a: P(), stage_params)
-    local = functools.partial(local_fn, sharded_params=sharded_params,
-                              **local_kw)
-    fn = shard_map_compat(
-        local, mesh, in_specs=(param_specs, P()), out_specs=P(),
-        manual_axes={axis})
-    return fn(stage_params, x)
+    # each rank holds its stage rows; every other mesh axis stays auto
+    param_specs = jax.tree_util.tree_map(
+        lambda a: P(axis, *([None] * (a.ndim - 1))), stage_params)
+    fn = jax.shard_map(
+        functools.partial(local_fn, **local_kw), mesh=mesh,
+        in_specs=(param_specs, P()), out_specs=P(),
+        axis_names=frozenset({axis}), check_vma=False)
+    # jitted even when called eagerly: jax 0.9.0's eager shard_map with
+    # check_vma=False re-shards outputs over EVERY mesh axis and then
+    # rejects its own out_specs for touching the auto ones ("out_specs
+    # refers to 'dp'"). Under an enclosing jit this inner one inlines.
+    # tpulint: disable-next-line=TRC01
+    return jax.jit(fn)(stage_params, x)
 
 
 class LayerDesc:

@@ -145,11 +145,9 @@ def _spmd(local_fn, mesh, axis):
     sequence-sharded body — this is what lets a dp x sp (or dp x mp x
     sp) train step compose with no extra code."""
     spec = P(None, axis, None, None)
-    from ..mesh import shard_map_compat
-    # manual over `axis` only; dp/mp stay auto for GSPMD
-    return shard_map_compat(
-        local_fn, mesh, in_specs=(spec, spec, spec),
-        out_specs=spec, manual_axes={axis})
+    return jax.shard_map(
+        local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=frozenset({axis}), check_vma=False)
 
 
 def ring_attention_spmd(q, k, v, mesh, *, axis="sp", causal=False,

@@ -16,49 +16,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 _global_mesh: Mesh | None = None
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes=None,
-                     check=False):
-    """jax.shard_map across jax versions. jax >= 0.6 exposes the public
-    `jax.shard_map(..., axis_names=manual, check_vma=...)`; 0.4.x only
-    has `jax.experimental.shard_map.shard_map(..., auto=complement,
-    check_rep=...)`. `manual_axes=None` means fully manual (all mesh
-    axes); otherwise only the named axes are manual and the rest stay
-    auto for GSPMD."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {"check_vma": check}
-        if manual_axes is not None and len(mesh.axis_names) > 1:
-            kw["axis_names"] = frozenset(manual_axes)
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    # 0.4.x's partial-auto shard_map is unreliable: the eager impl
-    # raises NotImplementedError, and the jitted lowering emits a
-    # PartitionId op the SPMD partitioner rejects (or aborts XLA
-    # outright on multi-axis meshes). Lower fully manual instead —
-    # semantics are preserved (axes absent from a spec replicate into
-    # the body); only GSPMD sharding over the non-manual axes INSIDE
-    # the mapped region is lost, and only on old-jax installs (real TPU
-    # deployments run the new-jax branch above).
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
-
-
-def pvary_compat(x, axes):
-    """Mark `x` as varying over manual mesh axes. jax >= 0.6 tracks
-    varying-manual-axes (VMA) types and wants an explicit
-    lax.pcast/pvary; 0.4.x has neither, and with replication checking
-    off (shard_map_compat passes check_rep=False) the annotation is
-    simply unnecessary — identity there."""
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is not None:
-        return pc(x, tuple(axes), to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, tuple(axes))
-    return x
-
-
 def set_mesh(mesh: Mesh):
     global _global_mesh
     _global_mesh = mesh

@@ -59,10 +59,7 @@ def quantized_all_reduce(x, axis_name, block=256):
     the summed array in x's dtype. Payload on the interconnect is int8
     plus one f32 scale per `block` elements (~x4 less than fp32).
     """
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:  # jax 0.4.x has no lax.axis_size — psum of 1 is the idiom
-        n = int(jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     orig_dtype = x.dtype
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)

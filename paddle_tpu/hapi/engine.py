@@ -58,6 +58,12 @@ class Engine:
         self.metrics = metrics or []
         self.amp_dtype = amp_dtype
         self.mesh = mesh
+        if mesh is not None:
+            # model code reads the process-wide mesh (mpu.annotate's
+            # sharding constraints, the per-shard flash kernel): an
+            # Engine given a mesh makes it that mesh
+            from ..distributed.mesh import set_mesh
+            set_mesh(mesh)
         self.donate = donate_params
         # resilience.TrainGuard: when set, train_batch compiles the
         # guarded step variant (fused all-finite check, masked update,
@@ -316,8 +322,10 @@ class Engine:
                     scaler_state, loss_v, ok, gnorm, outs)
 
         donate = (0, 1, 2) if self.donate else ()
-        return self.tracer.jit("train_step_guarded", train_step,
-                               donate_argnums=donate)
+        return self.tracer.jit(
+            "train_step_guarded", train_step, donate_argnums=donate,
+            **self._pin_state_layout(self._params, self._buffers,
+                                     self._opt_state, rest=5))
 
     def _build_train_fn(self):
         if self.guard is not None:
@@ -362,8 +370,10 @@ class Engine:
                     gnorm, outs)
 
         donate = (0, 1, 2) if self.donate else ()
-        return self.tracer.jit("train_step", train_step,
-                               donate_argnums=donate)
+        return self.tracer.jit(
+            "train_step", train_step, donate_argnums=donate,
+            **self._pin_state_layout(self._params, self._buffers,
+                                     self._opt_state, rest=3))
 
     def _build_accum_fns(self):
         """Gradient accumulation as TWO compiled programs (ref: the
@@ -429,7 +439,9 @@ class Engine:
             donate_argnums=(2,) if self.donate else ())
         apply_jit = self.tracer.jit(
             "apply_step", apply_step,
-            donate_argnums=(0, 1, 2) if self.donate else ())
+            donate_argnums=(0, 1, 2) if self.donate else (),
+            **self._pin_state_layout(self._params, self._opt_state,
+                                     rest=1))
         return grad_jit, apply_jit
 
     def _ensure_opt_state(self):
@@ -437,6 +449,10 @@ class Engine:
         paths — including the set_state_dict pending-leaves restore."""
         if self._opt_state is not None:
             return
+        if self.mesh is not None:
+            self._params = self._on_mesh(self._params)
+            self._buffers = self._on_mesh(self._buffers)
+            self.network.load_raw_state(self._params, self._buffers)
         trainable = {n: self._params[n]
                      for n, p in self.network.named_parameters()
                      if p.trainable and n in self._params}
@@ -448,7 +464,54 @@ class Engine:
                 self._opt_state = jax.tree_util.tree_unflatten(
                     treedef, pending)
             self.optimizer._pending_state_leaves = None
+        self._opt_state = self._on_mesh(self._opt_state)
         self._apply_zero_placement()
+
+    def _on_mesh(self, tree):
+        """`tree` committed to the mesh BEFORE the first compile. State
+        created on one device (optimizer moments, a model never passed
+        to shard_model) comes back from the step laid out over the mesh,
+        so the second call would see new input shardings and compile the
+        whole step again. A leaf keyed by a parameter's name takes that
+        parameter's sharding (Adam moments mirror their weight);
+        anything else not yet on the mesh is replicated over it."""
+        mesh = self.mesh
+        if mesh is None:
+            return tree
+        from jax.sharding import NamedSharding, PartitionSpec
+        replicated = NamedSharding(mesh, PartitionSpec())
+        params = self._params
+
+        def on_mesh(a):
+            sh = getattr(a, "sharding", None)
+            return isinstance(sh, NamedSharding) and sh.mesh == mesh
+
+        def place(path, a):
+            if not hasattr(a, "sharding") or on_mesh(a):
+                return a
+            like = params.get(getattr(path[-1], "key", None)) \
+                if path else None
+            if like is not None and like is not a and on_mesh(like) \
+                    and like.shape == a.shape:
+                return jax.device_put(a, like.sharding)
+            return jax.device_put(a, replicated)
+
+        return jax.tree_util.tree_map_with_path(place, tree)
+
+    def _pin_state_layout(self, *state, rest):
+        """jit kwargs that hand each state output back laid out exactly
+        like its input; the `rest` trailing outputs stay XLA's choice.
+        Left alone, XLA may return e.g. ZeRO-updated parameters still
+        dp-sharded, and the next call would compile the whole step again
+        for the new input layout. Nothing to pin off-mesh."""
+        if self.mesh is None:
+            return {}
+
+        def layout(tree):
+            return jax.tree_util.tree_map(
+                lambda a: getattr(a, "sharding", None), tree)
+        return {"out_shardings": tuple(layout(t) for t in state)
+                + (None,) * rest}
 
     def train_batch_accum(self, inputs, labels, apply_update):
         """One microbatch of gradient accumulation; pass
@@ -733,9 +796,11 @@ class Engine:
                 # should use train_batch
                 return p, b, s, losses
 
-            multi = self.tracer.jit("train_step_multi", multi_step,
-                                    donate_argnums=(0, 1, 2)
-                                    if self.donate else ())
+            multi = self.tracer.jit(
+                "train_step_multi", multi_step,
+                donate_argnums=(0, 1, 2) if self.donate else (),
+                **self._pin_state_layout(self._params, self._buffers,
+                                         self._opt_state, rest=1))
             if len(self._multi_fns) > 8:
                 self._multi_fns.clear()
             self._multi_fns[cache_key] = multi
@@ -808,7 +873,7 @@ class Engine:
                 "opt_step": self._opt_step}
 
     def load_opt_state_dict(self, d):
-        self._opt_state = d["state"]
+        self._opt_state = self._on_mesh(d["state"])
         self._step = d["step"]
         # older checkpoints predate the separate update counter; the
         # fused path kept it == step

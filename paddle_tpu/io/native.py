@@ -9,8 +9,12 @@ Python objects can't cross the ctypes boundary, so the prefetcher stores
 numpy payloads in a Python-side slot table and pushes slot ids through the
 native queue.
 
-Builds csrc/ automatically on first use when a compiler is available;
-falls back to None (pure-python queue) otherwise.
+Source checkouts build csrc/ with `make` on first use — from the
+sources as committed, rebuilding whenever a .so is older than its .cc
+(make's own rule), so a stale binary left in the working tree is never
+what runs. Without sources (an installed package) a prebuilt
+paddle_tpu/lib/*.so is used. When neither yields a library the loader
+returns None and says why; callers then take the pure-python queue.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -25,27 +30,28 @@ _LIB = None
 _TRIED = False
 _PKG = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _REPO = os.path.dirname(_PKG)
-# source checkout build, or a prebuilt .so shipped inside the package
-_CANDIDATES = (os.path.join(_REPO, "csrc", "build", "libptio.so"),
-               os.path.join(_PKG, "lib", "libptio.so"))
 
 
-def _build():
+def native_lib_path(name):
+    """Path of csrc's `lib<name>.so`, built (or refreshed) from the
+    committed sources, else the copy shipped inside the package; None
+    with a warning when the build fails."""
     src_dir = os.path.join(_REPO, "csrc")
-    if not os.path.exists(os.path.join(src_dir, "ptio.cc")):
+    if os.path.exists(os.path.join(src_dir, f"{name}.cc")):
+        so = os.path.join(src_dir, "build", f"lib{name}.so")
+        try:
+            r = subprocess.run(["make", "-C", src_dir, f"build/lib{name}.so"],
+                               capture_output=True, timeout=120, text=True)
+            err = r.stderr if r.returncode else ""
+        except (OSError, subprocess.TimeoutExpired) as e:
+            err = str(e)
+        if not err and os.path.exists(so):
+            return so
+        warnings.warn(f"native {name} build failed, using the "
+                      f"pure-python fallback:\n{err[-500:]}")
         return None
-    try:
-        r = subprocess.run(["make", "-C", src_dir], capture_output=True,
-                           timeout=60, text=True)
-    except Exception:
-        return None
-    so = _CANDIDATES[0]
-    if r.returncode != 0 or not os.path.exists(so):
-        import warnings
-        warnings.warn("native IO build failed, using pure-python fallback:\n"
-                      + (r.stderr or "")[-500:])
-        return None
-    return so
+    shipped = os.path.join(_PKG, "lib", f"lib{name}.so")
+    return shipped if os.path.exists(shipped) else None
 
 
 def _load():
@@ -53,7 +59,7 @@ def _load():
     if _TRIED:
         return _LIB
     _TRIED = True
-    so = next((c for c in _CANDIDATES if os.path.exists(c)), None) or _build()
+    so = native_lib_path("ptio")
     if so is None:
         return None
     try:

@@ -35,17 +35,14 @@ def fused_residual_ln(residual, h, ln, want_sum=True):
     as separate HBM round trips. want_sum=True returns (y, s) with
     s = residual + h materialized (GPT pre-LN: s feeds the next
     residual); want_sum=False returns y alone and skips the sum's HBM
-    write entirely (BERT/ERNIE post-LN discard it). interpret off-TPU."""
-    import jax as _jax
-
+    write entirely (BERT/ERNIE post-LN discard it)."""
     from ..autograd import apply_op
     from ..ops.pallas.fused_ln import (fused_add_layer_norm,
                                        fused_add_layer_norm_y)
-    interp = _jax.default_backend() != "tpu"
     eps = getattr(ln, "_epsilon", 1e-5)
     fn = fused_add_layer_norm if want_sum else fused_add_layer_norm_y
     return apply_op(
-        lambda a, b, g, bb: fn(a, b, g, bb, eps, 0, interp),
+        lambda a, b, g, bb: fn(a, b, g, bb, eps),
         residual, h, ln.weight, ln.bias)
 
 

@@ -218,7 +218,7 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lens,
     v = gather(v_pages, v_scale)
     s = jnp.einsum("bhgd,bhkd->bhgk", q.astype(jnp.float32), k,
                    preferred_element_type=jnp.float32) * sm_scale
-    kpos = jnp.arange(k.shape[2])[None, None, None, :]
+    kpos = jnp.arange(k.shape[2], dtype=jnp.int32)[None, None, None, :]
     s = jnp.where(kpos < lens[:, None, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)  # fully-masked rows -> 0

@@ -147,8 +147,9 @@ class ServingEngine:
         Default fully provisions every slot; smaller values exercise
         admission back-pressure/recycling.
     cache_dtype: 'float32' | 'bfloat16' | 'int8' KV storage.
-    use_flash: None auto (TPU + PADDLE_TPU_FLASH_DECODE=1), True force
-        the Pallas paged kernel (interpret mode off-TPU), False jnp ref.
+    use_flash: None auto (TPU + PADDLE_TPU_FLASH_DECODE=1), True the
+        Pallas paged kernel (interpret mode off-TPU; ValueError when
+        head_dim/page_size rule it out), False jnp ref.
     steps_per_dispatch: decode tokens per compiled call (the scan
         length) — admission/eviction happen at dispatch boundaries.
     admission_policy: what to do with the queue head when pages run
@@ -176,10 +177,7 @@ class ServingEngine:
         zero-recompile contract is untouched. reset_counters() zeroes
         every serve_* series (incl. retry/watchdog counts) uniformly.
     donate: donate the page pool to the decode/prefill programs
-        (in-place HBM updates). Turn OFF when running under a
-        persistent compilation cache on jax 0.4.x (reloading donated
-        executables aborts — R6_NOTES.md); bench.py does this
-        automatically for PADDLE_TPU_BENCH_CACHE.
+        (in-place HBM updates).
     prefix_cache: copy-on-write prefix-page sharing (PrefixIndex):
         prompts sharing a page-aligned prefix with an earlier prompt
         map the already-written pages into their page table and run a
@@ -1309,8 +1307,11 @@ class ServingEngine:
 
     def _sample(self, logits, key):
         logits = logits.astype(jnp.float32)
+        # lax.argmax with an int32 index: jnp.argmax would reduce over
+        # an s64 iota (framework.py turns x64 on), and 64-bit integer
+        # compares are emulated on a TPU
         if self.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.lax.argmax(logits, logits.ndim - 1, jnp.int32)
         logits = logits / self.temperature
         if self.top_k:
             vals, cand = jax.lax.top_k(logits, self.top_k)
@@ -1331,7 +1332,7 @@ class ServingEngine:
         batch-shape-dependent noise and break it."""
         logits = logits.astype(jnp.float32)
         if self.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.lax.argmax(logits, logits.ndim - 1, jnp.int32)
         logits = logits / self.temperature
         if self.top_k:
             vals, cand = jax.lax.top_k(logits, self.top_k)
@@ -1477,7 +1478,7 @@ class ServingEngine:
         def prefill(params, buffers, pages, ids, true_len, pages_vec,
                     key):
             s_b = ids.shape[1]
-            mask = (jnp.arange(s_b)[None, :]
+            mask = (jnp.arange(s_b, dtype=jnp.int32)[None, :]
                     < true_len).astype(jnp.int32)
             out = functional_call(self.model, params, buffers,
                                   Tensor(ids), attention_mask=Tensor(mask),

@@ -27,23 +27,9 @@ __all__ = ["BasicTokenizer", "WordpieceTokenizer", "BertTokenizer",
 # ---------------------------------------------------------------------------
 def _load_pttok():
     import ctypes
-    import os
-    import subprocess
 
-    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    repo = os.path.dirname(pkg)
-    candidates = (os.path.join(repo, "csrc", "build", "libpttok.so"),
-                  os.path.join(pkg, "lib", "libpttok.so"))
-    so = next((c for c in candidates if os.path.exists(c)), None)
-    if so is None:
-        src_dir = os.path.join(repo, "csrc")
-        if os.path.exists(os.path.join(src_dir, "pttok.cc")):
-            try:
-                subprocess.run(["make", "-C", src_dir], capture_output=True,
-                               timeout=60, text=True)
-            except Exception:
-                return None
-        so = candidates[0] if os.path.exists(candidates[0]) else None
+    from ..io.native import native_lib_path
+    so = native_lib_path("pttok")
     if so is None:
         return None
     try:

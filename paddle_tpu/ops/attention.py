@@ -12,7 +12,6 @@ fall back to the jnp path — the reference routes those off flash too.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -22,11 +21,10 @@ _PALLAS_MIN_SEQ = 128
 _PALLAS_HEAD_DIMS = (64, 128, 256)
 
 
-def _platform():
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+def _on_tpu():
+    # no try/except: a backend that fails to initialise must surface,
+    # not read as "cpu" and silently switch the kernels off
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention_available(q_shape, k_shape, attn_mask, dropout_p) -> bool:
@@ -35,7 +33,7 @@ def flash_attention_available(q_shape, k_shape, attn_mask, dropout_p) -> bool:
     head dims."""
     if attn_mask is not None:
         return False
-    if _platform() != "tpu":
+    if not _on_tpu():
         return False
     if len(q_shape) != 4:
         return False
@@ -52,36 +50,80 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if flash_attention_available(q.shape, k.shape, None, dropout_p):
-        from .pallas.flash_attention import flash_attention as pallas_flash
-        # On a real TPU the kernel compiles natively; if the availability
-        # gate was forced on elsewhere (CPU tests), run in interpret mode so
-        # the identical kernel/ad path is exercised.
-        return pallas_flash(q, k, v, causal=causal, sm_scale=sm_scale,
-                            kv_lens=kv_lens, dropout_p=dropout_p,
-                            dropout_seed=dropout_seed,
-                            interpret=_platform() != "tpu")
+        return _flash_per_shard(q, k, v, kv_lens, dropout_seed,
+                                causal=causal, sm_scale=sm_scale,
+                                dropout_p=dropout_p)
     return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                kv_lens=kv_lens, dropout_p=dropout_p,
                                dropout_seed=dropout_seed)
+
+
+def _flash_per_shard(q, k, v, kv_lens, dropout_seed, **static):
+    """The Pallas kernel, run per shard when a device mesh is active.
+
+    Mosaic kernels cannot be partitioned by GSPMD ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — what every mesh layout raised on a 4-chip v5e host),
+    so under an explicitly set mesh the call is wrapped in a shard_map
+    that makes every not-yet-manual mesh axis manual: the batch splits
+    over 'dp' and the heads over 'mp' where they divide (attention is
+    independent per batch row and per head), any other axis just
+    replicates. Inside the pipeline's 'pp'-manual region this nests and
+    only the remaining axes are taken. Off-mesh it is a plain call."""
+    from ..distributed import mesh as mesh_mod
+    from .pallas.flash_attention import flash_attention as pallas_flash
+    mesh = mesh_mod._global_mesh
+    context = jax.sharding.get_abstract_mesh()
+    bound = set(context.manual_axes) if not context.empty else set()
+    free = [] if mesh is None else \
+        [a for a in mesh.axis_names if a not in bound]
+    if not free:
+        return pallas_flash(q, k, v, kv_lens=kv_lens,
+                            dropout_seed=dropout_seed, **static)
+
+    def split(axis, *dims):
+        ok = axis in free and all(d % mesh.shape[axis] == 0 for d in dims)
+        return axis if ok else None
+    b_axis = split("dp", q.shape[0])
+    h_axis = split("mp", q.shape[2], k.shape[2])
+    from jax.sharding import PartitionSpec as P
+    qkv = P(b_axis, None, h_axis, None)
+    use_lens = kv_lens is not None
+    seed = jnp.asarray(dropout_seed, jnp.int32)
+
+    def local(q, k, v, seed, *lens):
+        if static["dropout_p"]:
+            # distinct masks per shard: the kernel hashes LOCAL positions
+            for a in free:
+                seed = seed * jnp.int32(31) + jax.lax.axis_index(a)
+        return pallas_flash(q, k, v, kv_lens=lens[0] if use_lens else None,
+                            dropout_seed=seed, **static)
+
+    args = (q, k, v, seed) + ((jnp.asarray(kv_lens, jnp.int32),)
+                              if use_lens else ())
+    specs = (qkv, qkv, qkv, P()) + ((P(b_axis),) if use_lens else ())
+    return jax.shard_map(
+        local, mesh=None if bound else mesh, in_specs=specs, out_specs=qkv,
+        axis_names=frozenset(free), check_vma=False)(*args)
 
 
 def flash_decode(q, k_cache, v_cache, kv_lens, sm_scale=None):
     """Single-query decode against a padded KV cache ([B, 1, H, D] x
     [B, S, H, D] + kv_lens [B]). Pallas on TPU (opt-in), jnp elsewhere.
 
-    The Pallas decode kernel is gated behind PADDLE_TPU_FLASH_DECODE=1:
-    its first Mosaic compile inside a scanned decode program hung the
-    shared TPU terminal in round 2 (BENCHLOG "decode-path incident") and
-    it is not yet hardware-proven (tools/decode_probe.py bisects it in
-    killable subprocesses). Decode attention is HBM-bandwidth-bound, so
-    the jnp path is a safe default; flip the env once the probe passes."""
+    The Pallas decode kernel sits behind PADDLE_TPU_FLASH_DECODE=1. It
+    compiles natively on a v5e and matches the jnp path (chip_smoke.py
+    kernels phase: 2.5e-3 f32 / 6e-4 bf16 normalised max error at b8
+    s1024 h12 d64, PR 21); whether it is FASTER than the jnp path has
+    not been measured, so the jnp path stays the default until a
+    benchmark cell decides."""
     import os
     d = q.shape[-1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     sk = k_cache.shape[1]
     if (os.environ.get("PADDLE_TPU_FLASH_DECODE") == "1"
-            and _platform() == "tpu" and d in _PALLAS_HEAD_DIMS
+            and _on_tpu() and d in _PALLAS_HEAD_DIMS
             and sk % _PALLAS_MIN_SEQ == 0):
         from .pallas.flash_attention import flash_decode as pallas_decode
         return pallas_decode(q, k_cache, v_cache, kv_lens,
@@ -92,31 +134,31 @@ def flash_decode(q, k_cache, v_cache, kv_lens, sm_scale=None):
 
 def paged_flash_available(head_dim, page_size, use_flash=None):
     """Gate for the paged GQA decode kernel (serving engine /
-    nlp/paged_cache.py). Mirrors flash_decode's caution: the Pallas
-    decode path stays OFF by default on hardware until
-    PADDLE_TPU_FLASH_DECODE=1 (round-2 wedge, BENCHLOG), but an
-    explicit use_flash=True forces it anywhere the SHAPE supports
-    (interpret mode off-TPU — the CPU ladder/tests exercise the
-    identical kernel); a forced-but-unsupported shape falls back to
-    the jnp reference with a stderr warning (callers that report
-    results must echo the effective gate, e.g. bench --serve's
-    flash_kernel field).
+    nlp/paged_cache.py). The kernel compiles natively inside the
+    engine's decode scan on a v5e and agrees with the jnp reference
+    path (7e-3 normalised max error on decode-step logits, gpt2-en,
+    page_size 128, bf16 cache — chip_smoke.py serve phase, PR 21).
+    Which path is faster is unmeasured, so auto mode keeps the
+    reference path unless PADDLE_TPU_FLASH_DECODE=1.
 
-    use_flash: True -> force on; False -> off; None -> auto (TPU +
-    env gate + supported shape)."""
+    use_flash: True -> the kernel, anywhere the SHAPE supports it
+    (interpret mode off-TPU — the CPU tests exercise the identical
+    kernel), ValueError where it does not: an explicit request is
+    never silently served by the reference. False -> off. None ->
+    auto (TPU + env gate + supported shape)."""
     shape_ok = head_dim in _PALLAS_HEAD_DIMS and page_size % 8 == 0
     if use_flash is False:
         return False
     if use_flash is True:
         if not shape_ok:
-            import sys
-            print(f"paged_flash_available: use_flash=True refused — "
-                  f"head_dim={head_dim} not in {_PALLAS_HEAD_DIMS} or "
-                  f"page_size={page_size} % 8 != 0; running the jnp "
-                  "reference path", file=sys.stderr, flush=True)
-        return shape_ok
+            raise ValueError(
+                f"use_flash=True: the paged flash-decode kernel needs "
+                f"head_dim in {_PALLAS_HEAD_DIMS} and page_size % 8 == 0, "
+                f"got head_dim={head_dim} page_size={page_size}; pass "
+                "use_flash=False for the jnp reference path")
+        return True
     import os
-    return (shape_ok and _platform() == "tpu"
+    return (shape_ok and _on_tpu()
             and os.environ.get("PADDLE_TPU_FLASH_DECODE") == "1")
 
 
@@ -130,8 +172,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lens,
     identical kernel."""
     from .pallas.flash_decode import paged_flash_decode as kernel
     return kernel(q, k_pages, v_pages, page_table, lens,
-                  k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale,
-                  interpret=_platform() != "tpu")
+                  k_scale=k_scale, v_scale=v_scale, sm_scale=sm_scale)
 
 
 def reference_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,

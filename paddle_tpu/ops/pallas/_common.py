@@ -1,0 +1,37 @@
+"""The one `pallas_call` every kernel in this package goes through.
+
+Two decisions live here and nowhere else:
+
+- interpret mode: kernels compile natively (Mosaic) on a TPU backend
+  and run in the Pallas interpreter on any other backend, which is how
+  the CPU test-suite executes the identical kernel bodies. Call sites
+  never compute `interpret=` themselves; a test may still force it.
+- x32 tracing: the framework turns jax_enable_x64 on globally for
+  paddle dtype parity (framework.py), and under x64 the Python int /
+  float literals in index maps and kernel bodies trace as i64/f64,
+  which Mosaic cannot legalize. All kernel math is explicitly f32/i32,
+  so tracing the call with x64 off is semantics-preserving.
+"""
+from __future__ import annotations
+
+import jax
+from jax.experimental import pallas as pl
+
+
+def interpret_default() -> bool:
+    """True iff kernels must run interpreted: the backend is not a TPU."""
+    return jax.default_backend() != "tpu"
+
+
+def pallas_call(kernel, *, interpret=None, **kwargs):
+    """`pl.pallas_call` with the package's interpret and x32 decisions
+    applied. interpret=None (every production call site) resolves from
+    the backend; True/False is honoured for tests."""
+    if interpret is None:
+        interpret = interpret_default()
+    call = pl.pallas_call(kernel, interpret=interpret, **kwargs)
+
+    def run(*args):
+        with jax.enable_x64(False):
+            return call(*args)
+    return run

@@ -1,10 +1,11 @@
 """Fused 1x1-conv + BatchNorm scale/shift + ReLU (+ residual add) Pallas
 TPU kernel — the diagnosed ResNet-50 HBM-bandwidth wall.
 
-Why: BENCH_r05 puts ResNet-50 at 0.76x the A100 share at MFU 0.139 with
-the roofline pinned on the bottleneck 1x1 convs (SURVEY §6, VERDICT r5
-weak #2): each is a skinny matmul whose output makes extra full HBM
-round trips through the BN normalize, the ReLU, and the residual add.
+Why: ResNet-50 sits at 0.77x the A100 share at MFU 0.139
+(campaign_out/summary_first_window_0347.json) with the roofline pinned
+on the bottleneck 1x1 convs (SURVEY §6): each is a skinny matmul whose
+output makes extra full HBM round trips through the BN normalize, the
+ReLU, and the residual add.
 In NHWC a 1x1 conv IS a [M, Cin] @ [Cin, Cout] matmul (M = N*H*W), so
 this kernel computes
 
@@ -45,6 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._common import pallas_call
 
 __all__ = ["fused_conv1x1_bn_act", "conv1x1_batch_stats"]
 
@@ -117,7 +120,7 @@ def _fwd_call(x2, w, scale, shift, res2, relu, block_m, interpret):
     else:
         kern = functools.partial(_fwd_kernel, relu=relu)
         args = (x2, w, scale[None, :], shift[None, :])
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
@@ -129,7 +132,7 @@ def _fwd_call(x2, w, scale, shift, res2, relu, block_m, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def fused_conv1x1_bn_act(x2, w, scale, shift, res2=None, relu=True,
-                         block_m=0, interpret=False):
+                         block_m=0, interpret=None):
     """y = relu((x2 @ w) * scale + shift [+ res2]) in one HBM pass.
 
     x2: [M, Cin] (NHWC flattened over N*H*W); w: [Cin, Cout];

@@ -34,27 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax._src.config import enable_x64 as _enable_x64_ctx
-except ImportError:  # pragma: no cover
-    import contextlib
-    _enable_x64_ctx = lambda _on: contextlib.nullcontext()
-
-
-def _x32_traced(fn):
-    """Trace pallas kernels in x32 mode.
-
-    The framework enables jax_enable_x64 globally for paddle dtype parity
-    (framework.py), but under x64 Python int/float literals in index maps
-    and kernels trace as i64/f64, which Mosaic cannot legalize
-    ('failed to legalize tpu.truncf / func.return'). All kernel math here
-    is explicitly f32/i32, so tracing with x64 off is semantics-preserving.
-    """
-    @functools.wraps(fn)
-    def wrapped(*a, **k):
-        with _enable_x64_ctx(False):
-            return fn(*a, **k)
-    return wrapped
+from ._common import pallas_call
 
 
 #   measured on v5e (b8 h16 d64, fwd+bwd, causal): 512x512 blocks beat both
@@ -305,7 +285,6 @@ def _smem_full(n):
     return pl.BlockSpec((n,), lambda *_: (0,), memory_space=pltpu.SMEM)
 
 
-@_x32_traced
 def _fwd_call(q, k, v, lens, seed, causal, sm_scale, dropout_p, block_q,
               block_k, interpret):
     bh, sq, d = q.shape
@@ -318,7 +297,7 @@ def _fwd_call(q, k, v, lens, seed, causal, sm_scale, dropout_p, block_q,
         dropout_p=dropout_p, sk=sk)
     lens_in = lens if use_lens else jnp.zeros((bh,), jnp.int32)
     seed_in = seed if seed is not None else jnp.zeros((1,), jnp.int32)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -345,7 +324,6 @@ def _fwd_call(q, k, v, lens, seed, causal, sm_scale, dropout_p, block_q,
     )(lens_in, seed_in, q, k, v)
 
 
-@_x32_traced
 def _bwd_call(res, g, causal, sm_scale, dropout_p, block_q, block_k,
               interpret):
     q, k, v, o, lse, lens, seed = res
@@ -362,7 +340,7 @@ def _bwd_call(res, g, causal, sm_scale, dropout_p, block_q, block_k,
                   block_k=block_k, offset=sk - sq, use_lens=use_lens,
                   dropout_p=dropout_p, sk=sk)
     dq_kern = functools.partial(_dq_kernel, **common)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         dq_kern,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
@@ -382,7 +360,7 @@ def _bwd_call(res, g, causal, sm_scale, dropout_p, block_q, block_k,
     )(lens_in, seed_in, q, k, v, do, lse, delta)
 
     dkv_kern = functools.partial(_dkv_kernel, **common)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         dkv_kern,
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[
@@ -445,7 +423,7 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention(q, k, v, causal=False, sm_scale=None, kv_lens=None,
                     dropout_p=0.0, dropout_seed=0,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False):
+                    interpret=None):
     """[B, S, H, D] differentiable flash attention.
 
     kv_lens: optional [B] int32 — key positions >= kv_lens[b] are masked
@@ -483,7 +461,7 @@ _DECODE_Q_ROWS = 8  # Mosaic minimum sublane tile for f32
 
 
 def flash_decode(q, k_cache, v_cache, kv_lens, sm_scale=None,
-                 block_k=DEFAULT_BLOCK_K, interpret=False):
+                 block_k=DEFAULT_BLOCK_K, interpret=None):
     """Single-step decode attention against a padded KV cache.
 
     q [B, 1, H, D]; k_cache/v_cache [B, S, H, D] (S static, padded);

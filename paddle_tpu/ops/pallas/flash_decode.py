@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _x32_traced
+from ._common import pallas_call
 
 _NEG_INF = -1e30
 _Q_SUBLANES = 8  # f32 minimum sublane tile; G query heads pad up to it
@@ -95,10 +95,9 @@ def _decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         o_ref[0, 0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
 
 
-@_x32_traced
 def paged_flash_decode(q, k_pages, v_pages, page_table, lens,
                        k_scale=None, v_scale=None, sm_scale=None,
-                       interpret=False):
+                       interpret=None):
     """q [B, Hkv, G, D] f32/bf16; k_pages/v_pages [Hkv, P, ps, D]
     (f32/bf16, or int8 with k_scale/v_scale [Hkv, P, ps, 1] f32);
     page_table [B, MP] int32 (every entry a valid page id — unused
@@ -154,7 +153,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lens,
             pltpu.VMEM((gp, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),

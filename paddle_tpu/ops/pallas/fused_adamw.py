@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._common import pallas_call
+
 __all__ = ["fused_adamw_update", "fused_adamw_supported"]
 
 _LANES = 512
@@ -67,7 +69,7 @@ def fused_adamw_supported(p, m, v):
 
 def fused_adamw_update(p, m, v, g, lr, bc1, bc2, *, beta1, beta2, eps,
                        weight_decay, decoupled, block_rows=256,
-                       interpret=False):
+                       interpret=None):
     """One-pass update; returns (p_new, m_new, v_new). lr/bc1/bc2 may
     be traced scalars (they ride SMEM); betas/eps/wd are static."""
     shape = p.shape
@@ -100,7 +102,7 @@ def fused_adamw_update(p, m, v, g, lr, bc1, bc2, *, beta1, beta2, eps,
                              decoupled=bool(decoupled))
     row = lambda i: (i, 0)
     tile = pl.BlockSpec((br, _LANES), row)
-    po, mo, vo = pl.pallas_call(
+    po, mo, vo = pallas_call(
         kern,
         grid=(rows // br,),
         in_specs=[

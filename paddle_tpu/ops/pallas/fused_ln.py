@@ -1,6 +1,6 @@
 """Fused residual-add + LayerNorm Pallas TPU kernel (fwd + bwd).
 
-Why: step anatomy on the 345M GPT (BENCHLOG r4) put the MFU gap in
+Why: step anatomy on the 345M GPT (2026-07-30) put the MFU gap in
 elementwise HBM passes — the pre-LN block's `s = x + drop(h);
 ln_2(s)` chain costs an extra full read of s when the add and the
 norm compile to separate HBM round trips. This kernel computes
@@ -18,7 +18,8 @@ as a kernel break).
 
 Grid: rows are tiled [block_rows, H] per step; the weight grads are
 accumulated across the sequential TPU grid into fp32 [1, H] outputs.
-Validated in interpret mode on CPU (tests/test_fused_ln.py);
+Validated in interpret mode on CPU (tests/test_fused_ln.py) and
+compiled natively on a v5e against the jnp reference (chip_smoke.py);
 bf16/fp32 both supported, softmax-free so tolerance is tight.
 """
 from __future__ import annotations
@@ -28,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ._common import pallas_call
 
 __all__ = ["fused_add_layer_norm", "fused_add_layer_norm_y"]
 
@@ -137,7 +140,7 @@ def _fwd_call(x2, r2, gamma, beta, eps, block_rows, interpret):
     row = lambda i: (i, 0)
     vec = lambda i: (0, 0)
     kern = functools.partial(_fwd_kernel, eps=eps)
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -167,7 +170,7 @@ def _bwd_call(dy2, ds2, s2, mu, rstd, gamma, block_rows, interpret):
     grid = (n // block_rows,)
     row = lambda i: (i, 0)
     vec = lambda i: (0, 0)
-    return pl.pallas_call(
+    return pallas_call(
         _bwd_kernel,
         grid=grid,
         in_specs=[
@@ -203,7 +206,7 @@ def _reference(x, res, gamma, beta, eps):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def fused_add_layer_norm(x, res, gamma, beta, eps=1e-5, block_rows=0,
-                         interpret=False):
+                         interpret=None):
     """(y, s): y = LayerNorm(x + res) * gamma + beta, s = x + res.
 
     x, res: [..., H]; gamma/beta: [H]. Both outputs differentiable
@@ -268,7 +271,7 @@ def _fwd_call_y(x2, r2, gamma, beta, eps, block_rows, interpret):
     n, h = x2.shape
     row = lambda i: (i, 0)
     vec = lambda i: (0, 0)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fwd_kernel_y, eps=eps),
         grid=(n // block_rows,),
         in_specs=[
@@ -295,7 +298,7 @@ def _bwd_call_y(dy2, x2, r2, mu, rstd, gamma, block_rows, interpret):
     n, h = dy2.shape
     row = lambda i: (i, 0)
     vec = lambda i: (0, 0)
-    return pl.pallas_call(
+    return pallas_call(
         _bwd_kernel_y,
         grid=(n // block_rows,),
         in_specs=[
@@ -322,7 +325,7 @@ def _bwd_call_y(dy2, x2, r2, mu, rstd, gamma, block_rows, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def fused_add_layer_norm_y(x, res, gamma, beta, eps=1e-5, block_rows=0,
-                           interpret=False):
+                           interpret=None):
     """y = LayerNorm(x + res) * gamma + beta, WITHOUT materializing the
     sum (post-LN blocks discard it): one HBM write fewer per call than
     fused_add_layer_norm, and backward re-adds x+res in-kernel."""

@@ -33,7 +33,7 @@ def _sround_bf16(x32, key):
     bf16-stored Adam second moment still accumulates (1-b2)=1e-3 relative
     increments that nearest-rounding would silently drop (they sit below
     bf16's 2^-8 resolution). This is what makes half-width moments usable:
-    it halves the optimizer's HBM state traffic (BENCHLOG: 9.9 GB/step at
+    it halves the optimizer's HBM state traffic (9.9 GB/step at
     gpt3-345M) without biasing the moment estimates.
     ref parity: paddle.optimizer.adamw multi_precision / master-weight
     path (python/paddle/optimizer/adamw.py) — same goal (reduced-precision
@@ -291,6 +291,17 @@ class Optimizer:
         return lr * lr_mult.get(key, 1.0)
 
 
+def _bias_correction(beta, step):
+    """1 - beta**step as an f32 scalar.
+
+    framework.py turns jax_enable_x64 on, and there a Python float
+    raised to the traced int32 step is an f64 scalar; dividing a
+    parameter-sized f32 tensor by it promotes the WHOLE update to f64,
+    which a TPU emulates in software. The scalar itself is still
+    computed in double (a handful of scalar ops) and rounded once."""
+    return jnp.asarray(1.0 - beta ** step, jnp.float32)
+
+
 class SGD(Optimizer):
     """ref: paddle.optimizer.SGD — vanilla + optional (coupled) L2 decay."""
 
@@ -391,8 +402,8 @@ class Adam(Optimizer):
         wd = self._weight_decay
         decay_fn = self._apply_decay_param_fun
         mdt = self._moment_dtype
-        bc1 = 1.0 - b1 ** step
-        bc2 = 1.0 - b2 ** step
+        bc1 = _bias_correction(b1, step)
+        bc2 = _bias_correction(b2, step)
         skey = None
         if mdt == jnp.bfloat16:
             # per-step, per-parameter keys derived inside the trace: no
@@ -404,10 +415,8 @@ class Adam(Optimizer):
         use_fused = self._fused_kernel and not self._amsgrad \
             and not self._multi_precision
         if use_fused:
-            import jax as _jax
             from ..ops.pallas.fused_adamw import (fused_adamw_supported,
                                                   fused_adamw_update)
-            interp = _jax.default_backend() != "tpu"
         for k in params:
             if use_fused and fused_adamw_supported(
                     params[k], state["m"][k], state["v"][k]):
@@ -417,7 +426,7 @@ class Adam(Optimizer):
                     params[k], state["m"][k], state["v"][k], grads[k],
                     elr, bc1, bc2, beta1=b1, beta2=b2, eps=eps,
                     weight_decay=(wd if apply_wd else 0.0),
-                    decoupled=self._decoupled, interpret=interp)
+                    decoupled=self._decoupled)
                 continue
             g = grads[k].astype(jnp.float32)
             p32 = state["master"][k] if self._multi_precision else \
@@ -505,7 +514,7 @@ class Adamax(Optimizer):
                 g = g + wd * p
             m = b1 * state["m"][k] + (1 - b1) * g
             u = jnp.maximum(b2 * state["u"][k], jnp.abs(g))
-            elr = self._effective_lr(lr, lr_mult, k) / (1 - b1 ** step)
+            elr = self._effective_lr(lr, lr_mult, k) / _bias_correction(b1, step)
             new[0][k] = p - elr * m / (u + eps)
             new[1][k] = m
             new[2][k] = u
@@ -635,8 +644,8 @@ class Lamb(Optimizer):
             p = params[k].astype(jnp.float32)
             m = b1 * state["m"][k] + (1 - b1) * g
             v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
-            m_hat = m / (1 - b1 ** step)
-            v_hat = v / (1 - b2 ** step)
+            m_hat = m / _bias_correction(b1, step)
+            v_hat = v / _bias_correction(b2, step)
             r = m_hat / (jnp.sqrt(v_hat) + eps)
             use_wd = wd and (self._exclude_fn is None or not self._exclude_fn(k))
             if use_wd:
@@ -661,9 +670,9 @@ class NAdam(Adam):
                 g = g + self._weight_decay * p
             m = b1 * state["m"][k] + (1 - b1) * g
             v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
-            m_hat = m / (1 - b1 ** (step + 1))
-            v_hat = v / (1 - b2 ** step)
-            m_bar = b1 * m_hat + (1 - b1) * g / (1 - b1 ** step)
+            m_hat = m / _bias_correction(b1, step + 1)
+            v_hat = v / _bias_correction(b2, step)
+            m_bar = b1 * m_hat + (1 - b1) * g / _bias_correction(b1, step)
             new_p[k] = p - self._effective_lr(lr, lr_mult, k) * m_bar / \
                 (jnp.sqrt(v_hat) + eps)
             new_m[k], new_v[k] = m, v
@@ -682,13 +691,15 @@ class RAdam(Adam):
                 g = g + self._weight_decay * p
             m = b1 * state["m"][k] + (1 - b1) * g
             v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
-            m_hat = m / (1 - b1 ** step)
+            m_hat = m / _bias_correction(b1, step)
             rho_t = rho_inf - 2 * step * (b2 ** step) / (1 - b2 ** step)
             elr = self._effective_lr(lr, lr_mult, k)
-            v_hat = jnp.sqrt(v / (1 - b2 ** step))
+            v_hat = jnp.sqrt(v / _bias_correction(b2, step))
             r_num = (rho_t - 4) * (rho_t - 2) * rho_inf
             r_den = (rho_inf - 4) * (rho_inf - 2) * rho_t
-            r = jnp.sqrt(jnp.maximum(r_num / r_den, 0.0))
+            # scalar math in double, rounded before it meets a tensor
+            r = jnp.asarray(jnp.sqrt(jnp.maximum(r_num / r_den, 0.0)),
+                            jnp.float32)
             rect = p - elr * r * m_hat / (v_hat + eps)
             plain = p - elr * m_hat
             new_p[k] = jnp.where(rho_t > 5.0, rect, plain)

@@ -2,7 +2,7 @@
 
 TPU runtimes surface recoverable conditions as textual status codes
 (RESOURCE_EXHAUSTED while another client's pages drain, UNAVAILABLE /
-DEADLINE_EXCEEDED across a flaky tunnel, ABORTED on a preempted
+DEADLINE_EXCEEDED from a busy runtime, ABORTED on a preempted
 dispatch). Those deserve a bounded, deterministic backoff-and-retry at
 the dispatch seam — not a dead training job. Everything else (shape
 errors, OOM of the program itself, assertion failures) must propagate

@@ -1,6 +1,6 @@
 """Dispatch watchdog — detects a wedged device call.
 
-A wedged dispatch (dead tunnel, deadlocked collective, runaway kernel)
+A wedged dispatch (hung runtime, deadlocked collective, runaway kernel)
 looks identical to a slow one from the host: the execute call just
 never returns. The watchdog is a daemon thread watching a heartbeat
 the caller brackets around each dispatch; when an operation stays in

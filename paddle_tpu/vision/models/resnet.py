@@ -86,8 +86,6 @@ def _fused_conv1x1_bn(x, conv, bn, residual=None, training=False):
         rv._inplace(rv * mom + unbiased.detach() * (1.0 - mom))
     else:
         mean, var = bn._mean, bn._variance
-    interp = _jax.default_backend() != "tpu"
-
     def f(a, ww, g, b, mu, v, *res):
         scale = g.astype(jnp.float32) * _jax.lax.rsqrt(
             v.astype(jnp.float32) + eps)
@@ -99,7 +97,7 @@ def _fused_conv1x1_bn(x, conv, bn, residual=None, training=False):
         w2 = ww.reshape(ww.shape[-2], ww.shape[-1])
         r2 = res[0].reshape(m, res[0].shape[-1]) if res else None
         y2 = fused_conv1x1_bn_act(a.reshape(m, a.shape[-1]), w2, scale,
-                                  shift, r2, True, 0, interp)
+                                  shift, r2, True)
         return y2.reshape(lead + (w2.shape[-1],))
 
     args = [x, w, bn.weight, bn.bias, mean, var]

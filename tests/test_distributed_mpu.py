@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu as paddle
 from paddle_tpu.distributed import mesh as mesh_mod
@@ -76,7 +76,7 @@ def test_column_row_shard_map_matches_dense(mp_mesh):
         stage, mesh=mp_mesh,
         in_specs=(P(), P(None, "mp"), P("mp"), P("mp", None), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     got = np.asarray(jax.jit(fn)(x, w1, b1, w2, b2))
     want = (x @ w1 + b1) @ w2 + b2
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
@@ -94,7 +94,7 @@ def test_vocab_parallel_embedding_shard_map(mp_mesh):
 
     fn = shard_map(stage, mesh=mp_mesh,
                    in_specs=(P(), P("mp", None)), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     got = np.asarray(jax.jit(fn)(ids, w))
     np.testing.assert_allclose(got, w[ids], rtol=1e-6, atol=1e-6)
 
@@ -111,7 +111,7 @@ def test_parallel_cross_entropy_shard_map(mp_mesh):
 
     fn = shard_map(stage, mesh=mp_mesh,
                    in_specs=(P(None, "mp"), P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     got = np.asarray(jax.jit(fn)(logits, labels))
     m = logits.max(-1, keepdims=True)
     lse = np.log(np.exp(logits - m).sum(-1)) + m[:, 0]

@@ -122,7 +122,7 @@ def test_shard_map_mp_loss_matches_dense():
     must give the SAME loss as the dense model (regression: gathering
     logits before the parallel CE double-counted the partition function)."""
     from jax.sharding import Mesh
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     m = GPTForCausalLM(tiny())
     crit = GPTPretrainingCriterion()
     m.eval()
@@ -143,7 +143,7 @@ def test_shard_map_mp_loss_matches_dense():
         sp = getattr(p, "sharding_spec", None)
         specs[n] = sp if sp is not None else P()
     fn = shard_map(step, mesh=mesh, in_specs=(P(), P(), specs),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     got = float(jax.jit(fn)(inp, lab, params))
     np.testing.assert_allclose(got, dense_loss, rtol=1e-4)
 

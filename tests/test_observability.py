@@ -818,18 +818,17 @@ def _clean_introspection(monkeypatch, tmp_path):
 
 
 class TestIntrospect:
-    def test_normalize_cost_handles_both_jax_shapes(self):
-        # jax 0.4.x: list of dicts; 0.6.x: dict; CPU builds may omit keys
-        lst = introspect.normalize_cost(
-            [{"flops": 10.0, "bytes accessed": 5.0}])
-        assert lst == {"flops": 10.0, "bytes_accessed": 5.0,
-                       "transcendentals": None}
-        dct = introspect.normalize_cost({"flops": 3})
-        assert dct["flops"] == 3.0
-        assert introspect.normalize_cost(None) is None
-        assert introspect.normalize_cost([]) == {
+    def test_normalize_cost(self):
+        # a dict; CPU builds may omit keys or return None
+        full = introspect.normalize_cost(
+            {"flops": 10.0, "bytes accessed": 5.0})
+        assert full == {"flops": 10.0, "bytes_accessed": 5.0,
+                        "transcendentals": None}
+        assert introspect.normalize_cost({"flops": 3})["flops"] == 3.0
+        assert introspect.normalize_cost({}) == {
             "flops": None, "bytes_accessed": None,
             "transcendentals": None}
+        assert introspect.normalize_cost(None) is None
         assert introspect.normalize_cost("bogus") is None
 
     def test_resolve_peak_env_override_beats_table(self, monkeypatch):
@@ -844,6 +843,10 @@ class TestIntrospect:
         peak, src = introspect.resolve_peak_flops("TPU v4")
         assert peak == 275e12
         peak, src = introspect.resolve_peak_flops("Quantum9000")
+        assert peak is None and "unknown-device-kind" in src
+        # no catch-all: a v5 string the table does not name is unknown,
+        # never silently the v5p peak
+        peak, src = introspect.resolve_peak_flops("TPU v5")
         assert peak is None and "unknown-device-kind" in src
 
     def test_resolve_peak_null_on_cpu_without_override(self, monkeypatch):

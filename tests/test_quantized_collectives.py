@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from paddle_tpu.distributed.quantized import (
     dequantize_int8_blockwise, quantize_int8_blockwise,
@@ -37,7 +37,7 @@ def test_quantize_relative_error_bounded():
 def _qar(mesh, x, block=256):
     fn = shard_map(
         lambda v: quantized_all_reduce(v, "dp", block=block),
-        mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_rep=False)
+        mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False)
     return fn(x)
 
 

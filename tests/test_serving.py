@@ -212,7 +212,8 @@ class TestPagedKernelRouting:
         assert fl_eng.generate(prompts, max_new_tokens=6) == refs
 
     def test_gate_rejects_unsupported_head_dim(self, gpt_model):
-        # gpt-tiny head_dim=16: even a forced flash must fall back
-        eng = ServingEngine(gpt_model, max_slots=1, page_size=16,
-                            max_seq_len=48, use_flash=True)
-        assert not eng.use_flash
+        # gpt-tiny head_dim=16: an explicit request the kernel cannot
+        # serve is an error, never a silent reference run
+        with pytest.raises(ValueError, match="head_dim=16"):
+            ServingEngine(gpt_model, max_slots=1, page_size=16,
+                          max_seq_len=48, use_flash=True)

@@ -1,9 +1,12 @@
 """aot_boot_probe — artifact-boot vs traced-boot wall clock (r21).
 
 The campaign's measured rung for the AOT serving-artifact store
-(jit/serving_artifact.py): on the first live TPU window this is the
-number that says what a scale-out actually costs with and without the
-artifact path.
+(jit/serving_artifact.py): what a scale-out costs with and without the
+artifact path. The two boot walls are device numbers, so the probe
+needs a TPU and exits non-zero without one (the CPU correctness drill
+of the same path is tests/test_serving_artifact.py). The persistent
+compile cache is deliberately NOT armed here: a warm cache would make
+the traced control look like the artifact boot.
 
 1. **traced control**: build a ServingEngine and pay the full traced
    warmup (prefill buckets + decode scan) — wall-clocked;
@@ -49,10 +52,14 @@ def main(argv=None):
     os.makedirs(out_dir, exist_ok=True)
     store = args.store or os.path.join(out_dir, "aot_store")
 
+    import jax
     import numpy as np
     import paddle_tpu as paddle
     from paddle_tpu.jit.serving_artifact import export_artifact, \
         warm_boot
+    if jax.default_backend() != "tpu":
+        sys.exit("aot_boot_probe times boots on the device and needs a "
+                 f"TPU; jax.default_backend() is {jax.default_backend()!r}")
     from paddle_tpu.nlp.gpt import GPTForCausalLM, _resolve_config
     from paddle_tpu.nlp.serving import ServingEngine
     from paddle_tpu.observability.trace import report_all

@@ -1,4 +1,4 @@
-"""CINN-parity fusion audit (SURVEY §7 R3 / VERDICT r2 next #6).
+"""CINN-parity fusion audit (SURVEY §7 R3).
 
 The reference's CINN pass fuses elementwise chains (LN -> residual ->
 GELU) into generated kernels so activations make one HBM round trip.
@@ -194,6 +194,9 @@ def main():
     ap.add_argument("--dump-hlo", default=None,
                     help="also write the raw optimized HLO here (prefix)")
     args = ap.parse_args()
+    sys.path.insert(0, ".")
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     sections = [f"# Fusion audit (backend: {jax.default_backend()})", ""]
     todo = []
