@@ -45,6 +45,25 @@ def _global_grad_norm(grads):
                         for g in leaves))
 
 
+def _clip_and_update(opt, clip, collect_gnorm, live, grads, opt_state, lr,
+                     opt_step_i):
+    """The tail every train-step variant shares, each part under the
+    scope a device trace attributes it by (`grad_norm`, `grad_clip`,
+    `optimizer`). Returns (new params, new optimizer state, grad norm)."""
+    if collect_gnorm:
+        with jax.named_scope("grad_norm"):
+            gnorm = _global_grad_norm(grads)
+    else:
+        gnorm = jnp.float32(0.0)
+    if clip is not None:
+        with jax.named_scope("grad_clip"):
+            grads = clip.apply(grads)
+    with jax.named_scope("optimizer"):
+        new_live, new_opt = opt.update(live, grads, opt_state, lr,
+                                       opt_step_i)
+    return new_live, new_opt, gnorm
+
+
 class Engine:
     def __init__(self, network: Layer, loss=None, optimizer=None,
                  metrics=None, amp_dtype=None, mesh=None,
@@ -168,27 +187,32 @@ class Engine:
                       inputs, labels, rng):
         """The forward+loss closure shared by the fused train step and
         the accumulation grad step (single source of truth for the AMP
-        cast and buffer-dtype-restore logic)."""
+        cast and buffer-dtype-restore logic). The network runs under
+        its layers' own scopes (nn.Layer.__call__); what is no Layer
+        gets an explicit one here: `amp_cast` and `loss`."""
         def loss_fn(p):
             run_p = {**frozen, **p}
             run_in = inputs
             if amp_dt is not None:
-                cast = jax.tree_util.tree_map(
-                    lambda a: a.astype(amp_dt)
-                    if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                    (run_p, list(inputs)))
+                with jax.named_scope("amp_cast"):
+                    cast = jax.tree_util.tree_map(
+                        lambda a: a.astype(amp_dt)
+                        if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                        (run_p, list(inputs)))
                 run_p, run_in = cast
             outs, new_buf = functional_call(
                 network, run_p, buffers, *run_in, rng=rng, mutable=True)
             if amp_dt is not None:
                 # keep running stats at their original dtype so the step
                 # signature is stable (no recompile) and stats stay fp32
-                new_buf = jax.tree_util.tree_map(
-                    lambda n, o: n.astype(o.dtype)
-                    if hasattr(n, "astype") else n, new_buf, buffers)
+                with jax.named_scope("amp_cast"):
+                    new_buf = jax.tree_util.tree_map(
+                        lambda n, o: n.astype(o.dtype)
+                        if hasattr(n, "astype") else n, new_buf, buffers)
             outs_t = outs if isinstance(outs, (list, tuple)) else [outs]
             if loss_layer is not None:
-                l = loss_layer(*outs_t, *labels)
+                with jax.named_scope("loss"):
+                    l = loss_layer(*outs_t, *labels)
             else:
                 l = outs_t[0]
             l_arr = l._value if isinstance(l, Tensor) else l
@@ -293,15 +317,13 @@ class Engine:
             if grad_shardings is not None:
                 grads = jax.lax.with_sharding_constraint(
                     grads, grad_shardings)
-            ok = jnp.isfinite(loss_v)
-            for g in jax.tree_util.tree_leaves(grads):
-                ok = ok & jnp.all(jnp.isfinite(g))
-            gnorm = _global_grad_norm(grads) if collect_gnorm \
-                else jnp.float32(0.0)
-            if clip is not None:
-                grads = clip.apply(grads)
-            new_live, new_opt = opt.update(live, grads, opt_state,
-                                           lr, opt_step_i)
+            with jax.named_scope("guard"):
+                ok = jnp.isfinite(loss_v)
+                for g in jax.tree_util.tree_leaves(grads):
+                    ok = ok & jnp.all(jnp.isfinite(g))
+            new_live, new_opt, gnorm = _clip_and_update(
+                opt, clip, collect_gnorm, live, grads, opt_state, lr,
+                opt_step_i)
 
             def mask(new, old):
                 # elementwise select, NOT arithmetic: NaNs in the
@@ -310,14 +332,15 @@ class Engine:
                     lambda n, o: jnp.where(ok, n, o)
                     if hasattr(n, "dtype") else n, new, old)
 
-            new_live = mask(new_live, live)
-            new_opt = mask(new_opt, opt_state)
-            new_buf = mask(new_buf, buffers)
-            if use_scaler:
-                scaler_state = _GS.functional_update(
-                    scaler_state, ~ok, incr_ratio=s_incr,
-                    decr_ratio=s_decr, incr_every=s_incr_n,
-                    decr_every=s_decr_n)
+            with jax.named_scope("guard"):
+                new_live = mask(new_live, live)
+                new_opt = mask(new_opt, opt_state)
+                new_buf = mask(new_buf, buffers)
+                if use_scaler:
+                    scaler_state = _GS.functional_update(
+                        scaler_state, ~ok, incr_ratio=s_incr,
+                        decr_ratio=s_decr, incr_every=s_incr_n,
+                        decr_every=s_decr_n)
             return ({**frozen, **new_live}, new_buf, new_opt,
                     scaler_state, loss_v, ok, gnorm, outs)
 
@@ -360,12 +383,9 @@ class Engine:
             if grad_shardings is not None:
                 grads = jax.lax.with_sharding_constraint(
                     grads, grad_shardings)
-            gnorm = _global_grad_norm(grads) if collect_gnorm \
-                else jnp.float32(0.0)
-            if clip is not None:
-                grads = clip.apply(grads)
-            new_live, new_opt = opt.update(live, grads, opt_state,
-                                           lr, opt_step_i)
+            new_live, new_opt, gnorm = _clip_and_update(
+                opt, clip, collect_gnorm, live, grads, opt_state, lr,
+                opt_step_i)
             return ({**frozen, **new_live}, new_buf, new_opt, loss_v,
                     gnorm, outs)
 
@@ -402,27 +422,27 @@ class Engine:
                                    buffers, inputs, labels, rng)
             (loss_v, (outs, new_buf)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(live)
-            grads32 = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads)
-            if grad_shardings is not None:
-                # keep the fp32 accumulator sharded too — a replicated
-                # accumulator would undo ZeRO-2's memory win
-                grads32 = jax.lax.with_sharding_constraint(
-                    grads32, grad_shardings)
-            acc_out = jax.tree_util.tree_map(
-                lambda a, g: a + g, acc, grads32)
+            with jax.named_scope("grad_accum"):
+                grads32 = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads)
+                if grad_shardings is not None:
+                    # keep the fp32 accumulator sharded too — a
+                    # replicated accumulator would undo ZeRO-2's memory win
+                    grads32 = jax.lax.with_sharding_constraint(
+                        grads32, grad_shardings)
+                acc_out = jax.tree_util.tree_map(
+                    lambda a, g: a + g, acc, grads32)
             return acc_out, new_buf, loss_v, outs
 
         def apply_step(params, opt_state, acc, n_micro, lr, step_i):
             frozen = {k: v for k, v in params.items()
                       if k not in trainable_keys}
             live = {k: v for k, v in params.items() if k in trainable_keys}
-            grads = jax.tree_util.tree_map(
-                lambda a, p: (a / n_micro).astype(p.dtype), acc, live)
-            if clip is not None:
-                grads = clip.apply(grads)
-            new_live, new_opt = opt.update(live, grads, opt_state,
-                                           lr, step_i)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree_util.tree_map(
+                    lambda a, p: (a / n_micro).astype(p.dtype), acc, live)
+            new_live, new_opt, _ = _clip_and_update(
+                opt, clip, False, live, grads, opt_state, lr, step_i)
             if not donate:
                 # nothing to alias into without donation — returning a
                 # zero tree would just be a param-size transient
@@ -608,7 +628,8 @@ class Engine:
             outs_t = outs if isinstance(outs, (list, tuple)) else [outs]
             l_arr = None
             if loss_layer is not None and labels:
-                l = loss_layer(*outs_t, *labels)
+                with jax.named_scope("loss"):
+                    l = loss_layer(*outs_t, *labels)
                 l_arr = (l._value if isinstance(l, Tensor) else l).astype(jnp.float32)
             return _unwrap(outs), l_arr
 

@@ -615,9 +615,10 @@ class GPTForCausalLM(FromPretrainedMixin, Layer):
                     "chunked_ce": int(self.config.chunked_ce)}
         # vocab stays sharded under shard_map: GPTPretrainingCriterion's
         # ParallelCrossEntropy consumes vocab-LOCAL logits (Megatron-style)
-        logits = parallel_matmul(
-            hidden, self.gpt.embeddings.word_embeddings.weight,
-            transpose_y=True, gather_output=False)
+        with jax.named_scope("lm_head"):
+            logits = parallel_matmul(
+                hidden, self.gpt.embeddings.word_embeddings.weight,
+                transpose_y=True, gather_output=False)
         if new_cache is not None:
             return logits, new_cache
         return logits
@@ -814,6 +815,7 @@ class GPTForCausalLMPipe(Layer):
         x = annotate(x, "dp", None, None)
         x = self.pipe(x, n_micro=self.n_micro, mesh=self.mesh)
         x = self.ln_f(x)
-        return parallel_matmul(
-            x, self.embeddings.word_embeddings.weight,
-            transpose_y=True, gather_output=False)
+        with jax.named_scope("lm_head"):
+            return parallel_matmul(
+                x, self.embeddings.word_embeddings.weight,
+                transpose_y=True, gather_output=False)

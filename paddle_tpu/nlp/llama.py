@@ -484,12 +484,13 @@ class LlamaForCausalLM(FromPretrainedMixin, Layer):
                     "lm_weight": lm_w,
                     "chunked_ce": int(self.config.chunked_ce)}
         w, tied = self._head_weight()
-        if tied:
-            logits = parallel_matmul(hidden, w, transpose_y=True,
-                                     gather_output=False)
-        else:
-            # lm_head weight is [in, out] — the Linear layout
-            logits = self.lm_head(hidden)
+        with jax.named_scope("lm_head"):
+            if tied:
+                logits = parallel_matmul(hidden, w, transpose_y=True,
+                                         gather_output=False)
+            else:
+                # lm_head weight is [in, out] — the Linear layout
+                logits = self.lm_head(hidden)
         if new_cache is not None:
             return logits, new_cache
         return logits

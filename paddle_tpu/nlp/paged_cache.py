@@ -134,6 +134,7 @@ def _dequant(pages, scale, dtype):
     return x.astype(dtype)
 
 
+@jax.named_scope("kv_write")
 def write_token_kv(cache: PagedLayerCache, k_new, v_new, live):
     """Write one token per slot into the pages. k_new/v_new
     [B, Hkv, D] (post-RoPE for Llama); live [B] bool — masked slots are
@@ -161,6 +162,7 @@ def write_token_kv(cache: PagedLayerCache, k_new, v_new, live):
             None, None)
 
 
+@jax.named_scope("kv_write")
 def write_prompt_kv(k_pages, v_pages, k_scale, v_scale, k_full, v_full,
                     pages_vec):
     """Prefill write: one request's whole (bucket-padded) prompt K/V
@@ -190,6 +192,7 @@ def write_prompt_kv(k_pages, v_pages, k_scale, v_scale, k_full, v_full,
             None, None)
 
 
+@jax.named_scope("paged_attention")
 def paged_attention_ref(q, k_pages, v_pages, page_table, lens,
                         k_scale=None, v_scale=None, sm_scale=None):
     """jnp reference paged attention (the XLA-fused fallback path and
@@ -207,6 +210,7 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lens,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
 
+    @jax.named_scope("page_gather")
     def gather(pages, scale):
         x = pages[:, page_table]        # [Hkv, B, MP, ps, D]
         x = _dequant(x, None if scale is None else scale[:, page_table],
@@ -287,8 +291,10 @@ def paged_update_and_attend(q, k, v, cache: PagedLayerCache, groups=1,
     qg = q1.reshape(b, hkv, groups, d)
     if cache.use_flash:
         from ..ops.attention import paged_flash_decode
-        out = paged_flash_decode(qg, k_pages, v_pages, cache.page_table,
-                                 lens, k_scale=k_scale, v_scale=v_scale)
+        with jax.named_scope("paged_attention"):
+            out = paged_flash_decode(qg, k_pages, v_pages,
+                                     cache.page_table, lens,
+                                     k_scale=k_scale, v_scale=v_scale)
     else:
         out = paged_attention_ref(qg, k_pages, v_pages, cache.page_table,
                                   lens, k_scale=k_scale, v_scale=v_scale)

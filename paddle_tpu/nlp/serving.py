@@ -1305,6 +1305,7 @@ class ServingEngine:
 
     # -- sampling (one strategy per engine == per compiled program) ---------
 
+    @jax.named_scope("sample")
     def _sample(self, logits, key):
         logits = logits.astype(jnp.float32)
         # lax.argmax with an int32 index: jnp.argmax would reduce over
@@ -1320,6 +1321,7 @@ class ServingEngine:
                 cand, pick[..., None], axis=-1)[..., 0].astype(jnp.int32)
         return jax.random.categorical(key, logits).astype(jnp.int32)
 
+    @jax.named_scope("sample")
     def _sample_rows(self, logits, keys):
         """Batched sampling with ONE key per row: logits [N, V],
         keys [N, 2]. Every row's draw depends only on its own (key,

@@ -292,7 +292,11 @@ class Layer:
             r = hook(self, args)
             if r is not None:
                 args = r if isinstance(r, tuple) else (r,)
-        out = self.forward(*args, **kwargs)
+        # the class name, not _unique_name's counter: the scope reaches
+        # the compiled program's op metadata and has to be the same in
+        # every process (introspect.site_scopes reads it back)
+        with jax.named_scope(type(self).__name__):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_post_hooks.values():
             r = hook(self, args, out)
             if r is not None:

@@ -21,6 +21,17 @@ program; sites whose observed compile exceeded
 recorded reason, and
 ``PADDLE_TPU_INTROSPECT=0`` switches the whole layer off.
 
+Beside the numbers a capture keeps the compiled object itself, so
+that ``site_scopes(site)`` can say, on request, which layer each
+instruction of the compiled program belongs to: the map *instruction
+name -> scope path* parsed from the compiled text's
+``metadata={op_name=...}``, which carries the ``jax.named_scope`` names
+the program was built under (nn.Layer.__call__ gives every layer its
+class name; the step builders add `loss`, `optimizer`, `kv_write`, ...).
+A device trace names its operations by instruction, so a reader joins
+the two. The text is produced and parsed on the first query, never on
+the capture path.
+
 API-shape guards: ``cost_analysis()`` returns a dict, but CPU-only
 builds may return None or omit the ``flops`` key — both normalize to
 a plain dict (or None) here. ``memory_analysis()`` is a
@@ -33,12 +44,14 @@ registry import is unavailable — pass ``registry=`` explicitly there.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 
 __all__ = ["resolve_peak_flops", "normalize_cost", "normalize_memory",
-           "capture_site", "site_cost", "cost_report", "measured_mfu",
-           "enabled", "clear", "PEAK_FLOPS_BY_DEVICE_KIND"]
+           "capture_site", "site_cost", "site_scopes", "scope_of",
+           "parse_scopes", "cost_report", "measured_mfu", "enabled", "clear",
+           "PEAK_FLOPS_BY_DEVICE_KIND"]
 
 # bf16 matmul peak per chip, matched by lowercase substring of
 # jax's device_kind string (e.g. "TPU v5 lite", "TPU v4"). MFU is
@@ -208,7 +221,9 @@ def capture_site(tracer_name, site, jitted, args, kwargs, wall_s=0.0,
              "flops": (cost or {}).get("flops"),
              "bytes_accessed": (cost or {}).get("bytes_accessed"),
              "transcendentals": (cost or {}).get("transcendentals"),
-             "memory": mem, "captures": 1}
+             "memory": mem, "captures": 1,
+             # for site_scopes, which reads its text when first asked
+             "_compiled": compiled}
     with _lock:
         prev = _sites.get(key)
         if prev is not None:
@@ -216,7 +231,7 @@ def capture_site(tracer_name, site, jitted, args, kwargs, wall_s=0.0,
         _sites[key] = entry
         _skipped.pop(key, None)
     _publish(entry, registry)
-    return entry
+    return _public(entry)
 
 
 def _publish(entry, registry):
@@ -249,19 +264,117 @@ def _publish(entry, registry):
 
 # -- queries ---------------------------------------------------------------
 
+def _public(entry):
+    """A capture as callers and reports see it: the numbers, without
+    the compiled object and the scope map kept beside them."""
+    return {k: v for k, v in entry.items() if not k.startswith("_")}
+
+
+def _latest(site, tracer):
+    """The registry's own entry for `site`; call under _lock."""
+    if tracer is not None:
+        return _sites.get((tracer, site))
+    best = None
+    for (_t, s), e in _sites.items():
+        if s == site and (best is None or e["ts"] >= best["ts"]):
+            best = e
+    return best
+
+
 def site_cost(site, tracer=None):
     """Latest capture for `site` (optionally pinned to a tracer name);
     None when never captured. Latest-wins across same-named tracers
     (two Engines both report as 'engine')."""
     with _lock:
-        if tracer is not None:
-            e = _sites.get((tracer, site))
-            return dict(e) if e else None
-        best = None
-        for (_t, s), e in _sites.items():
-            if s == site and (best is None or e["ts"] >= best["ts"]):
-                best = e
-        return dict(best) if best else None
+        e = _latest(site, tracer)
+        return _public(e) if e else None
+
+
+# -- which layer an instruction belongs to ---------------------------------
+
+# `%fusion.12 = ... metadata={op_name="jit(train_step)/.../mul" ...}`
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s.*'
+    r'metadata=\{[^}]*?op_name="([^"]*)"', re.M)
+# what a transformation wraps a scope in: `transpose(jvp(GPTMLP))`
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+# functions jax names on the way (the program itself, jnp's own helpers)
+# and the structure of control flow: part of the path, no layer
+_CALLS = {"jit", "pjit"}
+_STRUCTURE = re.compile(
+    r"^(while|body|cond|closed_call|core_call|checkpoint|remat\d*|"
+    r"rematted_computation|custom_jvp_call|custom_vjp_call|"
+    r"custom_vjp_call_jaxpr|custom_lin|shard_map|branch_\d+_fun)$")
+_SCOPE = re.compile(r"^[A-Za-z_]\w*$")
+
+
+def scope_of(op_name):
+    """The scope path of one `op_name`, normalised so that forward and
+    backward of one layer, inside a loop or not, read the same:
+    `jit(step)/transpose(jvp(GPTModel))/GPTMLP/mul` and
+    `jit(step)/while/body/closed_call/GPTModel/GPTMLP/add` both give
+    `GPTModel/GPTMLP`. Transformation wrappers are taken off, jitted
+    functions and control-flow structure are left out, what is no
+    identifier (an einsum's spec) too, and the trailing primitive is
+    dropped. None where nothing is left (an operation built outside
+    every scope, a parameter, a compiler-made reduction)."""
+    parts, depth, cur = [], 0, []
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            parts.append("".join(cur))
+            cur = []
+            continue
+        depth += ch == "("
+        depth -= ch == ")"
+        cur.append(ch)
+    # what is left in `cur` is the last component: the primitive, never
+    # a scope
+    path = []
+    for part in parts:
+        call = False
+        while True:
+            m = _WRAPPED.match(part)
+            if m is None:
+                break
+            call = call or m.group(1) in _CALLS
+            part = m.group(2)
+        if call or _STRUCTURE.match(part) or not _SCOPE.match(part):
+            continue
+        path.append(part)
+    return "/".join(path) or None
+
+
+def parse_scopes(text):
+    """{instruction name: scope path} of a compiled program's text;
+    instructions whose metadata gives no scope are left out."""
+    out = {}
+    for name, op_name in _INSTRUCTION.findall(text):
+        scope = scope_of(op_name)
+        if scope is not None:
+            out[name] = scope
+    return out
+
+
+def site_scopes(site, tracer=None):
+    """{instruction name: scope path} of the latest capture of `site`,
+    or None when the site was never captured or its executable gives
+    no text. Built on the first call (the capture only kept the
+    compiled object), then kept, and the compiled object let go. The
+    lock is held meanwhile: a query comes when a run is read out, not
+    while it serves."""
+    with _lock:
+        e = _latest(site, tracer)
+        if e is None:
+            return None
+        if "_scopes" not in e:
+            text = None
+            try:
+                text = e.pop("_compiled").as_text()
+            except Exception as err:  # noqa: BLE001 — a backend without text
+                _skipped[(e["tracer"], site)] = (
+                    f"no compiled text: {type(err).__name__}: {err}")
+            e["_scopes"] = parse_scopes(text) if text else None
+        return e["_scopes"]
 
 
 def cost_report():
@@ -270,7 +383,7 @@ def cost_report():
     the resolved peak-FLOPs."""
     peak, src = resolve_peak_flops()
     with _lock:
-        sites = {f"{t}/{s}": dict(e) for (t, s), e in
+        sites = {f"{t}/{s}": _public(e) for (t, s), e in
                  sorted(_sites.items())}
         skipped = {f"{t}/{s}": r for (t, s), r in
                    sorted(_skipped.items())}

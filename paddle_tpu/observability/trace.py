@@ -28,10 +28,12 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import re
 import threading
 import time
 
-__all__ = ["RecompileTracer", "get_tracer", "all_tracers", "report_all"]
+__all__ = ["RecompileTracer", "get_tracer", "all_tracers", "report_all",
+           "program_name"]
 
 # REENTRANT: close() runs from GC finalizers (Engine's
 # weakref.finalize, ServingEngine.__del__), and a cyclic collection
@@ -51,6 +53,15 @@ _all_lock = threading.RLock()
 # list of individual reports would silently drop it).
 _all_tracers = []
 _closed_agg = {}
+
+
+def program_name(site):
+    """`site` made a Python identifier: what the compiled program is
+    called. jax names a program after the function it jits, so the
+    module of site "decode" is `jit_decode` in the compiled text and in
+    a device trace, and a reader finds it by that name."""
+    name = re.sub(r"\W", "_", str(site))
+    return name if name[:1].isalpha() or name[:1] == "_" else "_" + name
 
 
 def _leaf_sig(x):
@@ -114,6 +125,9 @@ class RecompileTracer:
                 counts[site] = counts.get(site, 0) + 1
             return fn(*args, **kw)
 
+        # the one place a compiled program gets its name (see
+        # program_name): without it every site is `jit_traced`
+        traced.__name__ = traced.__qualname__ = program_name(site)
         jfn = jax.jit(traced, **jit_kwargs)
         tracer = self
 

@@ -1,6 +1,6 @@
 """The one `pallas_call` every kernel in this package goes through.
 
-Two decisions live here and nowhere else:
+Three decisions live here and nowhere else:
 
 - interpret mode: kernels compile natively (Mosaic) on a TPU backend
   and run in the Pallas interpreter on any other backend, which is how
@@ -11,6 +11,11 @@ Two decisions live here and nowhere else:
   float literals in index maps and kernel bodies trace as i64/f64,
   which Mosaic cannot legalize. All kernel math is explicitly f32/i32,
   so tracing the call with x64 off is semantics-preserving.
+- the kernel's name: `name` is required, and becomes the name of the
+  custom call in the compiled program and in a device trace
+  (`%flash_fwd.3`). Without one the instruction takes the innermost
+  scope's name (`%jvp__.24`), and forward and backward kernels cannot
+  be told apart; a new kernel cannot be anonymous.
 """
 from __future__ import annotations
 
@@ -23,13 +28,16 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def pallas_call(kernel, *, interpret=None, **kwargs):
-    """`pl.pallas_call` with the package's interpret and x32 decisions
-    applied. interpret=None (every production call site) resolves from
-    the backend; True/False is honoured for tests."""
+def pallas_call(kernel, *, name, interpret=None, **kwargs):
+    """`pl.pallas_call` with the package's name, interpret and x32
+    decisions applied. interpret=None (every production call site)
+    resolves from the backend; True/False is honoured for tests."""
+    if not (isinstance(name, str) and name.isidentifier()):
+        raise ValueError(f"a Pallas kernel needs an identifier as its "
+                         f"name, got {name!r}")
     if interpret is None:
         interpret = interpret_default()
-    call = pl.pallas_call(kernel, interpret=interpret, **kwargs)
+    call = pl.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
 
     def run(*args):
         with jax.enable_x64(False):

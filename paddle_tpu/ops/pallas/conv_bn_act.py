@@ -122,6 +122,7 @@ def _fwd_call(x2, w, scale, shift, res2, relu, block_m, interpret):
         args = (x2, w, scale[None, :], shift[None, :])
     return pallas_call(
         kern,
+        name="conv1x1_bn_act",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, cout), row),

@@ -299,6 +299,7 @@ def _fwd_call(q, k, v, lens, seed, causal, sm_scale, dropout_p, block_q,
     seed_in = seed if seed is not None else jnp.zeros((1,), jnp.int32)
     return pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             _smem_full(bh),
@@ -342,6 +343,7 @@ def _bwd_call(res, g, causal, sm_scale, dropout_p, block_q, block_k,
     dq_kern = functools.partial(_dq_kernel, **common)
     dq = pallas_call(
         dq_kern,
+        name="flash_bwd_dq",
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
             _smem_full(bh),
@@ -362,6 +364,7 @@ def _bwd_call(res, g, causal, sm_scale, dropout_p, block_q, block_k,
     dkv_kern = functools.partial(_dkv_kernel, **common)
     dk, dv = pallas_call(
         dkv_kern,
+        name="flash_bwd_dkv",
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[
             _smem_full(bh),

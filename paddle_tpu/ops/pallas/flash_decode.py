@@ -155,6 +155,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lens,
     )
     out = pallas_call(
         kern,
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,

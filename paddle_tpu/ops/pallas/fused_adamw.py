@@ -104,6 +104,7 @@ def fused_adamw_update(p, m, v, g, lr, bc1, bc2, *, beta1, beta2, eps,
     tile = pl.BlockSpec((br, _LANES), row)
     po, mo, vo = pallas_call(
         kern,
+        name="fused_adamw",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((3,), lambda i: (0,),

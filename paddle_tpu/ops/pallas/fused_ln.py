@@ -142,6 +142,7 @@ def _fwd_call(x2, r2, gamma, beta, eps, block_rows, interpret):
     kern = functools.partial(_fwd_kernel, eps=eps)
     return pallas_call(
         kern,
+        name="fused_add_ln_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, h), row),
@@ -172,6 +173,7 @@ def _bwd_call(dy2, ds2, s2, mu, rstd, gamma, block_rows, interpret):
     vec = lambda i: (0, 0)
     return pallas_call(
         _bwd_kernel,
+        name="fused_add_ln_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, h), row),
@@ -273,6 +275,7 @@ def _fwd_call_y(x2, r2, gamma, beta, eps, block_rows, interpret):
     vec = lambda i: (0, 0)
     return pallas_call(
         functools.partial(_fwd_kernel_y, eps=eps),
+        name="fused_add_ln_y_fwd",
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, h), row),
@@ -300,6 +303,7 @@ def _bwd_call_y(dy2, x2, r2, mu, rstd, gamma, block_rows, interpret):
     vec = lambda i: (0, 0)
     return pallas_call(
         _bwd_kernel_y,
+        name="fused_add_ln_y_bwd",
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, h), row),
