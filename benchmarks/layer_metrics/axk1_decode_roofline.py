@@ -1,0 +1,23 @@
+"""Share of its roofline that one A.X-K1 decode step reaches: the least
+time to read the weights outside the routed experts once, each routed
+expert that got a row (`moe_experts_hit` per step x its three matrices)
+and the live latent cache, all at the width the loop reads them, or to do
+the step's operations, over `jit_decode`'s device time per step."""
+from benchmarks import axk1_read as r
+from benchmarks.kernels import axk1_step as k
+
+
+def read(run, trace):
+    per = r.routing_per_step(run)
+    steps = r.traced_steps(run, trace)
+    if per is None or steps is None:
+        return None
+    cfg = run["config"]
+    least = r.least_ms(
+        k.decode_step_bytes(cfg, r.BYTES[cfg["serve"]["weight_dtype"]],
+                            r.BYTES[run["engine"]["cache_dtype"]],
+                            run["mean_live_tokens"], per["moe_experts_hit"]),
+        k.decode_step_ops(cfg, run["mean_live_slots"],
+                          run["mean_live_tokens"],
+                          per["moe_local_assignments"]), run["peak"])
+    return 100.0 * least / (steps[0] / steps[1] * 1e3)

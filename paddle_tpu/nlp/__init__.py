@@ -23,6 +23,9 @@ from .llama import (  # noqa: F401
     LlamaConfig, LlamaModel, LlamaForCausalLM,
     LlamaPretrainingCriterion, LLAMA_CONFIGS,
 )
+from .axk1 import (  # noqa: F401
+    AXK1Config, AXK1Model, AXK1ForCausalLM, AXK1_CONFIGS,
+)
 from .tokenizer import (  # noqa: F401
     BasicTokenizer, WordpieceTokenizer, BertTokenizer, GPTTokenizer,
 )

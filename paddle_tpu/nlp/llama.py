@@ -117,7 +117,7 @@ def _init_attr(cfg):
                                         std=cfg.initializer_range))
 
 
-def apply_rope(x, positions, theta):
+def apply_rope(x, positions, theta, inv_freq=None):
     """Rotary embedding, HF/paddlenlp half-split convention:
     x [B, S, H, D]; positions [S] (absolute, shared across the batch)
     or [B, S] (per-row — the paged serving decode, where every slot
@@ -125,9 +125,12 @@ def apply_rope(x, positions, theta):
     last-dim halves; out = x*cos + rot*sin with cos/sin of
     freqs = pos * theta^(-2i/D) repeated over halves. Computed
     in-trace (no tables) so cached decode's dynamic offset (positions
-    = cache_index + arange) compiles into the one decode program."""
+    = cache_index + arange) compiles into the one decode program.
+    `inv_freq` [D/2] replaces theta's frequencies (a scaled RoPE such
+    as YaRN's blend: nlp/axk1.py)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     freqs = positions.astype(jnp.float32)[..., None] * inv  # [..., D/2]
     cos = jnp.concatenate([jnp.cos(freqs), jnp.cos(freqs)], axis=-1)
     sin = jnp.concatenate([jnp.sin(freqs), jnp.sin(freqs)], axis=-1)

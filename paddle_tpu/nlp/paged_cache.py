@@ -21,6 +21,13 @@ loop stays one compiled XLA program —
   validity is carried by `positions` ([num_slots] int32 = tokens
   already cached) and attention masks keys at index >= positions+1.
 
+Which pool a layer has is the model's to say (`cache_spec_of`): keys and
+values by head as above (`KVCacheSpec`: GPT, Llama), or, for latent
+attention, ONE pool `[P, ps, W]` of the rows every head shares and no
+value pool (`LatentCacheSpec`, `PagedLatentCache`: nlp/axk1.py). Page
+table, trash page, positions and the engine's page accounting are the
+same for both.
+
 Cache dtypes: float32 / bfloat16 store K/V directly; int8 stores
 per-token-per-head symmetric-quantized rows with an f32 scale sidecar
 `[Hkv, P, page_size, 1]` (the trailing singleton keeps the Mosaic lane
@@ -53,8 +60,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedLayerCache", "PrefixIndex", "alloc_pages",
-           "prefix_fingerprints", "quantize_rows",
+__all__ = ["PagedLayerCache", "PagedLatentCache", "KVCacheSpec",
+           "LatentCacheSpec", "LatentRows", "AUX_COUNTERS", "cache_spec_of",
+           "PrefixIndex", "alloc_pages",
+           "prefix_fingerprints", "quantize_rows", "write_token_latent",
+           "write_prompt_latent", "latent_paged_attention",
            "write_token_kv", "write_prompt_kv", "paged_attention_ref",
            "xla_attention_form",
            "paged_update_and_attend", "paged_layer_forward",
@@ -65,9 +75,19 @@ __all__ = ["PagedLayerCache", "PrefixIndex", "alloc_pages",
 # pages beyond a request's allocation
 TRASH_PAGE = 0
 
+# what an expert layer counts per forward, in the order of the int32
+# vector it hands back through its cache (`PagedLatentCache.aux`,
+# `LatentRows.aux`) and the engine sums per kind of program: assignments
+# that fell on experts held here, held experts that got at least one
+# row, rows the router saw
+AUX_COUNTERS = ("moe_local_assignments", "moe_experts_hit",
+                "moe_routed_tokens")
+
 _INT8_MAX = 127.0
 # rows a matrix product needs before the TPU compiler keeps it on the MXU
 _MXU_ROWS = 8
+# the minor dimension of a TPU tile
+_LANES = 128
 
 
 class PagedLayerCache:
@@ -96,6 +116,10 @@ class PagedLayerCache:
                                self.positions, k_scale=k_scale,
                                v_scale=v_scale, use_flash=self.use_flash)
 
+    def arrays(self):
+        """The pool arrays as the engine carries them between programs."""
+        return self.k_pages, self.v_pages, self.k_scale, self.v_scale
+
     @property
     def page_size(self):
         return self.k_pages.shape[2]
@@ -103,6 +127,33 @@ class PagedLayerCache:
     @property
     def quantized(self):
         return self.k_scale is not None
+
+
+class PagedLatentCache:
+    """One latent-attention layer's view of the paged cache: ONE pool
+    `[P, ps, W]` whose rows are `[c_kv | k_rope]` (what every head
+    shares), beside the same page table and positions a PagedLayerCache
+    carries. `aux` is what the layer hands back beside its pages: a small
+    int32 vector of counters (the expert layer's routing counts), None
+    where the layer has none. Not a pytree, like PagedLayerCache."""
+
+    __slots__ = ("pages", "page_table", "positions", "aux")
+
+    def __init__(self, pages, page_table, positions, aux=None):
+        self.pages = pages              # [P, ps, W]
+        self.page_table = page_table    # [B, MP] int32
+        self.positions = positions      # [B] int32 tokens already cached
+        self.aux = aux
+
+    def replaced(self, pages, aux=None):
+        return PagedLatentCache(pages, self.page_table, self.positions, aux)
+
+    def arrays(self):
+        return (self.pages,)
+
+    @property
+    def page_size(self):
+        return self.pages.shape[1]
 
 
 def alloc_pages(num_pages, page_size, kv_heads, head_dim, cache_dtype):
@@ -118,6 +169,76 @@ def alloc_pages(num_pages, page_size, kv_heads, head_dim, cache_dtype):
         return (k, v, jnp.zeros(shape[:3] + (1,), jnp.float32),
                 jnp.zeros(shape[:3] + (1,), jnp.float32))
     return k, v, None, None
+
+
+class KVCacheSpec:
+    """What a model with per-head keys and values tells the serving
+    engine about one layer's cache: the pool is the pair
+    `[Hkv, P, ps, D]` (+ the int8 scale sidecars), a cached forward
+    hands back dense `(k, v)` of `[1, S, Hkv, D]` per layer."""
+
+    latent = False
+
+    def __init__(self, kv_heads, head_dim):
+        self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
+
+    def alloc(self, num_pages, page_size, cache_dtype):
+        return alloc_pages(num_pages, page_size, self.kv_heads,
+                           self.head_dim, cache_dtype)
+
+    def view(self, arrays, page_table, positions, use_flash=False):
+        k, v, ks, vs = arrays
+        return PagedLayerCache(k, v, page_table, positions, k_scale=ks,
+                               v_scale=vs, use_flash=use_flash)
+
+    def prompt_rows(self, layer):
+        """The dense rows one layer's cached forward returned."""
+        return layer[0], layer[1]
+
+    def write_prompt(self, arrays, rows, pages_vec):
+        return write_prompt_kv(*arrays, *rows, pages_vec)
+
+
+class LatentCacheSpec:
+    """A latent-attention layer's cache: one pool `[P, ps, Wp]`, one row
+    per token, no value pool and no quantized form. A row holds the W
+    numbers the model caches, zero-padded to the lane width (`Wp`, the
+    next multiple of 128: 576 -> 640). That is what the TPU's default
+    tiled layout would occupy for a minor dimension of 576 anyway, and
+    said outright it keeps the pool row-major everywhere: left at 576
+    the compiler holds the pool transposed (`{1,2,0}`, no padding)
+    outside the decode loop and copies all of it in and out of the loop
+    every dispatch."""
+
+    latent = True
+
+    def __init__(self, width):
+        self.width = int(width)
+        self.pool_width = -(-self.width // _LANES) * _LANES
+
+    def alloc(self, num_pages, page_size, cache_dtype):
+        return (jnp.zeros((num_pages, page_size, self.pool_width),
+                          jnp.dtype(cache_dtype)),)
+
+    def view(self, arrays, page_table, positions, use_flash=False):
+        return PagedLatentCache(arrays[0], page_table, positions)
+
+    def prompt_rows(self, layer):
+        return (layer.rows,)
+
+    def write_prompt(self, arrays, rows, pages_vec):
+        return (write_prompt_latent(arrays[0], rows[0], pages_vec),)
+
+
+def cache_spec_of(model):
+    """The per-layer cache layout `model` serves with: its own
+    `cache_spec()` where it has one, else keys and values by its
+    configuration's heads."""
+    if hasattr(model, "cache_spec"):
+        return model.cache_spec()
+    cfg = model.config
+    return KVCacheSpec(getattr(cfg, "num_key_value_heads", 0)
+                       or cfg.num_attention_heads, cfg.head_dim)
 
 
 def quantize_rows(x):
@@ -199,6 +320,74 @@ def write_prompt_kv(k_pages, v_pages, k_scale, v_scale, k_full, v_full,
     return (k_pages.at[:, pages_vec].set(kb.astype(k_pages.dtype)),
             v_pages.at[:, pages_vec].set(vb.astype(v_pages.dtype)),
             None, None)
+
+
+class LatentRows:
+    """What a latent layer's cached (prefill) forward hands back: the
+    prompt's dense `[1, S, W]` rows and the layer's counters."""
+
+    __slots__ = ("rows", "aux")
+
+    def __init__(self, rows, aux=None):
+        self.rows, self.aux = rows, aux
+
+
+def _to_width(x, width):
+    """x zero-padded along its last axis to the pool's row width."""
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                   + ((0, width - x.shape[-1]),))
+
+
+@jax.named_scope("latent_kv_write")
+def write_token_latent(cache: PagedLatentCache, rows):
+    """One token per slot into the latent pool. rows [B, W]; inactive
+    slots carry an all-trash table row and position 0 (the engine's
+    contract), so the write is full-width. One index per (page, row), a
+    window of one row: the pool keeps its layout (see write_token_kv)."""
+    ps = cache.page_size
+    pos = cache.positions
+    page = jnp.take_along_axis(cache.page_table, (pos // ps)[:, None],
+                               axis=1)[:, 0]
+    rows = _to_width(rows, cache.pages.shape[-1])
+    return cache.pages.at[page, pos % ps].set(rows.astype(cache.pages.dtype))
+
+
+@jax.named_scope("latent_kv_write")
+def write_prompt_latent(pages, rows, pages_vec):
+    """Prefill write: rows [1, S_b, W] (S_b a multiple of the page size)
+    into the pages `pages_vec` names, as write_prompt_kv does."""
+    ps = pages.shape[1]
+    rows = _to_width(rows, pages.shape[-1])
+    blocks = rows[0].reshape(rows.shape[1] // ps, ps, rows.shape[-1])
+    return pages.at[pages_vec].set(blocks.astype(pages.dtype))
+
+
+@jax.named_scope("latent_attention")
+def latent_paged_attention(q, pages, page_table, lens, v_width, sm_scale):
+    """Absorbed-form latent attention over the paged pool, plain XLA.
+
+    q [B, H, W] (each head's `[q_nope W_UK^T | q_rope]`); pages
+    [P, ps, Wp] rows `[c_kv | k_rope | zeros]`; the values are the rows'
+    first `v_width` numbers, so one gathered copy serves both products.
+    Returns float32 [B, H, v_width] (`sum p c_kv`, before W_UV).
+
+    Always the gathered form: every head of a slot attends the same
+    rows, so the in-place form (q against the whole pool) would multiply
+    B x H query rows with every slot's pages, B times the work. The
+    second product runs over the whole rows and its first `v_width`
+    columns are kept: slicing the gathered rows first writes a second
+    copy of them. The operand rule is paged_attention_ref's: the rows
+    stay in the cache's dtype into both products, q and the unnormalised
+    exponentials are rounded to it, scores, softmax and sums are
+    float32."""
+    live = _live_keys(page_table, lens, pages.shape[1])     # [B, MP, ps]
+    with jax.named_scope("page_gather"):
+        rows = pages[page_table]                            # [B, MP, ps, Wp]
+    q = _to_width(q, rows.shape[-1])
+    s = jnp.einsum("bhw,bmrw->bhmr", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * sm_scale
+    return _softmax_product(s, live[:, None].astype(jnp.float32), rows,
+                            "bhmr,bmrw->bhw")[..., :v_width]
 
 
 def xla_attention_form(num_pages, batch, table_width):
@@ -310,13 +499,14 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lens,
     return jnp.swapaxes(out, 0, 1).astype(q.dtype)
 
 
-def _rope_rows(x, positions, theta):
+def _rope_rows(x, positions, theta, inv_freq=None):
     """RoPE for single-token rows: x [B, H, D], positions [B] — the
     per-slot-offset case of llama.apply_rope (ONE shared formula: a
     convention drift between prefill and paged decode would silently
     break K parity)."""
     from .llama import apply_rope
-    return apply_rope(x[:, None], positions[:, None], theta)[:, 0]
+    return apply_rope(x[:, None], positions[:, None], theta,
+                      inv_freq)[:, 0]
 
 
 def paged_layer_forward(q, k, v, cache: PagedLayerCache, out_proj,

@@ -61,7 +61,7 @@ from ..observability.metrics import MetricsRegistry
 from ..resilience import faults
 from ..resilience.retry import call_with_retries
 from ..tensor import Tensor
-from .paged_cache import PagedLayerCache, PrefixIndex, alloc_pages, \
+from .paged_cache import AUX_COUNTERS, PrefixIndex, cache_spec_of, \
     prefix_fingerprints, write_prompt_kv, xla_attention_form, TRASH_PAGE
 
 __all__ = ["ServingEngine", "ServeRequest"]
@@ -134,7 +134,11 @@ class ServingEngine:
     """Continuous-batching decode over a fixed slot pool.
 
     model: GPTForCausalLM / LlamaForCausalLM (anything whose attention
-    layers understand the PagedLayerCache contract). All requests share
+    layers understand the PagedLayerCache contract), or a model that
+    names another per-layer cache through `cache_spec()`
+    (AXK1ForCausalLM: one latent pool a layer; the prefix cache, an int8
+    cache, speculative verify, use_flash=True and AOT export are refused
+    for it by name). All requests share
     one sampling strategy (greedy when temperature==0, else
     temperature/top-k sampling) — the strategy is baked into the one
     compiled decode program.
@@ -250,11 +254,13 @@ class ServingEngine:
         self.model = model
         cfg = model.config
         self.cfg = cfg
-        self.kv_heads = (getattr(cfg, "num_key_value_heads", 0)
-                         or cfg.num_attention_heads)
-        self.groups = cfg.num_attention_heads // self.kv_heads
+        # the per-layer cache layout comes from the model: keys and values
+        # by head (GPT, Llama) or one latent pool (paged_cache.*CacheSpec)
+        self.cache_spec = spec = cache_spec_of(model)
         self.num_layers = cfg.num_hidden_layers
-        self.head_dim = cfg.head_dim
+        if not spec.latent:
+            self.kv_heads, self.head_dim = spec.kv_heads, spec.head_dim
+            self.groups = cfg.num_attention_heads // self.kv_heads
         self.page_size = int(page_size)
         self.max_slots = int(max_slots)
         self.max_pages_per_seq = -(-int(max_seq_len) // self.page_size)
@@ -271,15 +277,19 @@ class ServingEngine:
         if self.cache_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError(f"cache_dtype {cache_dtype!r}: expected "
                              "float32 | bfloat16 | int8")
-        from ..ops.attention import paged_flash_available
-        self.use_flash = paged_flash_available(self.head_dim,
-                                               self.page_size, use_flash)
-        # which attention the decode program is built with, as health()
-        # names it: the Pallas kernel, or the plain-XLA form the shapes
-        # select (paged_cache.xla_attention_form)
-        self.decode_attention = "paged_kernel" if self.use_flash else \
-            xla_attention_form(self.num_pages, self.max_slots,
-                               self.max_pages_per_seq)
+        if spec.latent:
+            self.use_flash = False
+            self.decode_attention = "latent_gathered"
+        else:
+            from ..ops.attention import paged_flash_available
+            self.use_flash = paged_flash_available(
+                self.head_dim, self.page_size, use_flash)
+            # which attention the decode program is built with, as
+            # health() names it: the Pallas kernel, or the plain-XLA form
+            # the shapes select (paged_cache.xla_attention_form)
+            self.decode_attention = "paged_kernel" if self.use_flash else \
+                xla_attention_form(self.num_pages, self.max_slots,
+                                   self.max_pages_per_seq)
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         self.sampling_seed = int(seed)  # published in health() so the
@@ -311,6 +321,20 @@ class ServingEngine:
         if spec_draft is None:
             spec_draft = os.environ.get("PADDLE_TPU_SPEC_DRAFT", "ngram")
         self.spec_draft = spec_draft
+        if spec.latent:
+            # what the latent cache does not serve yet is refused here,
+            # by name: never silently served by another path
+            for asked, what in (
+                    (prefix_cache, "prefix_cache=True (no sharing of "
+                     "latent pages yet; pass prefix_cache=False)"),
+                    (self.cache_dtype == "int8", "cache_dtype='int8'"),
+                    (spec_decode, "spec_decode=True (speculative verify)"),
+                    (use_flash is True, "use_flash=True (no paged latent "
+                     "kernel)")):
+                if asked:
+                    raise ValueError(
+                        f"{type(model).__name__} serves from a latent "
+                        f"paged cache, which does not support {what}")
         if profile is None:
             profile = os.environ.get(
                 "PADDLE_TPU_PROFILE", "0").lower() in ("1", "true", "on")
@@ -332,9 +356,8 @@ class ServingEngine:
         self._mem_capacity_bytes = mem_capacity_bytes
 
         self._params, self._buffers = model.raw_state()
-        self._pages = [alloc_pages(self.num_pages, self.page_size,
-                                   self.kv_heads, self.head_dim,
-                                   self.cache_dtype)
+        self._pages = [spec.alloc(self.num_pages, self.page_size,
+                                  self.cache_dtype)
                        for _ in range(self.num_layers)]
         self._quantized = self.cache_dtype == "int8"
 
@@ -588,6 +611,16 @@ class ServingEngine:
         self.decode_seconds = 0.0
         self.decode_tokens = 0
         self.decode_dispatches = 0
+        # what a model's layers count per dispatch (an expert layer's
+        # routing: AUX_COUNTERS), summed per kind of program; stays empty
+        # for a model whose layers count nothing
+        self.aux_counts = {}
+
+    def _add_aux(self, program, aux):
+        """Add one dispatch's per-layer counters ([layers, 3] int32, read
+        after the dispatch's own host sync) into `aux_counts[program]`."""
+        total = np.asarray(aux, np.int64).sum(axis=0)
+        self.aux_counts[program] = self.aux_counts.get(program, 0) + total
 
     def _status_counter(self, status):
         c = self._m_req.get(status)
@@ -1273,6 +1306,12 @@ class ServingEngine:
                           "top_k": self.top_k,
                           "seed": self.sampling_seed},
              "compile_counts": self.compile_counts()}
+        if self.aux_counts:
+            def named(v):
+                return dict(zip(AUX_COUNTERS, (int(x) for x in v)))
+            h["moe"] = dict(named(sum(self.aux_counts.values())),
+                            by_program={k: named(v) for k, v in
+                                        self.aux_counts.items()})
         if self.prefix is not None:
             st = self.prefix.stats()
             st["occupancy"] = self._g_prefix_occ.value
@@ -1375,24 +1414,30 @@ class ServingEngine:
         return self.tracer.jit(name, wrapped, **kw)
 
     def _layer_caches(self, pages, page_table, positions):
-        return [PagedLayerCache(k, v, page_table, positions,
-                                k_scale=ks, v_scale=vs,
-                                use_flash=self.use_flash)
-                for (k, v, ks, vs) in pages]
+        return [self.cache_spec.view(arrays, page_table, positions,
+                                     use_flash=self.use_flash)
+                for arrays in pages]
 
     @staticmethod
     def _unwrap_pages(new_caches):
         def arr(x):
             return x._value if isinstance(x, Tensor) else x
-        return [(arr(c.k_pages), arr(c.v_pages),
-                 None if c.k_scale is None else arr(c.k_scale),
-                 None if c.v_scale is None else arr(c.v_scale))
+        return [tuple(None if a is None else arr(a) for a in c.arrays())
                 for c in new_caches]
+
+    @staticmethod
+    def _layer_aux(new_caches):
+        """[layers with counters, 3] int32 of what the layers counted in
+        this forward, or None for a model whose layers count nothing."""
+        aux = [a for a in (getattr(c, "aux", None) for c in new_caches)
+               if a is not None]
+        return jnp.stack(aux) if aux else None
 
     def _model_token_step(self, params, buffers, tokens, pages,
                           page_table, positions):
         """One batched single-token forward through the paged cache.
-        tokens [B] int32; returns (last_logits [B, V] f32, new pages)."""
+        tokens [B] int32; returns (last_logits [B, V] f32, new pages,
+        the layers' counters or None)."""
         caches = self._layer_caches(pages, page_table, positions)
         out = functional_call(
             self.model, params, buffers, Tensor(tokens[:, None]),
@@ -1402,7 +1447,7 @@ class ServingEngine:
         logits = logits_t._value if isinstance(logits_t, Tensor) \
             else logits_t
         return (logits[:, -1].astype(jnp.float32),
-                self._unwrap_pages(new_caches))
+                self._unwrap_pages(new_caches), self._layer_aux(new_caches))
 
     def _build_decode_fn(self):
         steps = self.steps_per_dispatch
@@ -1414,7 +1459,7 @@ class ServingEngine:
             def step(carry, _):
                 (pages, seq_lens, last, done, emitted) = carry
                 live = active & ~done
-                logits, pages = self._model_token_step(
+                logits, pages, aux = self._model_token_step(
                     params, buffers, last, pages, page_table, seq_lens)
                 # token index e = emitted-so-far keys the draw:
                 # fold_in(base, e) — the stream is a function of the
@@ -1427,12 +1472,16 @@ class ServingEngine:
                 done = done | (live & stop)
                 seq_lens = seq_lens + live.astype(jnp.int32)
                 last = jnp.where(live, nxt, last)
-                return (pages, seq_lens, last, done, emitted), nxt
+                return (pages, seq_lens, last, done, emitted), (nxt, aux)
 
             carry = (pages, seq_lens, last_tokens, done, emitted)
-            carry, toks = jax.lax.scan(step, carry, None, length=steps)
+            carry, (toks, aux) = jax.lax.scan(step, carry, None,
+                                              length=steps)
             pages, seq_lens, last, done, emitted = carry
-            return (toks, pages, seq_lens, last, done, emitted)
+            out = (toks, pages, seq_lens, last, done, emitted)
+            # a model whose layers count (AUX_COUNTERS) returns the
+            # dispatch's sums as one more small array
+            return out if aux is None else out + (aux.sum(axis=0),)
 
         # donate the page pool (arg 2): decode updates it in place
         return self._counting("decode", decode, donate_argnums=(2,))
@@ -1474,7 +1523,7 @@ class ServingEngine:
             pt_f = jnp.repeat(page_table, k1, axis=0)
             pt_f = jnp.where((pos_f >= self.max_seq_len)[:, None],
                              jnp.int32(TRASH_PAGE), pt_f)
-            logits, pages = self._model_token_step(
+            logits, pages, _ = self._model_token_step(
                 params, buffers, toks_f, pages, pt_f, pos_f)
             idx_f = (emitted[:, None] + offs[None, :]).reshape(-1)
             keys = jax.vmap(jax.random.fold_in)(
@@ -1504,19 +1553,22 @@ class ServingEngine:
             def arr(x):
                 return x._value if isinstance(x, Tensor) else x
 
+            spec = self.cache_spec
             new_pages, dense_kv = [], []
-            for (k, v, ks, vs), layer in zip(pages, caches):
-                kd, vd = arr(layer[0]), arr(layer[1])
-                new_pages.append(write_prompt_kv(
-                    k, v, ks, vs, kd, vd, pages_vec))
+            for arrays, layer in zip(pages, caches):
+                rows = tuple(arr(r) for r in spec.prompt_rows(layer))
+                new_pages.append(spec.write_prompt(arrays, rows,
+                                                   pages_vec))
                 # the dense prompt K/V ride back out so the prefix
                 # index can pin host-side f32 copies of shareable
                 # pages — device buffers, no extra compute
-                dense_kv.append((kd, vd))
+                dense_kv.append(rows)
             last = jax.lax.dynamic_index_in_dim(
                 logits[0], true_len - 1, keepdims=False)
             tok = self._sample(last[None, :], key)[0]
-            return tok, new_pages, dense_kv
+            aux = self._layer_aux(caches)
+            out = (tok, new_pages, dense_kv)
+            return out if aux is None else out + (aux,)
 
         fn = self._counting(f"prefill_{bucket}", prefill,
                             donate_argnums=(2,))
@@ -1972,12 +2024,14 @@ class ServingEngine:
         t_pre = time.perf_counter()
         with self._phase(f"prefill_{bucket}"):
             with self._watch(f"prefill_{bucket}"):
-                tok, new_pages, dense_kv = fn(
+                tok, new_pages, dense_kv, *aux = fn(
                     self._params, self._buffers, self._pages,
                     jnp.asarray(ids), jnp.int32(lp),
                     jnp.asarray(pages_vec), key)
             self._pages = new_pages
             tok = int(tok)  # host sync: the first token exists NOW
+            if aux:
+                self._add_aux("prefill", aux[0])
         self._m_ttft.observe(time.monotonic() - req.submitted_at)
         # the int(tok) sync above bounds the span at real prefill work
         self.spans.add(f"prefill_{bucket}", t_pre, tid=f"req{req.rid}",
@@ -2144,7 +2198,7 @@ class ServingEngine:
             # dispatch and an injected stall look identical to health()
             faults.maybe_sleep("slow_step", self._rounds)
             (toks, pages, seq_lens, last, done,
-             emitted) = call_with_retries(
+             emitted, *aux) = call_with_retries(
                 dispatch, retries=self.dispatch_retries,
                 retryable=retryable_for(self.donate),
                 stats=self.retry_stats)
@@ -2177,6 +2231,8 @@ class ServingEngine:
         self.decode_seconds += self.last_dispatch_s
         self.decode_tokens += n_new
         self.decode_dispatches += 1
+        if aux:
+            self._add_aux("decode", aux[0])
         # histograms ride the sync that already happened above — one
         # count-weighted observe per dispatch, nothing per token
         self._m_dispatch.observe(self.last_dispatch_s)
