@@ -81,6 +81,8 @@ SIZES = {
             (8, 8, 4, 64, 128, 8),                  # GQA, 32 q heads
             (12, 16, 1, 128, 128, 16),              # gpt3-1.3B serve cell
         ],
+        # (slots, heads, row width, v_width, page_size, max_pages)
+        latent=[(32, 64, 576, 512, 128, 32)],       # serve-axk1-closed32
         ln=dict(rows=8192, hidden=(768, 1024)),
         adamw=(50304, 1024),                        # the embedding leaf
         conv=[  # (m, cin, cout, residual): ResNet-50 b256 bottlenecks
@@ -296,7 +298,10 @@ def _value_and_grads(fn, args, cot_key):
 
 
 def phase_kernels(sz, ctx):
-    from paddle_tpu.nlp.paged_cache import paged_attention_ref, quantize_rows
+    from paddle_tpu.nlp.paged_cache import (LatentCacheSpec,
+                                            latent_paged_attention,
+                                            paged_attention_ref,
+                                            quantize_rows)
     from paddle_tpu.ops.attention import reference_attention
     from paddle_tpu.ops.pallas import conv_bn_act, fused_ln
     from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
@@ -403,6 +408,35 @@ def phase_kernels(sz, ctx):
                 return _nerr(got, want)
             case(f"paged_flash_decode b{b} hkv{hkv} g{g} d{d} ps{ps} "
                  f"pages/slot {mp}", dtype, run)
+
+    # -- paged latent decode (absorbed form) -----------------------------
+    for (b, h, w, vw, ps, mp) in sz["latent"]:
+        def run(b=b, h=h, w=w, vw=vw, ps=ps, mp=mp):
+            ks = jax.random.split(jax.random.fold_in(key, 300), 3)
+            wp = LatentCacheSpec(w).pool_width
+            q = _rand(ks[0], (b, h, w), jnp.float32)
+            # every row of the pool holds numbers, the trash page's too:
+            # nothing past a slot's length may reach its result
+            pool = _rand(ks[1], (1 + b * mp, ps, wp), jnp.bfloat16)
+            pool = pool.at[:, :, w:].set(0)
+            # every slot owns a shuffled private set of pages, but slot 0:
+            # inactive, an all-trash table row and length 0
+            table = (1 + jax.random.permutation(ks[2], b * mp)
+                     ).reshape(b, mp).astype(jnp.int32).at[0].set(0)
+            cap = ps * mp
+            # then one key, a page, a page and one, the full table; the
+            # rest spread between
+            lens = jnp.asarray([0, 1, ps, ps + 1, cap] + [
+                1 + (j * 7919) % cap for j in range(5, b)], jnp.int32)
+            got = jax.jit(lambda *a: latent_paged_attention(
+                *a, vw, 0.1, use_flash=True))(q, pool, table, lens)
+            want = _exact(jax.jit(lambda *a: latent_paged_attention(
+                *a, vw, 0.1)), q, pool, table, lens)
+            if bool(jnp.any(got[0] != 0)):
+                raise AssertionError("the inactive slot's row is not zero")
+            return _nerr(got, want)
+        case(f"latent_decode b{b} h{h} w{w} v{vw} ps{ps} pages/slot {mp}",
+             "bfloat16", run)
 
     # -- fused residual add + LayerNorm, both variants, fwd + bwd --------
     for hidden in sz["ln"]["hidden"]:

@@ -401,7 +401,8 @@ class AXK1Attention(Layer):
                            w[:, :, :dn], preferred_element_type=jnp.float32)
         o_lat = latent_paged_attention(
             jnp.concatenate([q_lat, q_rope], axis=-1), pages,
-            cache.page_table, pos + 1, r, _softmax_scale(cfg))
+            cache.page_table, pos + 1, r, _softmax_scale(cfg),
+            use_flash=cache.use_flash)
         o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(w.dtype), w[:, :, dn:],
                        preferred_element_type=jnp.float32)
         out = _mm(o.reshape(o.shape[0], 1, heads * dv), self.o_proj._value)

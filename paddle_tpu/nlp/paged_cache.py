@@ -135,18 +135,22 @@ class PagedLatentCache:
     shares), beside the same page table and positions a PagedLayerCache
     carries. `aux` is what the layer hands back beside its pages: a small
     int32 vector of counters (the expert layer's routing counts), None
-    where the layer has none. Not a pytree, like PagedLayerCache."""
+    where the layer has none. `use_flash` is trace-time-static kernel
+    routing, as PagedLayerCache's. Not a pytree, like PagedLayerCache."""
 
-    __slots__ = ("pages", "page_table", "positions", "aux")
+    __slots__ = ("pages", "page_table", "positions", "aux", "use_flash")
 
-    def __init__(self, pages, page_table, positions, aux=None):
+    def __init__(self, pages, page_table, positions, aux=None,
+                 use_flash=False):
         self.pages = pages              # [P, ps, W]
         self.page_table = page_table    # [B, MP] int32
         self.positions = positions      # [B] int32 tokens already cached
         self.aux = aux
+        self.use_flash = bool(use_flash)
 
     def replaced(self, pages, aux=None):
-        return PagedLatentCache(pages, self.page_table, self.positions, aux)
+        return PagedLatentCache(pages, self.page_table, self.positions, aux,
+                                use_flash=self.use_flash)
 
     def arrays(self):
         return (self.pages,)
@@ -221,7 +225,8 @@ class LatentCacheSpec:
                           jnp.dtype(cache_dtype)),)
 
     def view(self, arrays, page_table, positions, use_flash=False):
-        return PagedLatentCache(arrays[0], page_table, positions)
+        return PagedLatentCache(arrays[0], page_table, positions,
+                                use_flash=use_flash)
 
     def prompt_rows(self, layer):
         return (layer.rows,)
@@ -363,23 +368,31 @@ def write_prompt_latent(pages, rows, pages_vec):
 
 
 @jax.named_scope("latent_attention")
-def latent_paged_attention(q, pages, page_table, lens, v_width, sm_scale):
-    """Absorbed-form latent attention over the paged pool, plain XLA.
+def latent_paged_attention(q, pages, page_table, lens, v_width, sm_scale,
+                           use_flash=False):
+    """Absorbed-form latent attention over the paged pool.
 
     q [B, H, W] (each head's `[q_nope W_UK^T | q_rope]`); pages
     [P, ps, Wp] rows `[c_kv | k_rope | zeros]`; the values are the rows'
-    first `v_width` numbers, so one gathered copy serves both products.
+    first `v_width` numbers, so a row read once serves both products.
     Returns float32 [B, H, v_width] (`sum p c_kv`, before W_UV).
 
-    Always the gathered form: every head of a slot attends the same
-    rows, so the in-place form (q against the whole pool) would multiply
-    B x H query rows with every slot's pages, B times the work. The
-    second product runs over the whole rows and its first `v_width`
-    columns are kept: slicing the gathered rows first writes a second
-    copy of them. The operand rule is paged_attention_ref's: the rows
-    stay in the cache's dtype into both products, q and the unnormalised
-    exponentials are rounded to it, scores, softmax and sums are
-    float32."""
+    use_flash: the Pallas kernel (ops/pallas/latent_decode.py), which
+    walks each slot's live pages where they lie. Else plain XLA, always
+    the gathered form: every head of a slot attends the same rows, so the
+    in-place form (q against the whole pool) would multiply B x H query
+    rows with every slot's pages, B times the work; it copies every
+    slot's whole table width, dead pages included, and reads the copy
+    twice. Its second product runs over the whole rows and its first
+    `v_width` columns are kept: slicing the gathered rows first writes a
+    second copy of them. One operand rule for both (paged_attention_ref's):
+    the rows stay in the cache's dtype into both products, q and the
+    unnormalised exponentials are rounded to it, scores, softmax and sums
+    are float32."""
+    if use_flash:
+        from ..ops.pallas.latent_decode import latent_flash_decode
+        return latent_flash_decode(q, pages, page_table, lens, v_width,
+                                   sm_scale)
     live = _live_keys(page_table, lens, pages.shape[1])     # [B, MP, ps]
     with jax.named_scope("page_gather"):
         rows = pages[page_table]                            # [B, MP, ps, Wp]

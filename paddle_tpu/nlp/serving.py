@@ -137,8 +137,8 @@ class ServingEngine:
     layers understand the PagedLayerCache contract), or a model that
     names another per-layer cache through `cache_spec()`
     (AXK1ForCausalLM: one latent pool a layer; the prefix cache, an int8
-    cache, speculative verify, use_flash=True and AOT export are refused
-    for it by name). All requests share
+    cache, speculative verify and AOT export are refused for it by
+    name). All requests share
     one sampling strategy (greedy when temperature==0, else
     temperature/top-k sampling) — the strategy is baked into the one
     compiled decode program.
@@ -158,7 +158,13 @@ class ServingEngine:
         gathered where the pool outgrows what the tables can name),
         None what ops/attention.paged_flash_available resolves from
         the shapes (the XLA path today; no environment variable is
-        read). health()["decode_attention"] names the one built.
+        read). A latent cache (ops/attention.latent_flash_available):
+        True its paged kernel (ops/pallas/latent_decode.py), False the
+        gathered XLA form, None the kernel on a TPU, where the chip
+        measured it faster, and the gathered form elsewhere.
+        health()["decode_attention"] names the one built
+        ("paged_kernel" | "in_place" | "gathered" |
+        "latent_paged_kernel" | "latent_gathered").
     steps_per_dispatch: decode tokens per compiled call (the scan
         length) — admission/eviction happen at dispatch boundaries.
     admission_policy: what to do with the queue head when pages run
@@ -278,8 +284,10 @@ class ServingEngine:
             raise ValueError(f"cache_dtype {cache_dtype!r}: expected "
                              "float32 | bfloat16 | int8")
         if spec.latent:
-            self.use_flash = False
-            self.decode_attention = "latent_gathered"
+            from ..ops.attention import latent_flash_available
+            self.use_flash = latent_flash_available(use_flash)
+            self.decode_attention = "latent_paged_kernel" \
+                if self.use_flash else "latent_gathered"
         else:
             from ..ops.attention import paged_flash_available
             self.use_flash = paged_flash_available(
@@ -328,9 +336,7 @@ class ServingEngine:
                     (prefix_cache, "prefix_cache=True (no sharing of "
                      "latent pages yet; pass prefix_cache=False)"),
                     (self.cache_dtype == "int8", "cache_dtype='int8'"),
-                    (spec_decode, "spec_decode=True (speculative verify)"),
-                    (use_flash is True, "use_flash=True (no paged latent "
-                     "kernel)")):
+                    (spec_decode, "spec_decode=True (speculative verify)")):
                 if asked:
                     raise ValueError(
                         f"{type(model).__name__} serves from a latent "

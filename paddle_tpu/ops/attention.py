@@ -166,6 +166,23 @@ def paged_flash_available(head_dim, page_size, use_flash=None):
     return use_flash is True
 
 
+def latent_flash_available(use_flash=None):
+    """Which attention a latent paged cache's decode program is built
+    with (nlp/paged_cache.latent_paged_attention): True the paged Pallas
+    kernel (ops/pallas/latent_decode.py), False the gathered XLA form.
+    The two obey one operand rule and agree on the chip (chip_smoke.py
+    kernels phase).
+
+    use_flash: True -> the kernel anywhere (interpret mode off a TPU),
+    False -> the gathered form, None -> the kernel where the backend is
+    a TPU, because the chip measures it faster there (PERF.md, PR 29),
+    else the gathered form, the faster one under the interpreter. Every
+    shape the engine builds fits the kernel (pages of a multiple of 8
+    rows, rows a multiple of 128 wide by LatentCacheSpec's construction).
+    No environment variable is read."""
+    return use_flash is True or (use_flash is None and _on_tpu())
+
+
 def paged_flash_decode(q, k_pages, v_pages, page_table, lens,
                        k_scale=None, v_scale=None, sm_scale=None):
     """Paged GQA decode attention — Pallas kernel entry used by

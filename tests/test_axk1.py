@@ -246,7 +246,6 @@ def test_a_skewed_router_loses_no_row():
     (dict(), "prefix_cache"),                   # the engine's default is on
     (dict(prefix_cache=False, cache_dtype="int8"), "int8"),
     (dict(prefix_cache=False, spec_decode=True), "spec_decode"),
-    (dict(prefix_cache=False, use_flash=True), "use_flash"),
 ])
 def test_the_engine_refuses_by_name_what_the_latent_cache_lacks(
         monkeypatch, kwargs, names):

@@ -22,9 +22,10 @@ from paddle_tpu.observability.trace import (  # noqa: E402
 
 # the package re-exports functions under its modules' names
 (_common, conv_bn_act, flash_attention, flash_decode, fused_adamw,
- fused_ln) = (importlib.import_module("paddle_tpu.ops.pallas." + m)
-              for m in ("_common", "conv_bn_act", "flash_attention",
-                        "flash_decode", "fused_adamw", "fused_ln"))
+ fused_ln, latent_decode) = (
+    importlib.import_module("paddle_tpu.ops.pallas." + m)
+    for m in ("_common", "conv_bn_act", "flash_attention", "flash_decode",
+              "fused_adamw", "fused_ln", "latent_decode"))
 
 
 # -- programs ----------------------------------------------------------------
@@ -82,6 +83,15 @@ def _paged_decode():
         q, p, p, table, lens, interpret=True)), (q, pages)
 
 
+def _latent_decode():
+    q = jnp.ones((2, 4, 40), jnp.float32)
+    pages = jnp.ones((5, 16, 128), jnp.float32)
+    table = jnp.ones((2, 3), jnp.int32)
+    lens = jnp.asarray([5, 40], jnp.int32)
+    return (lambda q, p: latent_decode.latent_flash_decode(
+        q, p, table, lens, 32, 0.2, interpret=True)), (q, pages)
+
+
 def _ln(entry, grad):
     x = jnp.ones((4, 32, 64), jnp.float32)
     g = jnp.ones((64,), jnp.float32)
@@ -114,6 +124,7 @@ KERNEL_ENTRIES = [
      {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     ("flash_decode_dense", _dense_decode, {"flash_fwd"}),
     ("paged_flash_decode", _paged_decode, {"flash_decode"}),
+    ("latent_flash_decode", _latent_decode, {"latent_decode"}),
     ("fused_add_ln", lambda: _ln(fused_ln.fused_add_layer_norm, False),
      {"fused_add_ln_fwd"}),
     ("fused_add_ln_grad", lambda: _ln(fused_ln.fused_add_layer_norm, True),
@@ -145,7 +156,7 @@ def test_kernel_names_are_unique_over_the_call_sites():
                 text = src.read()
             sites += len(re.findall(r"\bpallas_call\(", text))
             names += re.findall(r'\bname="(\w+)"', text)
-    assert sites == len(names) == len(set(names)) == 10
+    assert sites == len(names) == len(set(names)) == 11
 
 
 def test_a_kernel_without_a_name_is_an_error():
