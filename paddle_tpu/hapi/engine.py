@@ -751,9 +751,7 @@ class Engine:
         inside a compiled lax.scan.
 
         TPU-native perf lever: each dispatch to a (remote) backend costs
-        ~ms of latency; a K-step scan amortizes it K-fold (bench.py
-        --scan-steps uses the same construction — this is its public
-        form). Semantics match K train_batch calls exactly (per-step rng
+        ~ms of latency; a K-step scan amortizes it K-fold. Semantics match K train_batch calls exactly (per-step rng
         folding, update counters), with the learning rate CONSTANT
         across the window unless lr_values [K] supplies a schedule; the
         LR scheduler object is advanced by the caller per update as

@@ -21,8 +21,8 @@ between subsystems:
   numbered name instead of overwriting an earlier incident's record.
 
 Stdlib-only by contract: paddle_tpu.observability.flightrec loads
-this module straight from its file in lean bench workers (see
-bench._obs_mod), so nothing here may import jax, numpy, or any
+this module straight from its file in the stdlib-only tools (see
+tools/_obs.py), so nothing here may import jax, numpy, or any
 sibling package.
 """
 from __future__ import annotations

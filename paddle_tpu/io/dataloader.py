@@ -63,7 +63,7 @@ class DataLoader:
         # re-imports the framework per worker (~seconds), which only
         # pays for itself on decode/augment-heavy input pipelines —
         # exactly where the reference's worker processes earn their
-        # keep (bench.py --input-pipeline measures the crossover).
+        # keep (the crossover on the chip's host: not measured).
         self.use_process_workers = use_process_workers
         if use_process_workers and num_workers == 0:
             # __iter__ takes the num_workers==0 inline path before

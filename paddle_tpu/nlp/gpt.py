@@ -83,7 +83,7 @@ class GPTConfig:
     # one Pallas pass (y=LN(x+r) and s=x+r in a single read of the
     # operands — the add->reduce boundary XLA keeps as a kernel break;
     # step anatomy r4 put the MFU gap in exactly these elementwise HBM
-    # passes). A/B lever: bench.py --fused-ln. ref:
+    # passes). Default off, not measured on the chip. ref:
     # paddle/phi/kernels/fusion/fused_layernorm_residual_dropout_bias.
     fused_ln: bool = False
     # sequence/context parallelism for long sequences: '' (off), 'ring'

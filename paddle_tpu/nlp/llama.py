@@ -96,9 +96,9 @@ LLAMA_CONFIGS = {
                        num_hidden_layers=2, num_attention_heads=4,
                        num_key_value_heads=2, intermediate_size=128,
                        max_position_embeddings=128),
-    # single-chip bench flagship for the GQA family: a TinyLlama-class
-    # 1.1B shape (GQA 4:16); like gpt3-1.3B it needs bf16 Adam moments
-    # + remat to fit one 16GB chip (bench.py worker_llama defaults)
+    # single-chip size of the GQA family: a TinyLlama-class 1.1B shape
+    # (GQA 4:16); like gpt3-1.3B, training it on one 16GB chip needs
+    # bf16 Adam moments + remat
     "llama-1b": dict(vocab_size=32000, hidden_size=2048,
                      num_hidden_layers=22, num_attention_heads=16,
                      num_key_value_heads=4, intermediate_size=5632,

@@ -20,8 +20,9 @@ one of a small, fixed set of compiled XLA programs —
   never shapes, so the steady state compiles nothing.
 
 Zero-recompile is not aspirational: every jitted program runs under a
-trace counter and `compile_counts()` exposes them; `bench.py --serve`
-asserts the counts freeze after warmup on every ladder rung.
+trace counter and `compile_counts()` exposes them; the benchmark's
+serve cells refuse a window in which anything compiled, and
+tests/test_serving.py asserts the counts freeze after warmup.
 
 The cache is shared GPT/Llama (both models' attention layers route a
 `PagedLayerCache` through `paged_update_and_attend`): GQA models cache
@@ -612,8 +613,8 @@ class ServingEngine:
                      "token per live slot)"))
         # decode-dispatch accounting: batched-decode throughput is THE
         # serving metric (wall time also pays per-request prefill,
-        # which is batch-1 by construction); bench.py --serve reads
-        # these for the ladder's tok/s rows
+        # which is batch-1 by construction); the benchmark's
+        # decode_ms_per_step reads these
         self.decode_seconds = 0.0
         self.decode_tokens = 0
         self.decode_dispatches = 0
@@ -877,8 +878,8 @@ class ServingEngine:
 
     def compile_counts(self):
         """Trace counts per compiled program (name -> count). Steady
-        state == this dict stops changing; bench.py --serve asserts
-        it per ladder rung."""
+        state == this dict stops changing
+        (tests/test_serving.py)."""
         return dict(self._trace_counts)
 
     @property

@@ -6,8 +6,7 @@ recorder) — docs/observability.md.
 Layering: ``metrics``, ``telemetry``, ``exporter``, ``spans``,
 ``contprof``, ``dtrace``, ``slo``, ``flightrec``, ``history``,
 ``tenancy``, ``trafficrec`` and ``sentinel`` are pure stdlib
-(importable from the jax-free bench orchestrator and worker
-processes); ``trace`` and ``introspect`` import jax lazily inside
+(importable from jax-free tools and worker processes); ``trace`` and ``introspect`` import jax lazily inside
 the wrapping calls.
 """
 from . import (contprof, dtrace, exporter, flightrec,  # noqa: F401

@@ -27,7 +27,7 @@ Design contracts, matching the rest of the observability plane:
   ``min_hz``); when the stack trie hits its node bound the sample's
   weight lands on the deepest existing node and
   ``profile_samples_dropped_total`` counts the truncation.
-- **Stdlib-only, standalone-loadable** (``bench._obs_mod``): no
+- **Stdlib-only, standalone-loadable** (``tools/_obs.py``): no
   intra-package imports at module scope; ``io/atomic`` is file-loaded
   lazily for the write-then-rename persistence discipline.
 
@@ -123,7 +123,7 @@ class phase:
 def _introspecting_tids():
     """Thread ids currently inside an AOT introspection replay —
     published by introspect.py under either its package name or the
-    bench standalone-load key. No import: if the module was never
+    standalone-load key of tools/_obs.py. No import: if the module was never
     loaded, no replay can be running."""
     for key in ("paddle_tpu.observability.introspect",
                 "_bench_obs_introspect"):
@@ -460,8 +460,8 @@ class ContinuousProfiler:
 
     def flamegraph_html(self, path=None, window_s=None, title=None):
         """Self-contained flamegraph: the folded profile is embedded
-        as a JSON ``<script>`` block (machine-parseable back out — the
-        profile_smoke stage does exactly that) and a small inline
+        as a JSON ``<script>`` block (machine-parseable back out,
+        tests/test_contprof.py) and a small inline
         renderer draws the flame as nested divs. No external assets,
         openable from a triage dir years later."""
         folded = self.fold(window_s=window_s)

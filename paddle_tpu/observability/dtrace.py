@@ -66,7 +66,7 @@ def now():
 def _suppressed():
     try:
         from .introspect import introspecting
-    except ImportError:  # standalone file-load (bench._obs_mod)
+    except ImportError:  # standalone file-load (tools/_obs.py)
         return False
     return introspecting()
 

@@ -23,7 +23,7 @@ releases the port immediately (``allow_reuse_address`` covers the
 TIME_WAIT rebind); the serving thread is a daemon, so SIGTERM'd
 processes exit without joining it.
 
-Stdlib-only by contract (standalone-loadable via bench._obs_mod);
+Stdlib-only by contract (standalone-loadable via tools/_obs.py);
 the /report handler imports sibling modules lazily and degrades to
 an empty section when they are unavailable.
 """
@@ -41,7 +41,7 @@ __all__ = ["MetricsExporter", "serve_metrics"]
 def _finite(obj):
     """Non-finite floats -> None (RFC-valid JSON). Duplicated across
     the stdlib-only observability modules on purpose: each stays
-    standalone-loadable (bench._obs_mod) with no intra-package imports
+    standalone-loadable (tools/_obs.py) with no intra-package imports
     at module scope."""
     if isinstance(obj, float):
         return obj if math.isfinite(obj) else None
@@ -102,8 +102,8 @@ class MetricsExporter:
     ``/metrics`` route measures a throwaway render FIRST, observes it,
     then serves a fresh render — so the served exposition already
     contains the observation and stays byte-identical to a subsequent
-    in-process ``to_prometheus()`` (the telemetry_smoke parity
-    contract).
+    in-process ``to_prometheus()`` (the parity contract,
+    tests/test_observability.py).
     """
 
     def __init__(self, registry=None, port=0, host="127.0.0.1",
@@ -282,7 +282,7 @@ class MetricsExporter:
 
         Handler.protocol_version = "HTTP/1.1"
         # a close()d exporter's port rebinds immediately (no TIME_WAIT
-        # stall between bench rungs/tests): http.server's HTTPServer
+        # stall between tests): http.server's HTTPServer
         # already sets allow_reuse_address
         self._server = ThreadingHTTPServer((host, port), Handler)
         self._server.daemon_threads = True

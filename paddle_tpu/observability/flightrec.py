@@ -11,9 +11,8 @@ plus a registry snapshot and the recompile report to
 nulls out), always atomic, never clobbering an earlier dump (numeric
 suffixes).
 
-Dump directory resolution (at dump time, not construction — the env
-may be set per campaign stage): explicit ``run_dir`` >
-``PADDLE_TPU_FLIGHT_DIR`` > ``BENCH_TELEMETRY_DIR`` >
+Dump directory resolution (at dump time, not construction): explicit
+``run_dir`` > ``PADDLE_TPU_FLIGHT_DIR`` >
 ``<tempdir>/paddle_tpu_flight``. Never the CWD — a chaos suite must
 not litter the repo root.
 
@@ -46,8 +45,8 @@ def _atomic():
     """The shared crash-safe-write helper (io/atomic.py), resolved
     LAZILY so this module stays stdlib-only at import: the package
     path would pull paddle_tpu.io (numpy/jax) eagerly, and the
-    standalone file-load mode (bench lean workers, see bench._obs_mod)
-    has no package context at all — there the helper is loaded
+    standalone file-load mode (tools/_obs.py) has no package context
+    at all — there the helper is loaded
     straight from its file, which is fine because atomic.py is itself
     stdlib-only by contract."""
     global _atomic_mod
@@ -79,7 +78,6 @@ def _finite(obj):
 
 def _default_dir():
     return (os.environ.get("PADDLE_TPU_FLIGHT_DIR")
-            or os.environ.get("BENCH_TELEMETRY_DIR")
             or os.path.join(tempfile.gettempdir(), "paddle_tpu_flight"))
 
 

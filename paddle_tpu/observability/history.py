@@ -3,7 +3,7 @@
 Everything the observability stack exposed before this module was
 instantaneous: the MetricsRegistry is a point-in-time snapshot, the
 SLOTracker forgets past its horizon, and regression detection existed
-only as the offline ``tools/metrics_diff.py`` canary at campaign end.
+only as the offline ``tools/metrics_diff.py`` gate over two dumps.
 This module keeps *history*: a ``HistoryStore`` scrapes any
 ``MetricsRegistry`` on a cadence into per-series rings with a
 raw → 10s → 60s downsampling ladder, and answers the questions a
@@ -34,7 +34,7 @@ does not parse — a snapshot truncated at ANY byte offset reloads
 cleanly, never duplicates a sample, and loses at most the tail
 (fuzz-pinned by tests/test_history.py).
 
-Stdlib-only by contract: loadable standalone via ``bench._obs_mod``
+Stdlib-only by contract: loadable standalone via ``tools/_obs.py``
 (tools/metrics_diff.py reads archives with no jax, no package
 import). The io/atomic helper is resolved lazily with a file-load
 fallback, exactly like flightrec does.

@@ -1,6 +1,6 @@
 """Compiled-executable introspection — what XLA actually built.
 
-Every FLOP/MFU number the bench reported before this module was
+Every FLOP/MFU number reported before this module was
 *analytic*: a hand-derived 6N+12Lhs convention multiplied by a
 hardcoded peak. The compiler knows better — each compiled executable
 carries its own ``cost_analysis()`` (real FLOPs, bytes accessed) and
@@ -37,7 +37,7 @@ builds may return None or omit the ``flops`` key — both normalize to
 a plain dict (or None) here. ``memory_analysis()`` is a
 ``CompiledMemoryStats`` when available, None otherwise.
 
-Stdlib-only at import (bench's lean workers file-load this module);
+Stdlib-only at import (tools/_obs.py file-loads this module);
 jax is imported inside functions. When loaded standalone the relative
 registry import is unavailable — pass ``registry=`` explicitly there.
 """
@@ -57,7 +57,7 @@ __all__ = ["resolve_peak_flops", "normalize_cost", "normalize_memory",
 # jax's device_kind string (e.g. "TPU v5 lite", "TPU v4"). MFU is
 # reported against the bf16 peak regardless of the dtype actually
 # used, so an fp32 run shows honestly low MFU rather than flattering
-# itself (the long-standing bench.py convention).
+# itself (the benchmark's `mfu.train` follows the same convention).
 PEAK_FLOPS_BY_DEVICE_KIND = (
     ("v5 lite", 197e12), ("v5litepod", 197e12), ("v5e", 197e12),
     ("v5p", 459e12),
@@ -130,7 +130,7 @@ def resolve_peak_flops(device_kind=None):
 
 def measured_mfu(flops, step_seconds, peak=None):
     """compiled FLOPs / step wall / peak, or None when any leg is
-    missing (the honest null the bench stanzas record)."""
+    missing (an honest null, never a made-up number)."""
     if not flops or not step_seconds:
         return None
     if peak is None:

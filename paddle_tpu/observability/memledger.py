@@ -25,11 +25,11 @@ Design contracts, matching the rest of the observability plane:
   object and registers NO ``mem_*`` series (the spec-decode/profiler
   dormancy contract), so legacy goldens stay byte-identical.
 - **Never silent.** The residual series carries what the seams missed;
-  ``residual_alarm`` trips on growth past the baseline (the mem_smoke
-  leak drill proves it fires), and audit callbacks (e.g. the prefix
+  ``residual_alarm`` trips on growth past the baseline
+  (tests/test_memledger.py proves it fires), and audit callbacks (e.g. the prefix
   refcount audit) count failures into
   ``engine_mem_audit_failures_total``.
-- **Stdlib-only, standalone-loadable** (``bench._obs_mod``): no
+- **Stdlib-only, standalone-loadable** (``tools/_obs.py``): no
   intra-package imports at module scope; jax is imported lazily and
   its absence degrades to "no ground truth", never an exception.
 

@@ -1,8 +1,8 @@
 """Typed metrics registry — the single place run facts accumulate.
 
 Pure stdlib (no jax, no numpy): the registry must be importable from
-the jax-free bench orchestrator, DataLoader worker processes and
-validation tools alike. Three metric types, Prometheus-shaped:
+jax-free tools and DataLoader worker processes alike. Three metric
+types, Prometheus-shaped:
 
 - Counter: monotonically increasing total (requests served, steps
   skipped). ``inc(n)`` only; resets happen at the registry level.
@@ -14,8 +14,8 @@ validation tools alike. Three metric types, Prometheus-shaped:
 
 Snapshots are plain dicts and MERGEABLE: ``registry.merge(snapshot)``
 folds another process/rung's snapshot in (counters and histogram
-buckets add, gauges last-write-wins), which is how bench.py combines
-per-rung serving registries into the campaign-level metrics.json.
+buckets add, gauges last-write-wins), which is how a router folds
+its replicas' registries into one.
 
 Label support is deliberately minimal: a metric series is identified
 by (name, sorted labels); ``registry.counter(name, labels={...})``
@@ -299,8 +299,7 @@ class MetricsRegistry:
 
     def reset(self):
         """Zero every series IN PLACE (handles held by instrumented
-        code stay valid) — bench uses this to split warmup from the
-        timed window."""
+        code stay valid) — splits a warmup from a timed window."""
         with self._lock:
             for m in self._metrics.values():
                 m.reset()
@@ -351,7 +350,7 @@ class MetricsRegistry:
     def dump(self, path, extra=None):
         """Write the snapshot (plus optional extra sections, e.g. the
         RecompileTracer report) as JSON to `path` — the metrics.json
-        artifact bench/campaign stages emit. Always RFC-valid JSON: a
+        artifact tools/metrics_diff.py compares. Always RFC-valid JSON: a
         NaN gauge (e.g. train_loss on a storm's last step) is nulled,
         never emitted as a bare NaN token jq/JS consumers reject."""
         doc = self.snapshot()

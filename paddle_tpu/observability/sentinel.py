@@ -32,11 +32,11 @@ the live value leaves it:
   ``alerting`` into ``health()["anomaly"]`` exactly like SLO burn
   alerts, so placement/operators/the supervisor see it live.
 - ``replay()``: run the same detector offline over a SAVED history
-  snapshot — how the campaign proves the sentinel stays quiet across
-  the committed clean golden wave and how ``tools/fleet_top.py
+  snapshot — how tools/history_smoke.py proves the sentinel stays
+  quiet across the committed clean golden wave and how ``tools/fleet_top.py
   --snapshot`` triages a post-mortem archive.
 
-Stdlib-only by contract (standalone-loadable via bench._obs_mod);
+Stdlib-only by contract (standalone-loadable via tools/_obs.py);
 flightrec/metrics are sibling stdlib modules, imported lazily.
 """
 from __future__ import annotations
@@ -376,7 +376,7 @@ class AnomalySentinel:
             try:
                 from . import contprof
                 extra["profile"] = contprof.current_profile()
-            except ImportError:  # standalone file-load (bench._obs_mod)
+            except ImportError:  # standalone file-load (tools/_obs.py)
                 pass
             # ...and where device memory stood: the active memory
             # ledger's segment tree + headroom forecast (None when no
@@ -384,7 +384,7 @@ class AnomalySentinel:
             try:
                 from . import memledger
                 extra["memory"] = memledger.current_memory()
-            except ImportError:  # standalone file-load (bench._obs_mod)
+            except ImportError:  # standalone file-load (tools/_obs.py)
                 pass
             flightrec.dump("fleet_anomaly", extra=extra)
         except Exception:  # noqa: BLE001

@@ -43,7 +43,7 @@ _PERF_BASE = time.perf_counter()
 def _finite(obj):
     """Non-finite floats -> None (RFC-valid JSON for jq/Perfetto).
     (Duplicated across the observability modules by contract — each
-    stays standalone-loadable from bench._obs_mod.)"""
+    stays standalone-loadable from tools/_obs.py.)"""
     if isinstance(obj, float):
         return obj if math.isfinite(obj) else None
     if isinstance(obj, dict):
@@ -64,7 +64,7 @@ def _suppressed():
     spans to a timeline (or perturb a span-count assertion)."""
     try:
         from .introspect import introspecting
-    except ImportError:  # standalone file-load (bench._obs_mod)
+    except ImportError:  # standalone file-load (tools/_obs.py)
         return False
     return introspecting()
 

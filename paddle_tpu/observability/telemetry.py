@@ -227,7 +227,7 @@ class TelemetryCallback:
         self.metrics_path = None
         self.spans_path = None
         # sibling modules are optional under standalone file-loading
-        # (bench._obs_mod loads telemetry.py without the package)
+        # (tools/_obs.py loads telemetry.py without the package)
         try:
             from . import introspect as _intro
             from .flightrec import note as _fnote

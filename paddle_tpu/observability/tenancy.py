@@ -31,7 +31,7 @@ The ``tenant=`` label itself rides ``FleetRouter.submit`` →
 (KV-page-seconds, admission queue wait) and stamps them on each
 result, the router accounts fleet-level totals at resolve time.
 
-Stdlib-only by contract (standalone-loadable via bench._obs_mod).
+Stdlib-only by contract (standalone-loadable via tools/_obs.py).
 """
 from __future__ import annotations
 

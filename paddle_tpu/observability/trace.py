@@ -1,6 +1,6 @@
 """RecompileTracer — every XLA trace becomes a queryable run fact.
 
-"Zero-recompile" was a bench-only assertion (ServingEngine counted
+"Zero-recompile" was a private assertion (ServingEngine counted
 traces privately; the Engine counted nothing). This tracer is the one
 mechanism both ride: ``tracer.jit(site, fn, **jit_kwargs)`` returns a
 jitted callable whose body bumps a per-site counter exactly when jax
@@ -22,7 +22,7 @@ records an event carrying:
 Per-call steady-state overhead is two dict reads and a perf_counter —
 no device sync, no shape walking. Tracers register in a process-wide
 WeakSet; ``report_all()`` merges every live tracer's report into the
-run report bench.py exports next to metrics.json.
+run report exported next to metrics.json.
 """
 from __future__ import annotations
 
@@ -41,7 +41,7 @@ __all__ = ["RecompileTracer", "get_tracer", "all_tracers", "report_all",
 # the lock (report_all builds dicts under it) — a plain Lock would
 # self-deadlock there
 _all_lock = threading.RLock()
-# strong refs, deliberately: a bench worker's Engine (and its tracer)
+# strong refs, deliberately: a short-lived Engine (and its tracer)
 # is often garbage before the end-of-run report is written — a weak
 # registry would silently drop exactly the sites the report is for.
 # Cost is bounded per tracer (counts + a maxlen event deque), and a
@@ -115,7 +115,7 @@ class RecompileTracer:
         import jax
         try:
             from .introspect import introspecting
-        except ImportError:  # standalone file-load (bench._obs_mod)
+        except ImportError:  # standalone file-load (tools/_obs.py)
             def introspecting():
                 return False
         counts = self._counts

@@ -52,7 +52,7 @@ Capture discipline:
 
 Cost is metered in the owner's registry (``fleet_capture_*``,
 catalogue in docs/observability.md). Stdlib-only by contract
-(standalone-loadable via bench._obs_mod; io/atomic resolved lazily
+(standalone-loadable via tools/_obs.py; io/atomic resolved lazily
 with the same file-load fallback flightrec/history use).
 """
 from __future__ import annotations
@@ -97,7 +97,7 @@ def _atomic():
 def _suppressed():
     try:
         from .introspect import introspecting
-    except ImportError:  # standalone file-load (bench._obs_mod)
+    except ImportError:  # standalone file-load (tools/_obs.py)
         return False
     return introspecting()
 

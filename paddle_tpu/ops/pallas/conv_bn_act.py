@@ -1,9 +1,8 @@
 """Fused 1x1-conv + BatchNorm scale/shift + ReLU (+ residual add) Pallas
 TPU kernel — the diagnosed ResNet-50 HBM-bandwidth wall.
 
-Why: ResNet-50 sits at 0.77x the A100 share at MFU 0.139
-(campaign_out/summary_first_window_0347.json) with the roofline pinned
-on the bottleneck 1x1 convs (SURVEY §6): each is a skinny matmul whose
+Why (ResNet-50 on the chip: not measured; the argument is from the
+shapes): the bottleneck 1x1 convs are skinny matmuls, each of whose
 output makes extra full HBM round trips through the BN normalize, the
 ReLU, and the residual add.
 In NHWC a 1x1 conv IS a [M, Cin] @ [Cin, Cout] matmul (M = N*H*W), so

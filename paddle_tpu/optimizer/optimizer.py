@@ -367,8 +367,8 @@ class Adam(Optimizer):
         self._decoupled = False
         # one-HBM-pass Pallas update for large fp32 leaves (ref: the
         # CUDA fused adamw_kernel) — r4 step anatomy measured the jnp
-        # chain at ~2x its bandwidth floor. Opt-in A/B lever
-        # (bench --fused-adamw); ineligible leaves (small, amsgrad,
+        # chain at ~2x its bandwidth floor. Opt-in, not measured on
+        # the chip; ineligible leaves (small, amsgrad,
         # master weights, bf16 moments) keep the jnp path.
         self._fused_kernel = bool(fused_kernel)
         # reduced-precision moment storage (bf16 halves optimizer HBM

@@ -29,5 +29,5 @@ __all__ = ["faults", "preemption", "TrainGuard", "Watchdog",
            "call_with_retries", "backoff_schedule", "is_transient"]
 
 # arm any env-specified faults at first import of the subsystem — the
-# chaos_smoke campaign stage and the SIGTERM drill ride this
+# SIGTERM drill rides this
 faults.load_env()

@@ -1,4 +1,4 @@
-"""Deterministic fault-injection registry — the chaos-campaign backbone.
+"""Deterministic fault-injection registry — the chaos suites' backbone.
 
 A production TPU stack dies from unhandled faults (NaN storms, pod
 preemption, page exhaustion, wedged dispatches), not slow kernels.
@@ -10,7 +10,7 @@ CPU tier-1 with zero nondeterminism:
                          ("nan_grads", {"step": 6})):
         model.fit(...)
 
-or from the environment (chaos_smoke campaign stage)::
+or from the environment::
 
     PADDLE_TPU_FAULTS="nan_grads@10x3,sigterm@25,slow_step@5:seconds=0.5"
 

@@ -9,8 +9,8 @@ errors, OOM of the program itself, assertion failures) must propagate
 untouched.
 
 Deterministic by design: delays are a fixed exponential ladder (no
-jitter by default) so chaos tests assert exact retry counts and the
-campaign replays identically under a fixed seed. Jitter is OPT-IN and
+jitter by default) so chaos tests assert exact retry counts and a
+drill replays identically under a fixed seed. Jitter is OPT-IN and
 itself seeded (``jitter=``/``jitter_seed=``): N fleet replicas
 retrying the same transient fault would otherwise back off in
 lockstep and re-collide as a thundering herd — each replica passes its

@@ -54,8 +54,7 @@ See docs/robustness.md ("Fleet serving") for the contracts and
 docs/observability.md for the fleet_* metric catalogue and the
 "Distributed tracing & SLOs" guide. Chaos suites:
 tests/test_fleet_serving.py + tests/test_fleet_tracing.py (pytest -m
-chaos); campaign stage fleet_chaos_smoke (metrics_diff canary-gated
-against tools/golden/fleet_chaos_metrics.json).
+chaos).
 """
 from .autoscaler import FleetAutoscaler  # noqa: F401
 from .client import ReplicaClient  # noqa: F401

@@ -383,8 +383,8 @@ class FleetAutoscaler:
             self.state = "steady"
             boot_s = now - self._boot_started
             # boot-path accounting: aot (restored from a serving
-            # artifact) vs traced — the autoscale_smoke latency
-            # assertion and the fleet_top BOOT column both read this
+            # artifact) vs traced — the fleet_top BOOT column reads
+            # this
             bi = snap.get("boot") or {}
             mode = str(bi.get("mode") or "traced")
             self._bootmode_counter(mode).inc()

@@ -121,7 +121,6 @@ class FrameReader:
 
 def _default_flight_base():
     return (os.environ.get("PADDLE_TPU_FLIGHT_DIR")
-            or os.environ.get("BENCH_TELEMETRY_DIR")
             or os.path.join(tempfile.gettempdir(), "paddle_tpu_flight"))
 
 

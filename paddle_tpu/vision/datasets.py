@@ -119,7 +119,7 @@ class Cifar100(Cifar10):
 class SyntheticImageNet(Dataset):
     """Deterministic fake ImageNet for throughput benchmarking (the
     reference benchmarks use DALI/file pipelines; perf here is bounded by
-    device compute, which is what bench.py measures)."""
+    device compute)."""
 
     def __init__(self, n=1280, image_size=224, num_classes=1000,
                  transform=None, dtype=np.float32):

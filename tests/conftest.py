@@ -41,7 +41,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos: deterministic fault-injection resilience suite "
-        "(standalone: pytest -m chaos; campaign stage chaos_smoke)")
+        "(standalone: pytest -m chaos)")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -86,32 +86,3 @@ def _gc_per_module():
     collected here since the gen-0 collector above is throttled."""
     yield
     gc.collect()
-
-
-# -- fleet-stage metrics export (campaign canary gate) -----------------------
-# The fleet chaos tests (test_fleet_serving / test_fleet_tracing)
-# register each FleetRouter's registry here; at session end the merged
-# snapshot lands as metrics.json in $BENCH_TELEMETRY_DIR — the
-# artifact tools/tpu_campaign.py's fleet canary gate diffs against the
-# committed golden (tools/golden/fleet_chaos_metrics.json). A no-op
-# outside the campaign (env unset) or when no fleet test ran.
-fleet_stage_registries = []
-
-
-@pytest.fixture(scope="session", autouse=True)
-def _fleet_stage_metrics_export():
-    yield
-    out_dir = os.environ.get("BENCH_TELEMETRY_DIR")
-    if not out_dir or not fleet_stage_registries:
-        return
-    from paddle_tpu.observability.metrics import MetricsRegistry
-    from paddle_tpu.observability.trace import report_all
-    merged = MetricsRegistry()
-    for reg in fleet_stage_registries:
-        try:
-            merged.merge(reg.snapshot())
-        except Exception:  # noqa: BLE001 — one bad registry must not
-            pass           # cost the whole stage its artifact
-    merged.dump(os.path.join(out_dir, "metrics.json"),
-                extra={"recompile_report": report_all(),
-                       "stage": "fleet_chaos"})

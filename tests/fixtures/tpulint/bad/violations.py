@@ -1,9 +1,9 @@
-"""Seeded tpulint violations — the staticcheck gate-trip fixture.
+"""Seeded tpulint violations — the gate-trip fixture.
 
 tests/test_tpulint.py runs ``python -m tools.tpulint --root
 tests/fixtures/tpulint bad`` and asserts exit 1 with exactly this
 finding mix; the ``good/`` twin must exit 0. Together they prove the
-campaign's staticcheck gate in BOTH directions without touching the
+CLI's exit-status gate in BOTH directions without touching the
 shipping tree. (tests/ is outside the default scan targets, so these
 seeds can never leak into the real repo sweep.)
 """

@@ -23,10 +23,7 @@ overload control"):
   ``tools/fleet_replay.py --knob autoscale.<param>`` scores a policy
   offline.
 
-`pytest -m chaos` selects the chaos classes; the campaign's
-fleet_chaos_smoke stage includes this file (the canary golden covers
-the fleet_autoscale_*/fleet_brownout_*/overload counters) and the
-autoscale_smoke stage runs the standalone drill.
+`pytest -m chaos` selects the chaos classes.
 """
 import os
 import time
@@ -112,12 +109,7 @@ def _counter(reg, name, **labels):
     return 0 if c is None else int(c.value)
 
 
-def _register(router):
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
-
-
-def _elastic_fleet(model, register=True, router_kw=None,
+def _elastic_fleet(model, router_kw=None,
                    autoscale_kw=None, n=1):
     """One-replica-plus-autoscaler fleet; spawn_fn builds warmed
     engines (appended to `engines` for cleanup)."""
@@ -141,8 +133,6 @@ def _elastic_fleet(model, register=True, router_kw=None,
     akw.update(autoscale_kw or {})
     asc = FleetAutoscaler(router, lambda i: InprocReplica(
         f"as{i}", build()), **akw)
-    if register:
-        _register(router)
     return router, asc, engines, frozen
 
 
@@ -669,7 +659,6 @@ class TestElasticChaos:
             r2 = FleetRouter.recover(jdir, reps, slos=SLOS,
                                      slo_windows=WINDOWS,
                                      overload_target_ms=5000.0)
-            _register(r2)
             try:
                 post = r2.run_to_completion(timeout_s=120)
                 got = pre + post

@@ -20,9 +20,8 @@ supervision"):
   results are rejected uniformly; journaled placements carry the
   incarnation and recovery treats a bumped incarnation as a fresh
   engine;
-- REAL-process chaos (pytest -m chaos; the slow-marked drills run in
-  the fleet_supervisor_smoke campaign stage with
-  PADDLE_TPU_RUN_SLOW=1): a ServingEngine subprocess SIGKILLed
+- REAL-process chaos (pytest -m chaos; the slow-marked drills run
+  with PADDLE_TPU_RUN_SLOW=1): a ServingEngine subprocess SIGKILLed
   mid-decode fails over token-exactly, the supervisor respawns it
   with a warm boot and health-gates it back into rotation under
   frozen compile counts; a persistent exit-at-boot seed trips the
@@ -100,11 +99,6 @@ def _proc_spec(**kw):
 def _counter(reg, name, **labels):
     c = reg.get(name, labels or None)
     return 0 if c is None else int(c.value)
-
-
-def _register_stage_registry(router):
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
 
 
 # -- wire framing fuzz (satellite) ----------------------------------------
@@ -590,7 +584,7 @@ class TestRouterMembership:
         router.close()
 
 
-# -- real-subprocess chaos drills (campaign: fleet_supervisor_smoke) ------
+# -- real-subprocess chaos drills ----------------------------------------
 
 
 def _wait_for(cond, timeout=180.0, step=None, msg="condition"):
@@ -673,8 +667,8 @@ class TestProcReplicaSmoke:
 @pytest.mark.slow
 class TestProcFleetChaos:
     """THE acceptance drills — real processes, real signals. Slow
-    (several subprocess boots each): the fleet_supervisor_smoke
-    campaign stage runs them with PADDLE_TPU_RUN_SLOW=1."""
+    (several subprocess boots each): PADDLE_TPU_RUN_SLOW=1 runs
+    them."""
 
     def _fleet(self, tmp_path, n=2, sup_kw=None, **rep_kw):
         reps = [ProcReplica(f"p{i}", _proc_spec(),
@@ -686,7 +680,6 @@ class TestProcFleetChaos:
                  backoff_base_s=0.05, backoff_max_s=0.5)
         d.update(sup_kw or {})
         sup = FleetSupervisor(router, **d)
-        _register_stage_registry(router)
         return router, sup, reps
 
     def test_sigkill_mid_decode_failover_respawn_warm_rejoin(
@@ -794,7 +787,6 @@ class TestProcFleetChaos:
                               breaker_window_s=120.0,
                               breaker_cooldown_s=600.0,
                               backoff_base_s=0.05, backoff_max_s=0.2)
-        _register_stage_registry(router)
         try:
             _wait_for(lambda: all(r.state == "serving" for r in reps),
                       300, msg="fleet boot")
@@ -910,7 +902,6 @@ class TestProcFleetChaos:
                               breaker_threshold=4,
                               breaker_window_s=300.0,
                               backoff_base_s=0.05, backoff_max_s=0.2)
-        _register_stage_registry(router)
         try:
             _wait_for(lambda: reps[0].state == "serving", 300,
                       msg="boot")

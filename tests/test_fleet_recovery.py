@@ -15,10 +15,7 @@ torn journal write, transient disk errors — and a successor built by
   resolved twice, restored unpopped results re-delivered once,
   retired rids never resurrected).
 
-`pytest -m chaos` selects the chaos classes; the campaign's
-fleet_recovery_smoke stage runs exactly that (and fleet_chaos_smoke
-includes this file so the fleet canary golden covers the
-fleet_journal_* counters).
+`pytest -m chaos` selects the chaos classes.
 """
 import json
 import os
@@ -100,16 +97,7 @@ def _fleet(model, tmp_path, n=3, router_kw=None, replica_kw=None,
             for i, e in enumerate(engines)]
     jdir = os.path.join(tmp_path, "journal")
     router = FleetRouter(reps, journal_dir=jdir, **(router_kw or {}))
-    _register(router)
     return router, reps, engines, frozen, jdir
-
-
-def _register(router):
-    """Session-end metrics export for the campaign's fleet canary
-    gate (conftest._fleet_stage_metrics_export) — the recovery
-    drills' fleet_journal_* counters ride the same golden."""
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
 
 
 def _drive_until(router, cond, timeout=60.0, results=None):
@@ -244,7 +232,7 @@ class TestRouterJournalUnits:
             router.close()
 
 
-# -- chaos drills (campaign stage: fleet_recovery_smoke) -----------------
+# -- chaos drills --------------------------------------------------------
 
 
 @pytest.mark.chaos
@@ -274,7 +262,6 @@ class TestRouterRecoveryChaos:
         assert any(not p.done for p in router._pending.values()), \
             "drill must crash with work still in flight"
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=90)
             _assert_exactly_once_token_exact(rids, refs, pre, post)
@@ -335,7 +322,6 @@ class TestRouterRecoveryChaos:
             preemption.clear()
         # successor: rejoin the parked replicas, finish the backlog
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=90)
             _assert_exactly_once_token_exact(rids, refs, pre, post)
@@ -371,7 +357,6 @@ class TestRouterRecoveryChaos:
         stats = replay(jdir)[1]
         assert stats["torn_tail_drops"] == 1
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=90)
             _assert_exactly_once_token_exact(rids, refs, pre, post)
@@ -404,7 +389,6 @@ class TestRouterRecoveryChaos:
         rids += [router.submit(p, NEW_TOK) for p in prompts[4:]]
         _crash(router, pre)
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=90)
             _assert_exactly_once_token_exact(rids, refs, pre, post)
@@ -445,7 +429,6 @@ class TestRouterRecoveryChaos:
                 results=pre, timeout=90)
             _crash(router, pre)
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=120)
             _assert_exactly_once_token_exact(rids, refs, pre, post)
@@ -482,7 +465,6 @@ class TestRouterRecoveryChaos:
         _crash(router, pre)
         faults.clear()
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=90)
             allres = {r["id"]: r for r in pre + post}
@@ -531,7 +513,6 @@ class TestRouterRecoveryChaos:
         _crash(router, pre)
         assert not pre, "nothing was popped after the second wave"
         r2 = FleetRouter.recover(jdir, reps)
-        _register(r2)
         try:
             post = r2.run_to_completion(timeout_s=60)
             # exactly the unpopped wave comes back — once

@@ -11,8 +11,7 @@ claims:
 - ``/requests`` serves the recent-resolved index with the archive
   locator (rid/tenant/status/ttft/e2e), mirroring /traces;
 - the committed ``tools/golden/replay_wave.json`` spec equals the
-  generator's output for its seed (drift guard lives in tier-1, not
-  only in the campaign stage);
+  generator's output for its seed (the drift guard);
 - golden-mode replay of a live capture is token-exact per rid with
   frozen compile counts and zero new traces, and the verdict's gates
   fire in BOTH directions (clean passes, an injected replica_slow
@@ -102,7 +101,7 @@ class TestSynthWave:
     def test_committed_golden_matches_generator(self):
         """Tier-1 drift guard: the committed replay_wave.json was
         generated by synth_wave(seed) — a silently changed generator
-        must fail HERE, not first in the campaign."""
+        must fail HERE."""
         with open(GOLDEN_WAVE) as f:
             golden = json.load(f)
         wave = fr.synth_wave(golden["seed"], golden["n"], burst=4,

@@ -21,8 +21,7 @@ Pins the fleet contracts (docs/robustness.md "Fleet serving"):
 Everything drills deterministically on CPU via resilience.faults
 (replica_crash / replica_wedge / replica_slow / scrape_timeout /
 flaky_transport, payload-targeted by replica name). `pytest -m chaos`
-selects the chaos classes; the campaign's fleet_chaos_smoke stage
-runs exactly that.
+selects the chaos classes.
 """
 import time
 
@@ -92,10 +91,6 @@ def _fleet(model, n=3, router_kw=None, **engine_kw):
     frozen = [e.compile_counts() for e in engines]
     reps = [InprocReplica(f"r{i}", e) for i, e in enumerate(engines)]
     router = FleetRouter(reps, **(router_kw or {}))
-    # register for the session-end metrics.json export the campaign's
-    # fleet canary gate diffs (conftest._fleet_stage_metrics_export)
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
     return router, reps, engines, frozen
 
 
@@ -236,7 +231,7 @@ class TestFaultTargeting:
                                match={"replica": "anything"}) is not None
 
 
-# -- chaos suite (campaign stage: fleet_chaos_smoke) ---------------------
+# -- chaos suite ---------------------------------------------------------
 
 
 @pytest.mark.chaos

@@ -25,8 +25,7 @@ tracing & SLOs"):
   stay RFC-valid under NaN/Inf — and fleet compile counts stay
   frozen with tracing enabled.
 
-`pytest -m chaos` selects the chaos classes; the campaign's
-fleet_chaos_smoke stage runs them together with test_fleet_serving.
+`pytest -m chaos` selects the chaos classes.
 """
 import json
 import time
@@ -95,8 +94,6 @@ def _fleet(model, n=3, router_kw=None, **engine_kw):
     frozen = [e.compile_counts() for e in engines]
     reps = [InprocReplica(f"r{i}", e) for i, e in enumerate(engines)]
     router = FleetRouter(reps, **(router_kw or {}))
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
     return router, reps, engines, frozen
 
 
@@ -276,7 +273,7 @@ class TestSLOTracker:
                         SLObjective("a", "availability", target=0.9)])
 
 
-# -- fleet chaos (campaign stage: fleet_chaos_smoke) ---------------------
+# -- fleet chaos ---------------------------------------------------------
 
 
 @pytest.mark.chaos

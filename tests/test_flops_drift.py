@@ -92,14 +92,15 @@ def test_resnet_bottleneck_analytic_tracks_compiled():
 
 
 def test_bench_analytic_convention_tracks_compiled_train_step():
-    """The 6N+12Lhs convention bench.py reports MFU with, against the
-    cost analysis of the REAL compiled train step (fwd+bwd+opt) — the
-    exact pair whose drift `mfu` vs `mfu_measured` now reports. Wider
+    """The 6N+12Lhs convention the benchmark computes `mfu.train` from
+    (`benchmarks/kernels/gpt_step.train_flops_per_token`), against the
+    cost analysis of the REAL compiled train step (fwd+bwd+opt). Wider
     band: the convention ignores the optimizer update and counts
     recompute-free backward."""
     import sys
     sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
-    from bench import build_engine, gpt_flops_per_token
+    from bench import build_engine
+    from benchmarks.kernels.gpt_step import train_flops_per_token
 
     paddle.seed(0)
     batch, seq = 2, 32
@@ -115,7 +116,11 @@ def test_bench_analytic_convention_tracks_compiled_train_step():
     if e is None or not e.get("flops"):
         pytest.skip(f"jax {jax.__version__} exposes no flops for the "
                     "compiled train step")
-    analytic = gpt_flops_per_token(eng.network, seq) * batch * seq
+    cfg = eng.network.config
+    sizes = {k: getattr(cfg, k) for k in (
+        "hidden_size", "intermediate_size", "num_hidden_layers",
+        "vocab_size", "max_position_embeddings")}
+    analytic = train_flops_per_token(sizes, seq) * batch * seq
     ratio = e["flops"] / analytic
     # 6N already includes the fwd+bwd factor; the loose band covers
     # the embedding/softmax/opt work the convention ignores at tiny

@@ -14,7 +14,7 @@ Pins the ISSUE-11 contracts:
   reloads without crashing, never duplicates a sample, and drops at
   most the tail (the journal-fuzz discipline, applied to history);
 - registry_snapshot_at + metrics_diff --history --at/--vs: one
-  archive, any two instants, the canary gate runs on it;
+  archive, any two instants, the gate runs on it;
 - sentinel: quiet through warmup + steady state, fires on a genuine
   excursion (with a parseable fleet_anomaly flight dump + counters),
   re-arms only after the signal clears; offline replay over a saved

@@ -648,7 +648,7 @@ class TestServeRegistryIsolation:
         assert rep["unexpected_retraces"] >= 1
 
     def test_engine_gc_retires_tracer(self):
-        """Engines register tracers STRONGLY (bench reports outlive
+        """Engines register tracers STRONGLY (reports outlive
         the engine) — so a collected Engine must retire its tracer or
         repeated construction grows the live set forever."""
         import gc
@@ -729,69 +729,6 @@ class TestProfilerBridge:
         assert len(prof._exported) == 2
         payloads = {open(p, "rb").read() for p in prof._exported}
         assert payloads == {b"run1", b"run2"}
-
-
-# -- bench worker telemetry (subprocess: the real finalize path) ----------
-
-class TestBenchTelemetry:
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-    def _run(self, code, argv, env_extra, timeout=120):
-        import subprocess
-        import sys as _sys
-        env = dict(os.environ, CAMPAIGN_CHILD="1", **env_extra)
-        return subprocess.run([_sys.executable, "-c", code] + argv,
-                              cwd=self.REPO, env=env,
-                              capture_output=True, text=True,
-                              timeout=timeout)
-
-    def test_probe_worker_telemetry_stays_framework_free(self, tmp_path):
-        """The probe's time-to-first-signal measures the backend
-        handshake — its telemetry must not charge it the full
-        paddle_tpu package import (the stdlib-only observability
-        modules are file-loaded instead, bench._obs_mod)."""
-        code = (
-            "import sys; sys.argv = ['bench.py']\n"
-            "import bench, json, os\n"
-            "bench._TELEMETRY['worker'] = 'probe'\n"
-            "bench.worker_probe()\n"
-            "bench._finalize_worker_telemetry('probe')\n"
-            "assert 'paddle_tpu' not in sys.modules, 'full import paid'\n"
-            "d = os.path.join(bench.CAMPAIGN_OUT, 'telemetry', 'probe')\n"
-            "doc = json.load(open(os.path.join(d, 'metrics.json')))\n"
-            "assert doc['workers'] == ['probe'], doc\n"
-            "print('LEAN-OK')\n")
-        proc = self._run(code, [], {"JAX_PLATFORMS": "cpu",
-                                    "BENCH_CAMPAIGN_DIR": str(tmp_path)})
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        assert "LEAN-OK" in proc.stdout
-
-    def test_metrics_merge_scoped_to_run_id(self, tmp_path):
-        """Cross-worker merge spans ONE bench invocation (shared
-        BENCH_RUN_ID); a re-invocation with the same telemetry dir
-        OVERWRITES — it must not compound the previous run's counters
-        or resurrect its retraces."""
-        code = (
-            "import sys\n"
-            "workers = sys.argv[1:]; sys.argv = ['bench.py']\n"
-            "import bench\n"
-            "for w in workers:\n"
-            "    bench._TELEMETRY.clear()\n"
-            "    bench._TELEMETRY['worker'] = w\n"
-            "    bench._emit('run_note', worker=w)\n"
-            "    bench._finalize_worker_telemetry(w)\n")
-        env = {"BENCH_TELEMETRY_DIR": str(tmp_path),
-               "BENCH_CAMPAIGN_DIR": str(tmp_path)}
-        p = self._run(code, ["w1", "w2"],
-                      {**env, "BENCH_RUN_ID": "r1"}, timeout=60)
-        assert p.returncode == 0, p.stderr[-2000:]
-        doc = json.load(open(tmp_path / "metrics.json"))
-        assert doc["workers"] == ["w1", "w2"]   # same-run merge
-        p = self._run(code, ["w3"],
-                      {**env, "BENCH_RUN_ID": "r2"}, timeout=60)
-        assert p.returncode == 0, p.stderr[-2000:]
-        doc = json.load(open(tmp_path / "metrics.json"))
-        assert doc["workers"] == ["w3"]         # re-invocation overwrote
 
 
 # =========================================================================
@@ -1396,105 +1333,6 @@ class TestMetricsDiffTool:
         p = self._run(a, a, "--fail-on", "nonsense")
         assert p.returncode == 2
         assert "grammar" in p.stderr
-
-
-class TestValidateStagesFlightCheck:
-    """check_flight_dumps: the preflight gate that chaos-family
-    campaign stages actually left their post-mortem dumps."""
-
-    @pytest.fixture()
-    def vs(self, tmp_path, monkeypatch):
-        import sys as _sys
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        monkeypatch.syspath_prepend(os.path.join(repo, "tools"))
-        monkeypatch.syspath_prepend(repo)
-        import validate_stages as mod
-        monkeypatch.setattr(mod, "OUT", str(tmp_path))
-        return mod
-
-    def _summary(self, vs, doc):
-        with open(os.path.join(vs.OUT, "summary.json"), "w") as f:
-            json.dump(doc, f)
-
-    def test_pre_flightrec_archives_not_flagged(self, vs):
-        assert vs.check_flight_dumps() == ([], 0)   # no summary
-        self._summary(vs, {"_telemetry": 1,
-                           "chaos_smoke": {"ok": True}})
-        assert vs.check_flight_dumps() == ([], 0)   # no _flightrec
-
-    def test_completed_chaos_stage_without_dump_is_a_problem(self, vs):
-        self._summary(vs, {"_flightrec": 1,
-                           "chaos_smoke": {"ok": True},
-                           "telemetry_smoke": {"ok": False}})
-        problems, checked = vs.check_flight_dumps()
-        assert checked == 1                       # failed stage skipped
-        assert "left no flight_" in problems[0]
-
-    def test_parseable_dump_passes_torn_dump_fails(self, vs):
-        self._summary(vs, {"_flightrec": 1,
-                           "chaos_smoke": {"ok": True}})
-        td = os.path.join(vs.OUT, "telemetry", "chaos_smoke")
-        os.makedirs(td)
-        with open(os.path.join(td, "flight_rollback.json"), "w") as f:
-            json.dump({"reason": "rollback",
-                       "records": [{"kind": "guard_step"}]}, f)
-        assert vs.check_flight_dumps() == ([], 1)
-        with open(os.path.join(td, "flight_torn.json"), "w") as f:
-            f.write("{torn")
-        problems, _ = vs.check_flight_dumps()
-        assert "unparseable flight dump" in problems[0]
-
-
-class TestValidateStagesCanaryCheck:
-    """check_canary_verdict: a _fleet_canary-marked campaign whose
-    fleet_chaos_smoke completed must carry the metrics_diff gate's
-    verdict file (ISSUE 8 — the gate must not silently never run)."""
-
-    @pytest.fixture()
-    def vs(self, tmp_path, monkeypatch):
-        repo = os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))
-        monkeypatch.syspath_prepend(os.path.join(repo, "tools"))
-        monkeypatch.syspath_prepend(repo)
-        import validate_stages as mod
-        monkeypatch.setattr(mod, "OUT", str(tmp_path))
-        return mod
-
-    def _summary(self, vs, doc):
-        with open(os.path.join(vs.OUT, "summary.json"), "w") as f:
-            json.dump(doc, f)
-
-    def test_pre_gate_archives_and_unrun_stages_not_flagged(self, vs):
-        assert vs.check_canary_verdict() == ([], 0)   # no summary
-        self._summary(vs, {"fleet_chaos_smoke": {"ok": True, "rc": 0}})
-        assert vs.check_canary_verdict() == ([], 0)   # no marker
-        self._summary(vs, {"_fleet_canary": 1})
-        assert vs.check_canary_verdict() == ([], 0)   # never ran
-
-    def test_completed_stage_without_verdict_is_a_problem(self, vs):
-        self._summary(vs, {"_fleet_canary": 1,
-                           "fleet_chaos_smoke": {"ok": True, "rc": 0}})
-        problems, checked = vs.check_canary_verdict()
-        assert checked == 1 and "no verdict" in problems[0]
-
-    def test_parseable_verdict_passes_torn_or_flagless_fails(self, vs):
-        self._summary(vs, {"_fleet_canary": 1,
-                           "fleet_chaos_smoke": {"ok": True, "rc": 0}})
-        td = os.path.join(vs.OUT, "telemetry", "fleet_chaos_smoke")
-        os.makedirs(td)
-        vp = os.path.join(td, "canary_verdict.json")
-        with open(vp, "w") as f:
-            json.dump({"ok": True, "failures": []}, f)
-        assert vs.check_canary_verdict() == ([], 1)
-        with open(vp, "w") as f:
-            json.dump({"failures": []}, f)   # no ok flag
-        problems, _ = vs.check_canary_verdict()
-        assert "no 'ok' flag" in problems[0]
-        with open(vp, "w") as f:
-            f.write("{torn")
-        problems, _ = vs.check_canary_verdict()
-        assert "unparseable canary verdict" in problems[0]
 
 
 class TestGuardOutcomeAfterRollback:

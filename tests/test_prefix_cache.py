@@ -4,9 +4,8 @@ Pins the round-19 contracts (docs/performance.md "Prefix caching"):
 
 - THE invariant: a cache hit may change TTFT, never tokens — ON vs
   OFF streams are token-exact for GPT and Llama/GQA across greedy and
-  top-k sampling and fp32/bf16/int8 KV dtypes (each axis covered on
-  both models; the full cross product lives in the campaign's
-  prefix_cache_smoke + bench serve rungs);
+  top-k sampling and fp32/bf16/int8 KV dtypes (the full cross
+  product, `EXACT_CASES`);
 - fingerprint chain: rolling per-page-boundary digests, page-size
   domain-separated, final prompt position always private (COW is
   structural, not best-effort);
@@ -27,9 +26,7 @@ Pins the round-19 contracts (docs/performance.md "Prefix caching"):
   wave's (independently random) hit rate as zero, and a genuinely
   shared wave as nonzero — the measure-before-build number.
 
-`pytest -m chaos` selects the fleet classes; the campaign's
-fleet_chaos_smoke stage runs exactly that (the router registries
-registered here fold into the canary golden's fleet_prefix_* series).
+`pytest -m chaos` selects the fleet classes.
 
 Engine/warmup tracing dominates this module's wall time, so waves are
 single-bucket (every prompt lands in prefill bucket 32, tail ladder
@@ -212,17 +209,12 @@ class TestPrefixIndex:
 # -- engine: the token-exactness invariant -------------------------------
 
 
-# every sampler and every KV dtype covered on BOTH models (pairing,
-# not cross product — each engine pays ~10s of warmup tracing, and
-# the remaining combos ride prefix_cache_smoke + the bench rungs)
+# both models x both samplers x every KV dtype
 EXACT_CASES = [
-    ("gpt", {}, None),
-    ("gpt", dict(temperature=0.8, top_k=4, seed=11), "bfloat16"),
-    ("gpt", dict(temperature=0.8, top_k=4, seed=11), "int8"),
-    ("llama", {}, "int8"),
-    ("llama", dict(temperature=0.8, top_k=4, seed=11), None),
-    ("llama", {}, "bfloat16"),
-]
+    (which, sampler, cache_dtype)
+    for which in ("gpt", "llama")
+    for sampler in ({}, dict(temperature=0.8, top_k=4, seed=11))
+    for cache_dtype in (None, "bfloat16", "int8")]
 
 
 class TestTokenExactness:
@@ -343,7 +335,7 @@ class TestReplayPrefixStats:
             <= row["expected_hit_requests"]
 
 
-# -- fleet: affinity, counters, journal, failover (campaign chaos) -------
+# -- fleet: affinity, counters, journal, failover (chaos) ----------------
 
 
 def _prefix_fleet(model, n=2, router_kw=None, jdir=None, **engine_kw):
@@ -357,11 +349,6 @@ def _prefix_fleet(model, n=2, router_kw=None, jdir=None, **engine_kw):
     if jdir is not None:
         kw["journal_dir"] = str(jdir)
     router = FleetRouter(reps, **kw)
-    # register for the session-end metrics.json export the campaign's
-    # fleet canary gate diffs (conftest._fleet_stage_metrics_export) —
-    # this is what makes fleet_prefix_* nonzero in the golden
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
     return router, reps, engines, frozen
 
 

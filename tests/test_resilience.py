@@ -14,8 +14,7 @@ in the stack, drilled deterministically via paddle_tpu.resilience.
   with compile_counts() frozen after warmup (zero-recompile survives
   chaos)
 
-Runs as part of tier-1 and standalone as the campaign's chaos_smoke
-stage: pytest -m chaos (seeded, CPU).
+Runs as part of tier-1 and standalone: pytest -m chaos (seeded, CPU).
 """
 import os
 import signal

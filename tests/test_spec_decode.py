@@ -5,9 +5,8 @@ decoding"):
 
 - THE invariant: speculation may change latency, never tokens — ON vs
   OFF streams are token-exact for GPT and Llama/GQA across greedy and
-  top-k sampling and fp32/bf16/int8 KV dtypes (each axis covered on
-  both models; the full cross product rides the campaign's spec_smoke
-  + bench serve rungs). The verify dispatch applies the target
+  top-k sampling and fp32/bf16/int8 KV dtypes (the full cross
+  product, `EXACT_CASES`). The verify dispatch applies the target
   model's own per-(request, token-index) seeded sampler to every
   folded lane, so an accepted draft IS the token plain decode would
   have emitted;
@@ -29,10 +28,7 @@ decoding"):
   feeds fleet_top's SPEC_ACC column, and crash-mid-spec-decode
   failover stays token-exact with speculation ON everywhere.
 
-`pytest -m chaos` selects the fleet classes; the campaign's
-fleet_chaos_smoke stage runs exactly that (the router registries
-registered here fold into the canary golden's fleet_spec_* series —
-the fleet_spec_accepted_total<50% canary's non-vacuity).
+`pytest -m chaos` selects the fleet classes.
 
 Engine/warmup tracing dominates this module's wall time, so waves are
 single-bucket and assertions share engines wherever the contracts
@@ -150,17 +146,12 @@ class TestNgramPropose:
 # -- engine: the token-exactness invariant -------------------------------
 
 
-# every sampler and every KV dtype covered on BOTH models (pairing,
-# not cross product — each engine pays ~10s of warmup tracing, and
-# the remaining combos ride spec_smoke + the bench serve rungs)
+# both models x both samplers x every KV dtype
 EXACT_CASES = [
-    ("gpt", {}, None),
-    ("gpt", dict(temperature=0.8, top_k=4, seed=11), "bfloat16"),
-    ("gpt", dict(temperature=0.8, top_k=4, seed=11), "int8"),
-    ("llama", {}, "int8"),
-    ("llama", dict(temperature=0.8, top_k=4, seed=11), None),
-    ("llama", {}, "bfloat16"),
-]
+    (which, sampler, cache_dtype)
+    for which in ("gpt", "llama")
+    for sampler in ({}, dict(temperature=0.8, top_k=4, seed=11))
+    for cache_dtype in (None, "bfloat16", "int8")]
 
 
 class TestTokenExactness:
@@ -304,7 +295,7 @@ class TestArming:
             _engine(gpt_model, spec_k=0)
 
 
-# -- fleet: counters, tenancy, failover (campaign chaos) -----------------
+# -- fleet: counters, tenancy, failover (chaos) --------------------------
 
 
 def _spec_fleet(model, n=2, router_kw=None, **engine_kw):
@@ -315,11 +306,6 @@ def _spec_fleet(model, n=2, router_kw=None, **engine_kw):
     frozen = [e.compile_counts() for e in engines]
     reps = [InprocReplica(f"r{i}", e) for i, e in enumerate(engines)]
     router = FleetRouter(reps, **dict(router_kw or {}))
-    # register for the session-end metrics.json export the campaign's
-    # fleet canary gate diffs (conftest._fleet_stage_metrics_export) —
-    # this is what makes fleet_spec_* nonzero in the golden
-    import conftest
-    conftest.fleet_stage_registries.append(router.registry)
     return router, reps, engines, frozen
 
 

@@ -1,6 +1,6 @@
 """tpu-lint suite (ISSUE 13) — per-rule positive/negative fixtures,
 suppression honoring, baseline stability under line drift, the
-campaign gate in both directions, and the tier-1 contract itself:
+CLI gate in both directions, and the tier-1 contract itself:
 the shipping tree lints clean against the committed baseline.
 
 Pure host-side: tpulint is stdlib-ast only, none of these tests
@@ -8,7 +8,6 @@ import jax.
 """
 import ast
 import json
-import os
 import subprocess
 import sys
 import textwrap
@@ -623,19 +622,17 @@ class TestRepoIsClean:
             assert j and "UNREVIEWED" not in j, e
 
 
-# ----------------------------------------------------- the campaign gate
+# ------------------------------------------------------------ the CLI gate
 
 def _cli(args, **kw):
-    env = dict(os.environ)
-    env.pop("BENCH_TELEMETRY_DIR", None)
     return subprocess.run(
         [sys.executable, "-m", "tools.tpulint", *args],
-        cwd=str(REPO), env=env, capture_output=True, text=True,
+        cwd=str(REPO), capture_output=True, text=True,
         timeout=120, **kw)
 
 
 class TestCampaignGate:
-    """The staticcheck stage's gate, proven in BOTH directions from
+    """The CLI's exit status as a gate, proven in BOTH directions from
     the committed fixtures (tests/fixtures/tpulint): the seeded
     violation tree MUST trip (exit 1), its clean twin MUST pass."""
 
@@ -727,33 +724,3 @@ class TestCampaignGate:
         rep2 = run_lint(paths=["pkg"], rules=["TRC01"],
                         root=str(tmp_path), baseline=bl2)
         assert len(rep2["unused_baseline"]) == 1
-
-    def test_validate_stages_gate_both_directions(self, tmp_path,
-                                                  monkeypatch):
-        """tools/validate_stages.check_lint_report: a completed
-        staticcheck stage without a clean lint_report.json must read
-        as a preflight problem; a clean one must not."""
-        sys.path.insert(0, str(REPO / "tools"))
-        import validate_stages as vs
-        out = tmp_path / "campaign_out"
-        tele = out / "telemetry" / "staticcheck"
-        tele.mkdir(parents=True)
-        (out / "summary.json").write_text(json.dumps(
-            {"staticcheck": {"ok": True, "rc": 0}}))
-        monkeypatch.setattr(vs, "OUT", str(out))
-
-        # missing report -> problem
-        problems, checked = vs.check_lint_report()
-        assert checked == 1 and problems
-
-        # clean report -> no problem
-        (tele / "lint_report.json").write_text(
-            json.dumps({"non_baselined": 0}))
-        problems, checked = vs.check_lint_report()
-        assert (problems, checked) == ([], 1)
-
-        # seeded non-baselined count -> MUST trip
-        (tele / "lint_report.json").write_text(
-            json.dumps({"non_baselined": 2}))
-        problems, checked = vs.check_lint_report()
-        assert checked == 1 and "non-baselined" in problems[0]

@@ -1,6 +1,5 @@
 """Engine.train_batch_multi — K optimizer steps in one dispatch
-(the public form of bench.py's --scan-steps construction; amortizes
-per-dispatch latency on remote backends).
+(amortizes per-dispatch latency on remote backends).
 
 Defining property: EXACTLY equal to K sequential train_batch calls
 (same rng folding, same counters, same updates).

@@ -51,7 +51,7 @@ original:
 
 Usage:
 
-  python tools/fleet_replay.py --archive campaign_out/capture \
+  python tools/fleet_replay.py --archive /path/to/capture \
       --golden --out replay_verdict.json
   python tools/fleet_replay.py --archive ... --knob hedge_after_ms=50 \
       --knob placement.queued=16
@@ -68,6 +68,7 @@ import json
 import os
 import random
 import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -859,8 +860,8 @@ def main(argv=None):
             "spec_stats": spec_stats(entries, k_values=ks)}))
         return 0
 
-    out_dir = os.environ.get("BENCH_TELEMETRY_DIR") or os.path.join(
-        REPO, "campaign_out", "telemetry", "fleet_replay")
+    out_dir = os.path.join(tempfile.gettempdir(),
+                           "paddle_tpu_fleet_replay")
     os.makedirs(out_dir, exist_ok=True)
     verdict, _rep = run_replay(
         entries, out_dir=out_dir, mode=args.mode,

@@ -21,14 +21,14 @@ Live mode reads ``/healthz`` + ``/history`` + ``/tenants`` +
   python tools/fleet_top.py --url ... --once        # one frame, exit
 
 Offline mode (``--snapshot <dir>``) renders the SAME frame from a
-post-mortem triage dir — the ``history_smoke`` stage's artifacts, or
+post-mortem triage dir — ``tools/history_smoke.py``'s artifacts, or
 anything holding a ``history_snapshot.json`` (HistoryStore save) and
 optionally ``tenants.json`` / ``health.json``:
 
-  python tools/fleet_top.py --snapshot campaign_out/telemetry/history_smoke
+  python tools/fleet_top.py --snapshot /path/to/triage_dir
 
 Stdlib-only (urllib + the standalone-loadable observability modules
-via bench._obs_mod); plain ANSI clear-screen, no curses.
+via tools/_obs.py); plain ANSI clear-screen, no curses.
 """
 from __future__ import annotations
 
@@ -41,7 +41,7 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import _obs_mod  # noqa: E402
+from tools._obs import obs_mod  # noqa: E402
 
 WINDOW_S = 30.0
 
@@ -111,7 +111,7 @@ def collect_live(base):
 
 def collect_snapshot(directory):
     """The same frame from a triage dir (offline post-mortem mode)."""
-    HistoryStore = _obs_mod("history").HistoryStore
+    HistoryStore = obs_mod("history").HistoryStore
     store = HistoryStore.load(
         os.path.join(directory, "history_snapshot.json"))
     _first, last = store.span()

@@ -1,5 +1,7 @@
-"""history_smoke — the campaign's CPU drill for the telemetry history
-plane, per-tenant accounting and the anomaly sentinel (ISSUE 11).
+"""history_smoke — CPU drill for the telemetry history plane, per-tenant
+accounting and the anomaly sentinel, and the generator (``--write-golden``)
+of the fixture tools/golden/history_clean_wave.json that
+tests/test_fleet_top.py reads.
 
 Shape (seeded, CPU-only, no chip time spent):
 
@@ -21,14 +23,14 @@ Shape (seeded, CPU-only, no chip time spent):
 4. invariants, asserted hard: per-tenant token totals sum EXACTLY to
    the fleet counters (space-saving sketch conservation), and compile
    counts are FROZEN across both waves with accounting on;
-5. artifacts into $BENCH_TELEMETRY_DIR: ``metrics.json`` (fleet
-   registry + recompile report), ``history_snapshot.json`` (the
-   torn-tolerant archive), ``tenants.json``, ``health.json``,
-   ``marks.json`` ({"t0","t_clean","t_end"} epoch marks). The
-   campaign's history gate then drives ``tools/metrics_diff.py
-   --history --at --vs`` over the archive: the clean span must show
-   no ``fleet_anomaly_*`` increase, the regression span MUST trip it
-   (the gate is proven live, not assumed).
+5. artifacts into ``<tempdir>/paddle_tpu_history_smoke`` (the
+   verdict's ``out_dir``): ``metrics.json`` (fleet registry +
+   recompile report), ``history_snapshot.json`` (the torn-tolerant
+   archive), ``tenants.json``, ``health.json``, ``marks.json``
+   ({"t0","t_clean","t_end"} epoch marks). ``tools/metrics_diff.py
+   --history --at --vs`` over the archive proves the two-instant gate:
+   the clean span shows no ``fleet_anomaly_*`` increase, the
+   regression span trips it.
 
 Last stdout line is a JSON verdict; exit 0 only when every assertion
 holds.
@@ -39,6 +41,7 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -128,8 +131,8 @@ def main(argv=None):
     ap.add_argument("--pulses", type=int, default=24)
     args = ap.parse_args(argv)
 
-    out_dir = os.environ.get("BENCH_TELEMETRY_DIR") or os.path.join(
-        REPO, "campaign_out", "telemetry", "history_smoke")
+    out_dir = os.path.join(tempfile.gettempdir(),
+                           "paddle_tpu_history_smoke")
     os.makedirs(out_dir, exist_ok=True)
     # flight dumps (fleet_anomaly) land next to the other artifacts
     os.environ.setdefault("PADDLE_TPU_FLIGHT_DIR", out_dir)

@@ -5,7 +5,7 @@ are climbing" become CHECKABLE: point this at two ledger snapshot
 files (``MemoryLedger.save()`` artifacts — the typed segment tree +
 the ground-truth residual) and it reports per-SEGMENT byte deltas as
 percent of the baseline — optionally failing on drift thresholds in
-BOTH directions so a campaign stage can gate on them (the
+BOTH directions so a script can gate on them (the
 profile_diff idiom, applied to device memory).
 
 Percent of side A, not absolute bytes: two runs may serve different
@@ -37,7 +37,7 @@ nothing.
 
 Last stdout line is a JSON report; exit 0 iff no --fail-on tripped.
 Stdlib-only (loads memledger straight from its file via
-bench._obs_mod — no jax, no package import).
+tools/_obs.py — no jax, no package import).
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import _obs_mod  # noqa: E402
+from tools._obs import obs_mod  # noqa: E402
 
 PSEUDO = ("attributed", "unattributed", "total")
 
@@ -70,7 +70,7 @@ def parse_spec(s):
 
 def load_segments(path):
     """Snapshot file -> {segment: bytes} incl. the pseudo-segments."""
-    ml = _obs_mod("memledger")
+    ml = obs_mod("memledger")
     doc = ml.load_snapshot(path)
     dg = doc.get("digest") or {}
     segs = {str(k): int(v) for k, v in (dg.get("segments")

@@ -1,13 +1,11 @@
 """metrics_diff — compare two metrics.json snapshots.
 
-BENCHLOG claims like "decode p99 held under 2 ms" or "zero extra
-retraces vs round 9" become CHECKABLE: point this at two bench/campaign
-`metrics.json` artifacts (the registry snapshots every stage exports)
-and it reports counter deltas, histogram quantile shifts (p50/p99/mean,
+Claims like "decode p99 held under 2 ms" or "zero extra retraces"
+become CHECKABLE: point this at two `metrics.json` artifacts (registry
+snapshots, ``MetricsRegistry.dump``) and it reports counter deltas, histogram quantile shifts (p50/p99/mean,
 rebuilt from the snapshot's buckets with the registry's own
 interpolation), and series added/removed between the runs — optionally
-failing on regression thresholds so a campaign preflight can gate on
-them.
+failing on regression thresholds so a script can gate on them.
 
 Usage:
   python tools/metrics_diff.py old/metrics.json new/metrics.json
@@ -16,11 +14,11 @@ Usage:
       --fail-on 'recompile_unexpected_retraces_total:value>0%'
 
 History mode — ONE archive, any two points in time: with
-``--history <snapshot>`` (a HistoryStore save, e.g. the
-``history_smoke`` stage's ``history_snapshot.json``) the A/B
+``--history <snapshot>`` (a HistoryStore save, e.g.
+``tools/history_smoke.py``'s ``history_snapshot.json``) the A/B
 snapshots are RECONSTRUCTED from the archive's rings at ``--at t0``
 and ``--vs t1`` instead of read from two files, so a single history
-archive supports the canary gate at any two instants:
+archive supports the gate at any two instants:
 
   python tools/metrics_diff.py --history history_snapshot.json \
       --at +0 --vs -0 --fail-on 'fleet_anomaly_fired_total>0%'
@@ -39,7 +37,7 @@ shows up under added/removed instead). PCT may be 0 ("any increase").
 
 Last stdout line is a JSON report; exit 0 iff no --fail-on tripped.
 Stdlib-only (loads the registry module straight from its file via
-bench._obs_mod — no jax, no package import).
+tools/_obs.py — no jax, no package import).
 """
 from __future__ import annotations
 
@@ -51,7 +49,7 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import _obs_mod  # noqa: E402
+from tools._obs import obs_mod  # noqa: E402
 
 _SPEC_RE = re.compile(
     r"^(?P<name>[^:<>]+?)(?::(?P<stat>value|count|mean|p\d{1,2}))?"
@@ -81,7 +79,7 @@ def load_snapshot(path):
 def _hist_stats(entry):
     """Rebuild a Histogram from its snapshot and read the rollup stats
     with the registry's own quantile interpolation."""
-    H = _obs_mod("metrics").Histogram
+    H = obs_mod("metrics").Histogram
     h = H(entry["name"], buckets=entry["bounds"])
     h.merge(entry)
     if not h.count:
@@ -151,7 +149,7 @@ def _series_stat(doc, key, stat):
         return _hist_stats(entry).get(stat)
     m = re.match(r"p(\d{1,2})$", stat)
     if m:
-        H = _obs_mod("metrics").Histogram
+        H = obs_mod("metrics").Histogram
         h = H(entry["name"], buckets=entry["bounds"])
         h.merge(entry)
         return h.quantile(int(m.group(1)) / 100.0) if h.count else None
@@ -199,7 +197,7 @@ def _resolve_t(spec, first, last):
 def load_history_pair(path, at, vs):
     """(a_doc, b_doc) reconstructed from a HistoryStore snapshot at
     two instants — the history plane's registry_snapshot_at."""
-    HistoryStore = _obs_mod("history").HistoryStore
+    HistoryStore = obs_mod("history").HistoryStore
     store = HistoryStore.load(path)
     first, last = store.span()
     if first is None:

@@ -5,9 +5,8 @@ placement" become CHECKABLE: point this at two folded-profile files
 (``ContinuousProfiler.save()`` artifacts — one ``stack weight`` line
 per collapsed stack, ``phase:decode;mod.fn;... N``) and it reports
 per-PHASE and per-leaf-FRAME wall-share deltas in absolute percentage
-points — optionally failing on drift thresholds so a campaign
-preflight can gate on them (the metrics_diff idiom, applied to
-profiles).
+points — optionally failing on drift thresholds so a script can
+gate on them (the metrics_diff idiom, applied to profiles).
 
 Shares, not raw sample counts: the two runs may have sampled at
 different rates or for different durations, so each side is first
@@ -35,7 +34,7 @@ fail loudly instead of green-lighting — a gate that compared nothing
 proved nothing.
 
 Last stdout line is a JSON report; exit 0 iff no --fail-on tripped.
-Stdlib-only (loads contprof straight from its file via bench._obs_mod
+Stdlib-only (loads contprof straight from its file via tools/_obs.py
 — no jax, no package import).
 """
 from __future__ import annotations
@@ -48,7 +47,7 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import _obs_mod  # noqa: E402
+from tools._obs import obs_mod  # noqa: E402
 
 _SPEC_RE = re.compile(
     r"^(?P<kind>phase|frame):(?P<key>.+?)"
@@ -67,7 +66,7 @@ def parse_spec(s):
 
 
 def _shares(path):
-    cp = _obs_mod("contprof")
+    cp = obs_mod("contprof")
     folded = cp.load_folded(path)
     phases, frames = cp.fold_shares(folded)
     return folded, phases, frames

@@ -1,5 +1,6 @@
-"""replay_smoke — the campaign's CPU drill for the traffic-capture &
-deterministic-replay plane (ISSUE 12).
+"""replay_smoke — CPU drill for the traffic-capture & deterministic-replay
+plane, and the generator (``--write-golden``) of the fixture
+tools/golden/replay_wave.json that tests/test_fleet_replay.py reads.
 
 Shape (seeded, CPU-only, no chip time spent):
 
@@ -28,7 +29,8 @@ Shape (seeded, CPU-only, no chip time spent):
    injected per-round replica slowdown (``replica_slow`` — the
    mid-wave latency regression) — the SAME gate spec MUST trip (a
    gate that never fires is not a gate);
-6. artifacts into $BENCH_TELEMETRY_DIR: ``metrics.json`` (capture
+6. artifacts into ``<tempdir>/paddle_tpu_replay_smoke`` (the verdict's
+   ``out_dir``): ``metrics.json`` (capture
    fleet registry incl. the ``fleet_capture_*`` series + recompile
    report), ``replay_verdict.json`` (clean),
    ``replay_verdict_regression.json``, and the capture archive dir.
@@ -44,6 +46,7 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -118,8 +121,8 @@ def main(argv=None):
                          "save it as the committed golden")
     args = ap.parse_args(argv)
 
-    out_dir = os.environ.get("BENCH_TELEMETRY_DIR") or os.path.join(
-        REPO, "campaign_out", "telemetry", "replay_smoke")
+    out_dir = os.path.join(tempfile.gettempdir(),
+                           "paddle_tpu_replay_smoke")
     os.makedirs(out_dir, exist_ok=True)
     os.environ.setdefault("PADDLE_TPU_FLIGHT_DIR", out_dir)
 
@@ -136,8 +139,8 @@ def main(argv=None):
             # reference); entries = the CAPTURED archive (measured
             # arrival offsets + resolved tokens — golden replay input).
             # Through io/atomic: a ctrl-C mid-regen must cost this
-            # regen, never the committed golden every future campaign
-            # replays against.
+            # regen, never the committed golden the tests replay
+            # against.
             from paddle_tpu.io import atomic
             atomic.atomic_replace(
                 GOLDEN,

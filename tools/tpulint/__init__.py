@@ -2,9 +2,8 @@
 contracts (trace-safety, durability, concurrency, telemetry validity,
 doc-catalogue sync). Stdlib-only; see docs/static_analysis.md.
 
-Entry points: ``python -m tools.tpulint`` (CLI, the campaign's
-``staticcheck`` stage) and ``run_lint()`` (in-process — what
-tests/test_tpulint.py drives).
+Entry points: ``python -m tools.tpulint`` (CLI) and ``run_lint()``
+(in-process — what tests/test_tpulint.py drives).
 """
 from .core import (Baseline, Finding, load_baseline, run_lint,  # noqa: F401
                    write_baseline, write_report)

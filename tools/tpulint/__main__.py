@@ -2,10 +2,7 @@
 
 Exit status is the gate: 0 = no non-baselined findings, 1 = new
 findings (or a syntax error in a scanned file). The machine-readable
-report always lands at --report (default: $BENCH_TELEMETRY_DIR/
-lint_report.json when the campaign exports one, else
-./lint_report.json) so `tools/validate_stages.py` can verify the
-staticcheck stage actually ran and came back clean.
+report always lands at --report (default: ./lint_report.json).
 """
 from __future__ import annotations
 
@@ -17,13 +14,6 @@ import sys
 from .core import (load_baseline, repo_root, run_lint, write_baseline,
                    write_report)
 from .rules import RULES
-
-
-def _default_report_path():
-    tele = os.environ.get("BENCH_TELEMETRY_DIR")
-    if tele:
-        return os.path.join(tele, "lint_report.json")
-    return "lint_report.json"
 
 
 def main(argv=None):
@@ -41,9 +31,9 @@ def main(argv=None):
                     help="print the full report as JSON on stdout "
                          "(last line stays machine-parseable either "
                          "way)")
-    ap.add_argument("--report", default=None, metavar="PATH",
+    ap.add_argument("--report", default="lint_report.json",
+                    metavar="PATH",
                     help="where to write lint_report.json (default: "
-                         "$BENCH_TELEMETRY_DIR/lint_report.json or "
                          "./lint_report.json)")
     ap.add_argument("--baseline", default=None, metavar="PATH",
                     help="baseline file (default: the committed "
@@ -101,8 +91,7 @@ def main(argv=None):
             return 1
         return 0
 
-    report_path = args.report or _default_report_path()
-    write_report(report, report_path)
+    write_report(report, args.report)
 
     if args.json:
         doc = {k: v for k, v in report.items()
@@ -116,8 +105,7 @@ def main(argv=None):
             print(f"baseline: UNUSED entry {e['rule']} {e['path']} "
                   f"[{e.get('qualname')}] {e.get('symbol')} — delete "
                   f"it (the debt is paid)")
-    # the machine-readable last line (campaign log convention: the
-    # last stdout line of every stage parses)
+    # the machine-readable last line
     print(json.dumps({
         "ok": report["non_baselined"] == 0,
         "non_baselined": report["non_baselined"],
@@ -125,7 +113,7 @@ def main(argv=None):
         "suppressed": report["suppressed"],
         "files_scanned": report["files_scanned"],
         "counts": report["counts"],
-        "report": os.path.abspath(report_path),
+        "report": os.path.abspath(args.report),
     }))
     return 0 if report["non_baselined"] == 0 else 1
 

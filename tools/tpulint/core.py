@@ -2,8 +2,8 @@
 
 The analyzer half of the suite: rules live in ``rules.py``, the CLI in
 ``__main__.py``. Everything here is stdlib-only (``ast``) so
-the linter runs in the jax-free campaign orchestrator, CI shells and
-the tier-1 test process alike, and never pays an accelerator import.
+the linter runs in CI shells and the tier-1 test process alike, and
+never pays an accelerator import.
 
 Design contracts (docs/static_analysis.md is the operator page):
 
@@ -89,7 +89,7 @@ class FileCtx:
         self._qualnames = _qualname_map(tree)
         # per-file memo shared across rules (one thread per file, so
         # no lock needed): import facts, parent maps, … — rebuilding
-        # these per rule (or per emit call) is O(file²) on bench.py
+        # these per rule (or per emit call) is O(file²) on a long file
         self.cache = {}
 
     def parents(self):
