@@ -74,6 +74,9 @@ SIZES = {
             (8, 1024, 8, 128, True, False, 0.0),    # d=128 heads
             (8, 1024, 12, 64, False, True, 0.0),    # padded encoder batch
             (8, 1024, 16, 64, True, False, 0.1),    # in-kernel dropout
+            # serve-axk1-closed32's prefill (heads padded to 256: the tiled
+            # forward) and the split backward no cell runs
+            (1, 1024, 64, 256, True, True, 0.0),
         ],
         decode=dict(b=8, s=1024, h=12, d=64),       # gpt2-en dense cache
         paged=[  # (b, hkv, g, d, page_size, max_pages)
@@ -304,8 +307,9 @@ def phase_kernels(sz, ctx):
                                             quantize_rows)
     from paddle_tpu.ops.attention import reference_attention
     from paddle_tpu.ops.pallas import conv_bn_act, fused_ln
-    from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
-                                                       flash_decode)
+    from paddle_tpu.ops.pallas.flash_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, _fit_block, flash_attention,
+        flash_decode, tile_counts)
     from paddle_tpu.ops.pallas.flash_decode import paged_flash_decode
     from paddle_tpu.ops.pallas.fused_adamw import fused_adamw_update
 
@@ -313,13 +317,13 @@ def phase_kernels(sz, ctx):
     results = []
     key = jax.random.PRNGKey(0)
 
-    def case(name, dtype, run):
+    def case(name, dtype, run, **facts):
         """run() -> normalised error. A compiler refusal or a mismatch is
         recorded and the remaining cases still run; the phase fails at
         the end if any case did."""
         mark = len(log.calls)
         t0 = time.perf_counter()
-        row = {"kernel": name, "dtype": dtype, "tol": TOL[dtype]}
+        row = {"kernel": name, "dtype": dtype, "tol": TOL[dtype], **facts}
         try:
             row["err"] = run()
             calls = log.since(mark)
@@ -358,9 +362,13 @@ def phase_kernels(sz, ctx):
                 lambda q, k, v: _attention_ref(q, k, v, causal, lens, drop,
                                                7), (q, k, v), ks[3])
             return _tree_nerr(got, want)
+        blocks = (_fit_block(s, DEFAULT_BLOCK_Q, d),
+                  _fit_block(s, DEFAULT_BLOCK_K, d))
+        # (plain, masked, skipped) score tiles a head, before kv_lens
         case(f"flash_attention fwd+bwd b{b} s{s} h{h} d{d} "
              f"causal={causal} kv_lens={use_lens} dropout={drop}",
-             "bfloat16", run)
+             "bfloat16", run, blocks=blocks,
+             tile_counts=tile_counts(s, s, *blocks, causal))
 
     # -- dense flash decode ----------------------------------------------
     dc = sz["decode"]
@@ -598,7 +606,8 @@ def phase_train(sz, ctx):
     calls = log.since(mark)
     _native_only(calls, "train")
     built = {n for n, _ in calls}
-    missing = {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"} - built
+    # heads of 64: the resident forward and the one backward kernel
+    missing = {"_fwd_resident_kernel", "_dkv_dq_kernel"} - built
     if missing:
         raise AssertionError(f"train: the compiled step holds no Mosaic "
                              f"flash-attention call for {sorted(missing)} "

@@ -1,0 +1,97 @@
+"""The flash kernels compile for a described TPU v5e at real widths.
+
+No chip is attached here; the TPU's compiler is installed and compiles for
+a chip that is described. Interpret mode cannot show what this shows: a
+slice off the tiling, too much VMEM, or the compiler's own refusals (at
+256 x 256 tiles a tile loop of static bounds around the backward's
+transposed key tile tripped an internal check of the MXU pass, which is why
+`_loop` unrolls every loop whose bounds are known). Nothing runs, so these
+say nothing about results or times.
+
+All in this one file and behind a fixture: only the worker that is given
+the file loads the TPU's library."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the package re-exports the function under the module's name
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler for it here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, b, sq, h, d, *, sk=None, causal=True, lens=False,
+             dropout=0.0, grad=True, dtype=jnp.bfloat16, **kw):
+    q = jax.ShapeDtypeStruct((b, sq, h, d), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, sk or sq, h, d), dtype, sharding=one_chip)
+    lens_in = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+
+    def f(q, k, v, kv_lens):
+        o = fa.flash_attention(
+            q, k, v, causal=causal, kv_lens=kv_lens if lens else None,
+            dropout_p=dropout, dropout_seed=3, interpret=False, **kw)
+        return o.astype(jnp.float32).sum()
+    fn = jax.grad(f, argnums=(0, 1, 2)) if grad else f
+    text = jax.jit(fn).lower(q, k, k, lens_in).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+CASES = {
+    "train_cell_d64": (dict(b=8, sq=1024, h=16, d=64), 2),
+    "d128": (dict(b=8, sq=1024, h=8, d=128), 2),
+    "encoder_lens": (dict(b=8, sq=1024, h=12, d=64, causal=False,
+                          lens=True), 2),
+    "dropout": (dict(b=8, sq=1024, h=16, d=64, dropout=0.1), 2),
+    "axk1_prefill_d256": (dict(b=1, sq=1024, h=64, d=256, lens=True,
+                               grad=False), 1),
+    "d256_gradients": (dict(b=1, sq=1024, h=8, d=256, lens=True), 3),
+    "s2048_two_spans": (dict(b=2, sq=2048, h=16, d=64), 2),
+    "s4096_lens_dropout": (dict(b=1, sq=4096, h=8, d=64, lens=True,
+                                dropout=0.1), 2),
+    "sq_lt_sk": (dict(b=2, sq=512, sk=1024, h=16, d=64), 2),
+    "sq_gt_sk": (dict(b=2, sq=1024, sk=512, h=16, d=64), 2),
+    "float32_d64": (dict(b=2, sq=1024, h=16, d=64, dtype=jnp.float32), 2),
+    "float32_d128_lens": (dict(b=2, sq=1024, h=8, d=128, lens=True,
+                               dtype=jnp.float32), 2),
+    "scale_not_a_power_of_two": (dict(b=2, sq=1024, h=8, d=64,
+                                      sm_scale=0.1), 2),
+    "tiles_256": (dict(b=2, sq=1024, h=16, d=64, block_q=256,
+                       block_k=256), 2),
+    "tiles_256_d128": (dict(b=2, sq=1024, h=8, d=128, block_q=256,
+                            block_k=256), 2),
+    "tiles_128_not_causal": (dict(b=2, sq=1024, h=16, d=64, causal=False,
+                                  block_q=128, block_k=128), 2),
+    "tiles_512_256": (dict(b=2, sq=1024, h=16, d=64, block_q=512,
+                           block_k=256), 2),
+    "s640_five_tiles_of_128": (dict(b=2, sq=640, h=16, d=64), 2),
+    "s8192_fused_backward": (dict(b=1, sq=8192, h=4, d=64), 2),
+    "s16384_split_backward": (dict(b=1, sq=16384, h=2, d=64), 3),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES), ids=list(CASES))
+def test_flash_attention_compiles_for_v5e(case, one_chip):
+    shape, kernels = CASES[case]
+    assert _compile(one_chip, **shape) == kernels
+
+
+def test_flash_decode_compiles_for_v5e(one_chip):
+    q = jax.ShapeDtypeStruct((8, 1, 12, 64), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda q, k, v, l: fa.flash_decode(
+        q, k, v, l, interpret=False)).lower(q, k, k, lens).compile().as_text()
+    assert text.count("tpu_custom_call") == 1

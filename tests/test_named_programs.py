@@ -121,7 +121,7 @@ def _conv(with_res):
 KERNEL_ENTRIES = [
     ("flash_attention", lambda: _flash(False), {"flash_fwd"}),
     ("flash_attention_grad", lambda: _flash(True),
-     {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+     {"flash_fwd", "flash_bwd_dkv_dq"}),
     ("flash_decode_dense", _dense_decode, {"flash_fwd"}),
     ("paged_flash_decode", _paged_decode, {"flash_decode"}),
     ("latent_flash_decode", _latent_decode, {"latent_decode"}),
@@ -156,7 +156,12 @@ def test_kernel_names_are_unique_over_the_call_sites():
                 text = src.read()
             sites += len(re.findall(r"\bpallas_call\(", text))
             names += re.findall(r'\bname="(\w+)"', text)
-    assert sites == len(names) == len(set(names)) == 11
+    # flash_attention.py: the tiled and the resident forward are both
+    # `flash_fwd` (one reader, one head-size path a call), and the split
+    # backward's one site is called under its two names
+    shared = [n for n in set(names) if names.count(n) > 1]
+    assert shared == ["flash_fwd"] and names.count("flash_fwd") == 2
+    assert sites == 12 and len(names) == 13 and len(set(names)) == 12
 
 
 def test_a_kernel_without_a_name_is_an_error():
