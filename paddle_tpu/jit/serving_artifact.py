@@ -199,11 +199,15 @@ def export_artifact(engine, root, prune=True):
     import jax
     from jax import export as jax_export
     from ..io.atomic import atomic_replace, publish_dir
-    if engine.cache_spec.latent:
+    other = sorted(set(engine.cache_layers) - {"kv"})
+    if other:
+        what = {"latent": "serves from a latent paged cache",
+                "conv_state": "has layers with a per-slot state"}
         raise ValueError(
-            f"{type(engine.model).__name__} serves from a latent paged "
-            "cache, which has no AOT artifact export yet (the artifact's "
-            "fingerprint and warm signatures describe per-head K/V pools)")
+            f"{type(engine.model).__name__} "
+            f"{' and '.join(what[k] for k in other)}, which has no AOT "
+            "artifact export yet (the artifact's fingerprint and warm "
+            "signatures describe per-head K/V pools)")
     if not engine.warmed:
         raise RuntimeError("export_artifact needs a warmed engine — "
                            "warmup() first (export is a boot step)")

@@ -21,8 +21,8 @@ rotary layout, `+1e-20` in the router's normaliser). Every layer is
   expert layers: float32 sigmoid scores over all `n_routed_experts`, the
   `num_experts_per_tok` highest, normalised and scaled, plus a shared
   expert. `topk_method: "none"` is read as selection by the scores alone
-  (no group limit, no correction bias): `select_experts` is the one
-  place to change.
+  (no group limit, no correction bias): `nlp/moe.select_experts` is the
+  one place to change.
 
 **The share.** `layer_chips` chips share each layer (expert-parallel FFN,
 data-parallel attention): this chip (`chip_rank`) holds
@@ -53,6 +53,7 @@ from ..nn.layers_common import LayerList
 from ..nn.layers_norm import RMSNorm
 from ..tensor import Tensor
 from .llama import apply_rope
+from .moe import held_experts, select_experts
 from .paged_cache import (LatentCacheSpec, LatentRows,
                           PagedLatentCache, _rope_rows,
                           latent_paged_attention, write_token_latent)
@@ -231,58 +232,6 @@ def _mm(x, w):
 
 def _swiglu(x, wg, wu, wd):
     return _mm(jax.nn.silu(_mm(x, wg)) * _mm(x, wu), wd)
-
-
-def select_experts(scores, k, norm, scale):
-    """(ids int32 [T, k], weights float32 [T, k]) from the router's scores
-    [T, E]: the k highest by the scores alone, their scores over their sum
-    where `norm`, times `scale`."""
-    w, idx = jax.lax.top_k(scores, k)
-    if norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * scale
-
-
-@jax.named_scope("moe_experts")
-def held_experts(u, idx, w, w_gate_up, w_down, offset, rows_live=None):
-    """The held experts' part of `sum_e w_e Expert_e(u)`, dropless.
-
-    u [T, h] float32; idx, w [T, k] the router's picks; w_gate_up
-    [held, h, 2m], w_down [held, m, h] experts `offset .. offset+held`;
-    rows_live [T] bool leaves padding rows out. The T*k assignments are
-    sorted by held expert (those of absent experts last), the three
-    products run as two grouped products over the rows of each expert,
-    and each token's rows are brought back and summed. Shapes are static
-    at the worst case (every assignment held here); rows past the held
-    ones belong to no group and count as zero. Returns (out [T, h]
-    float32, counters int32 [3] in AUX_COUNTERS' order)."""
-    t, k = idx.shape
-    held, m = w_down.shape[0], w_down.shape[1]
-    a = t * k
-    local = idx - offset
-    mine = (local >= 0) & (local < held)
-    if rows_live is not None:
-        mine = mine & rows_live[:, None]
-    key = jnp.where(mine, local, held).reshape(a)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32)[None],
-                    axis=0, dtype=jnp.int32)
-    n_mine = jnp.sum(sizes)
-    xs = u.astype(w_gate_up.dtype)[order // k]                   # [A, h]
-    gu = jax.lax.ragged_dot(xs, w_gate_up, sizes,
-                            preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gu[:, :m]) * gu[:, m:]).astype(w_down.dtype)
-    y = jax.lax.ragged_dot(act, w_down, sizes,
-                           preferred_element_type=jnp.float32)   # [A, h]
-    held_row = jnp.arange(a, dtype=jnp.int32) < n_mine
-    y = jnp.where(held_row[:, None], y * w.reshape(a)[order][:, None], 0.0)
-    back = jnp.zeros((a,), jnp.int32).at[order].set(
-        jnp.arange(a, dtype=jnp.int32))
-    out = jnp.sum(y[back].reshape(t, k, -1), axis=1)
-    routed = jnp.int32(t) if rows_live is None \
-        else jnp.sum(rows_live, dtype=jnp.int32)
-    aux = jnp.stack([n_mine, jnp.sum(sizes > 0, dtype=jnp.int32), routed])
-    return out, aux
 
 
 class _StackedNormal(Normal):

@@ -21,12 +21,16 @@ loop stays one compiled XLA program —
   validity is carried by `positions` ([num_slots] int32 = tokens
   already cached) and attention masks keys at index >= positions+1.
 
-Which pool a layer has is the model's to say (`cache_spec_of`): keys and
-values by head as above (`KVCacheSpec`: GPT, Llama), or, for latent
-attention, ONE pool `[P, ps, W]` of the rows every head shares and no
-value pool (`LatentCacheSpec`, `PagedLatentCache`: nlp/axk1.py). Page
-table, trash page, positions and the engine's page accounting are the
-same for both.
+Which cache a layer has is the model's to say (`cache_specs_of`: one spec
+for every layer, or one per layer): keys and values by head as above
+(`KVCacheSpec`: GPT, Llama), for latent attention ONE pool `[P, ps, W]` of
+the rows every head shares and no value pool (`LatentCacheSpec`,
+`PagedLatentCache`: nlp/axk1.py), or NO pages at all: a state of fixed
+size per serving slot, `[slots, taps, C]`, overwritten in place as the
+slot's sequence grows (`ConvStateSpec`, `ConvStateCache`: the short
+convolutions of nlp/lfm2.py, beside that model's K/V layers). Page table,
+trash page, positions and the engine's page accounting are the same for
+the paged kinds and count those layers alone.
 
 Cache dtypes: float32 / bfloat16 store K/V directly; int8 stores
 per-token-per-head symmetric-quantized rows with an f32 scale sidecar
@@ -50,6 +54,13 @@ needs NO device-side cleanup: the host simply declines to advance
 prefix cache's private-tail pages safe to re-prefill after failover).
 A spec verify dispatch writes K+1 rows per slot into already-owned
 pages; committing j of them is one host-side integer add.
+
+None of that holds for a slot state (`ConvStateSpec`): it is overwritten
+in place, so a rejected write cannot be taken back and a prefix's pages
+say nothing of the state at the prefix's end. The engine refuses
+speculative verify, the prefix cache, an int8 state and AOT export by
+name for a model with such layers (nlp/serving.py), and the decode step
+updates the rows of live slots only.
 """
 from __future__ import annotations
 
@@ -60,8 +71,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PagedLayerCache", "PagedLatentCache", "KVCacheSpec",
-           "LatentCacheSpec", "LatentRows", "AUX_COUNTERS", "cache_spec_of",
+__all__ = ["PagedLayerCache", "PagedLatentCache", "ConvStateCache",
+           "KVCacheSpec", "LatentCacheSpec", "ConvStateSpec", "LatentRows",
+           "PromptKV", "ConvStateRows", "AUX_COUNTERS", "cache_spec_of",
+           "cache_specs_of", "conv_state_step", "conv_state_at",
+           "write_prompt_state",
            "PrefixIndex", "alloc_pages",
            "prefix_fingerprints", "quantize_rows", "write_token_latent",
            "write_prompt_latent", "latent_paged_attention",
@@ -76,8 +90,9 @@ __all__ = ["PagedLayerCache", "PagedLatentCache", "KVCacheSpec",
 TRASH_PAGE = 0
 
 # what an expert layer counts per forward, in the order of the int32
-# vector it hands back through its cache (`PagedLatentCache.aux`,
-# `LatentRows.aux`) and the engine sums per kind of program: assignments
+# vector it hands back through its cache (the `aux` of a layer's cache
+# view in the decode step and of what its prefill forward returns) and
+# the engine sums per kind of program: assignments
 # that fell on experts held here, held experts that got at least one
 # row, rows the router saw
 AUX_COUNTERS = ("moe_local_assignments", "moe_experts_hit",
@@ -94,13 +109,14 @@ class PagedLayerCache:
     """One layer's view of the paged cache plus the shared routing
     state. Plain object (deliberately not a pytree — see module doc);
     `use_flash` is trace-time-static kernel routing, everything else is
-    a traced array."""
+    a traced array. `aux` is what the layer hands back beside its pages
+    (an expert layer's counters, AUX_COUNTERS), None where it has none."""
 
     __slots__ = ("k_pages", "v_pages", "k_scale", "v_scale",
-                 "page_table", "positions", "use_flash")
+                 "page_table", "positions", "use_flash", "aux")
 
     def __init__(self, k_pages, v_pages, page_table, positions,
-                 k_scale=None, v_scale=None, use_flash=False):
+                 k_scale=None, v_scale=None, use_flash=False, aux=None):
         self.k_pages = k_pages          # [Hkv, P, ps, D]
         self.v_pages = v_pages          # [Hkv, P, ps, D]
         self.k_scale = k_scale          # [Hkv, P, ps, 1] f32 | None
@@ -108,13 +124,16 @@ class PagedLayerCache:
         self.page_table = page_table    # [B, MP] int32
         self.positions = positions      # [B] int32 tokens already cached
         self.use_flash = bool(use_flash)
+        self.aux = aux
 
-    def replaced(self, k_pages, v_pages, k_scale=None, v_scale=None):
+    def replaced(self, k_pages, v_pages, k_scale=None, v_scale=None,
+                 aux=None):
         """New view with updated page arrays (same table/positions/
         routing) — what an attention layer returns as its new cache."""
         return PagedLayerCache(k_pages, v_pages, self.page_table,
                                self.positions, k_scale=k_scale,
-                               v_scale=v_scale, use_flash=self.use_flash)
+                               v_scale=v_scale, use_flash=self.use_flash,
+                               aux=aux)
 
     def arrays(self):
         """The pool arrays as the engine carries them between programs."""
@@ -160,6 +179,29 @@ class PagedLatentCache:
         return self.pages.shape[1]
 
 
+class ConvStateCache:
+    """One short-convolution layer's view of its state in the decode step:
+    `state` [B, taps, C], row b the last `taps` inputs of the convolution
+    that slot b's sequence fed it (the newest last, zeros before the
+    sequence starts), and `live` [B] bool, the slots whose row this step
+    may overwrite (None: all). No page table and no positions: the state
+    has one size whatever the sequence's length. `aux` as
+    PagedLayerCache's. Not a pytree."""
+
+    __slots__ = ("state", "live", "aux")
+
+    def __init__(self, state, live=None, aux=None):
+        self.state = state              # [B, taps, C]
+        self.live = live                # [B] bool | None
+        self.aux = aux
+
+    def replaced(self, state, aux=None):
+        return ConvStateCache(state, self.live, aux)
+
+    def arrays(self):
+        return (self.state,)
+
+
 def alloc_pages(num_pages, page_size, kv_heads, head_dim, cache_dtype):
     """Fresh page pool for ONE layer. cache_dtype: 'float32' |
     'bfloat16' | 'int8' (int8 adds the f32 scale sidecars)."""
@@ -181,16 +223,17 @@ class KVCacheSpec:
     `[Hkv, P, ps, D]` (+ the int8 scale sidecars), a cached forward
     hands back dense `(k, v)` of `[1, S, Hkv, D]` per layer."""
 
-    latent = False
+    kind, latent, paged = "kv", False, True
 
     def __init__(self, kv_heads, head_dim):
         self.kv_heads, self.head_dim = int(kv_heads), int(head_dim)
 
-    def alloc(self, num_pages, page_size, cache_dtype):
+    def alloc(self, num_pages, page_size, cache_dtype, max_slots=None):
         return alloc_pages(num_pages, page_size, self.kv_heads,
                            self.head_dim, cache_dtype)
 
-    def view(self, arrays, page_table, positions, use_flash=False):
+    def view(self, arrays, page_table, positions, use_flash=False,
+             live=None):
         k, v, ks, vs = arrays
         return PagedLayerCache(k, v, page_table, positions, k_scale=ks,
                                v_scale=vs, use_flash=use_flash)
@@ -199,7 +242,7 @@ class KVCacheSpec:
         """The dense rows one layer's cached forward returned."""
         return layer[0], layer[1]
 
-    def write_prompt(self, arrays, rows, pages_vec):
+    def write_prompt(self, arrays, rows, pages_vec, slot=None):
         return write_prompt_kv(*arrays, *rows, pages_vec)
 
 
@@ -214,36 +257,80 @@ class LatentCacheSpec:
     outside the decode loop and copies all of it in and out of the loop
     every dispatch."""
 
-    latent = True
+    kind, latent, paged = "latent", True, True
 
     def __init__(self, width):
         self.width = int(width)
         self.pool_width = -(-self.width // _LANES) * _LANES
 
-    def alloc(self, num_pages, page_size, cache_dtype):
+    def alloc(self, num_pages, page_size, cache_dtype, max_slots=None):
         return (jnp.zeros((num_pages, page_size, self.pool_width),
                           jnp.dtype(cache_dtype)),)
 
-    def view(self, arrays, page_table, positions, use_flash=False):
+    def view(self, arrays, page_table, positions, use_flash=False,
+             live=None):
         return PagedLatentCache(arrays[0], page_table, positions,
                                 use_flash=use_flash)
 
     def prompt_rows(self, layer):
         return (layer.rows,)
 
-    def write_prompt(self, arrays, rows, pages_vec):
+    def write_prompt(self, arrays, rows, pages_vec, slot=None):
         return (write_prompt_latent(arrays[0], rows[0], pages_vec),)
 
 
+class ConvStateSpec:
+    """A short-convolution layer's cache: no pages, one row of state per
+    serving slot, `[max_slots, taps, channels]` in the engine's cache dtype
+    (no int8 form). The channels are the minor dimension: with the taps
+    minor, the TPU's tiled layout pads 3 to the lane width of 128 and a
+    state of 9 MB takes 400. A prefill writes the row of the slot it
+    admits (`slot`, which the paged kinds do not need) with the state as
+    it stands after the prompt's last token, whatever the slot's last
+    request left there; the decode step shifts the rows of live slots."""
+
+    kind, latent, paged = "conv_state", False, False
+
+    def __init__(self, channels, taps):
+        self.channels, self.taps = int(channels), int(taps)
+
+    def alloc(self, num_pages, page_size, cache_dtype, max_slots=None):
+        return (jnp.zeros((max_slots, self.taps, self.channels),
+                          jnp.dtype(cache_dtype)),)
+
+    def view(self, arrays, page_table, positions, use_flash=False,
+             live=None):
+        return ConvStateCache(arrays[0], live)
+
+    def prompt_rows(self, layer):
+        return (layer.state,)
+
+    def write_prompt(self, arrays, rows, pages_vec, slot=None):
+        return (write_prompt_state(arrays[0], rows[0], slot),)
+
+
 def cache_spec_of(model):
-    """The per-layer cache layout `model` serves with: its own
-    `cache_spec()` where it has one, else keys and values by its
-    configuration's heads."""
+    """The cache layout `model` serves with, as the model says it: its own
+    `cache_spec()` where it has one (one spec for every layer, or a list
+    of one per layer), else keys and values by its configuration's
+    heads."""
     if hasattr(model, "cache_spec"):
         return model.cache_spec()
     cfg = model.config
     return KVCacheSpec(getattr(cfg, "num_key_value_heads", 0)
                        or cfg.num_attention_heads, cfg.head_dim)
+
+
+def cache_specs_of(model):
+    """(what `cache_spec_of(model)` answers, [that as one spec a layer])."""
+    spec = cache_spec_of(model)
+    layers = model.config.num_hidden_layers
+    if not isinstance(spec, (list, tuple)):
+        return spec, [spec] * layers
+    if len(spec) != layers:
+        raise ValueError(f"{type(model).__name__}.cache_spec() names "
+                         f"{len(spec)} layers of {layers}")
+    return spec, list(spec)
 
 
 def quantize_rows(x):
@@ -335,6 +422,65 @@ class LatentRows:
 
     def __init__(self, rows, aux=None):
         self.rows, self.aux = rows, aux
+
+
+class PromptKV(tuple):
+    """What a K/V layer's cached (prefill) forward hands back where the
+    layer also counts: the pair `(k, v)` of `[1, S, Hkv, D]`, as a plain
+    tuple would be, and the layer's counters as `aux`."""
+
+    def __new__(cls, k, v, aux=None):
+        self = super().__new__(cls, (k, v))
+        self.aux = aux
+        return self
+
+
+class ConvStateRows:
+    """What a short-convolution layer's cached (prefill) forward hands
+    back: the state `[B, taps, C]` as it stands after each row's last
+    true token, and the layer's counters."""
+
+    __slots__ = ("state", "aux")
+
+    def __init__(self, state, aux=None):
+        self.state, self.aux = state, aux
+
+
+def conv_state_step(cache: ConvStateCache, g):
+    """One decode step of a slot state. g [B, C] float32, this token's
+    input to the convolution. Returns (window [B, taps, C] float32: the
+    stored inputs of the last `taps - 1` tokens, then g itself unrounded;
+    the new state in the cache's dtype: the window, for the live slots
+    only)."""
+    old = cache.state
+    window = jnp.concatenate([old[:, 1:].astype(jnp.float32), g[:, None]],
+                             axis=1)
+    new = window.astype(old.dtype)
+    if cache.live is not None:
+        new = jnp.where(cache.live[:, None, None], new, old)
+    return window, new
+
+
+def conv_state_at(g, lens, taps):
+    """The state a prompt leaves: g [B, S, C], the convolution's inputs
+    over a (right-padded) prompt; lens [B] int32 the true lengths, None
+    for S. Returns [B, taps, C], rows `lens - taps .. lens - 1` of g with
+    zeros where the sequence has not begun: the state after the last TRUE
+    token, not after the bucket's padding."""
+    b, s, _ = g.shape
+    if lens is None:
+        lens = jnp.full((b,), s, jnp.int32)
+    at = lens[:, None] - taps + jnp.arange(taps, dtype=jnp.int32)[None, :]
+    rows = jnp.take_along_axis(g, jnp.clip(at, 0, s - 1)[:, :, None], axis=1)
+    return jnp.where((at >= 0)[:, :, None], rows, 0.0)
+
+
+@jax.named_scope("conv_state_write")
+def write_prompt_state(state, rows, slot):
+    """Prefill write: rows [1, taps, C], one prompt's state, into the row
+    of the slot it is admitted to. A `slot` past the last one (the
+    engine's warm-up) writes nothing."""
+    return state.at[slot].set(rows[0].astype(state.dtype), mode="drop")
 
 
 def _to_width(x, width):

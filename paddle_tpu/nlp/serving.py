@@ -62,7 +62,7 @@ from ..observability.metrics import MetricsRegistry
 from ..resilience import faults
 from ..resilience.retry import call_with_retries
 from ..tensor import Tensor
-from .paged_cache import AUX_COUNTERS, PrefixIndex, cache_spec_of, \
+from .paged_cache import AUX_COUNTERS, PrefixIndex, cache_specs_of, \
     prefix_fingerprints, write_prompt_kv, xla_attention_form, TRASH_PAGE
 
 __all__ = ["ServingEngine", "ServeRequest"]
@@ -136,10 +136,12 @@ class ServingEngine:
 
     model: GPTForCausalLM / LlamaForCausalLM (anything whose attention
     layers understand the PagedLayerCache contract), or a model that
-    names another per-layer cache through `cache_spec()`
-    (AXK1ForCausalLM: one latent pool a layer; the prefix cache, an int8
-    cache, speculative verify and AOT export are refused for it by
-    name). All requests share
+    names another cache through `cache_spec()`, for every layer or
+    layer by layer (AXK1ForCausalLM: one latent pool a layer;
+    LFM2ForCausalLM: K/V pages for its attention layers and a per-slot
+    state, no pages, for its short convolutions; the prefix cache, an
+    int8 cache, speculative verify and AOT export are refused for both
+    by name). All requests share
     one sampling strategy (greedy when temperature==0, else
     temperature/top-k sampling) — the strategy is baked into the one
     compiled decode program.
@@ -261,12 +263,20 @@ class ServingEngine:
         self.model = model
         cfg = model.config
         self.cfg = cfg
-        # the per-layer cache layout comes from the model: keys and values
-        # by head (GPT, Llama) or one latent pool (paged_cache.*CacheSpec)
-        self.cache_spec = spec = cache_spec_of(model)
+        # the cache layout comes from the model, layer by layer: keys and
+        # values by head (GPT, Llama), one latent pool, or a per-slot state
+        # and no pages (paged_cache.*Spec); `cache_spec` is the model's own
+        # answer, one spec or a list
+        self.cache_spec, self.cache_specs = cache_specs_of(model)
+        specs = self.cache_specs
         self.num_layers = cfg.num_hidden_layers
-        if not spec.latent:
-            self.kv_heads, self.head_dim = spec.kv_heads, spec.head_dim
+        kinds = [s.kind for s in specs]
+        self.cache_layers = {k: kinds.count(k) for k in dict.fromkeys(kinds)}
+        self._state_layers = kinds.count("conv_state")
+        latent = "latent" in kinds
+        if not latent:
+            kv = specs[kinds.index("kv")]
+            self.kv_heads, self.head_dim = kv.kv_heads, kv.head_dim
             self.groups = cfg.num_attention_heads // self.kv_heads
         self.page_size = int(page_size)
         self.max_slots = int(max_slots)
@@ -284,7 +294,7 @@ class ServingEngine:
         if self.cache_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError(f"cache_dtype {cache_dtype!r}: expected "
                              "float32 | bfloat16 | int8")
-        if spec.latent:
+        if latent:
             from ..ops.attention import latent_flash_available
             self.use_flash = latent_flash_available(use_flash)
             self.decode_attention = "latent_paged_kernel" \
@@ -330,9 +340,9 @@ class ServingEngine:
         if spec_draft is None:
             spec_draft = os.environ.get("PADDLE_TPU_SPEC_DRAFT", "ngram")
         self.spec_draft = spec_draft
-        if spec.latent:
-            # what the latent cache does not serve yet is refused here,
-            # by name: never silently served by another path
+        # what a kind of cache does not serve yet is refused here, by
+        # name: never silently served by another path
+        if latent:
             for asked, what in (
                     (prefix_cache, "prefix_cache=True (no sharing of "
                      "latent pages yet; pass prefix_cache=False)"),
@@ -342,6 +352,19 @@ class ServingEngine:
                     raise ValueError(
                         f"{type(model).__name__} serves from a latent "
                         f"paged cache, which does not support {what}")
+        if self._state_layers:
+            for asked, what in (
+                    (prefix_cache, "prefix_cache=True (a tail prefill "
+                     "would need the state at the prefix's end, which no "
+                     "page holds; pass prefix_cache=False)"),
+                    (self.cache_dtype == "int8", "cache_dtype='int8' (the "
+                     "state has no quantized form)"),
+                    (spec_decode, "spec_decode=True (a rejected write to a "
+                     "state overwritten in place cannot be taken back)")):
+                if asked:
+                    raise ValueError(
+                        f"{type(model).__name__} has layers with a "
+                        f"per-slot state, which does not support {what}")
         if profile is None:
             profile = os.environ.get(
                 "PADDLE_TPU_PROFILE", "0").lower() in ("1", "true", "on")
@@ -364,8 +387,11 @@ class ServingEngine:
 
         self._params, self._buffers = model.raw_state()
         self._pages = [spec.alloc(self.num_pages, self.page_size,
-                                  self.cache_dtype)
-                       for _ in range(self.num_layers)]
+                                  self.cache_dtype, self.max_slots)
+                       for spec in specs]
+        # prefills that wrote a layer's per-slot state (one count a state
+        # layer and admission): health()["conv_state_prefill_writes"]
+        self.conv_state_prefill_writes = 0
         self._quantized = self.cache_dtype == "int8"
 
         b = self.max_slots
@@ -547,7 +573,8 @@ class ServingEngine:
         # per-page KV bytes (all layers, incl. int8 scale sidecars):
         # the unit the admission hint prices a request in. Host attr
         # walk over pool metadata, computed once.
-        self._page_bytes = (_memledger.nbytes_of(self._pages)
+        self._page_bytes = (_memledger.nbytes_of(
+            [a for a, spec in zip(self._pages, specs) if spec.paged])
                             // max(self.num_pages, 1))
         if self._mem_enabled:
             self.ledger = _memledger.MemoryLedger(
@@ -1070,9 +1097,11 @@ class ServingEngine:
             ids = np.full((1, n), self.pad_token_id, np.int32)
             pages_vec = np.full((n // self.page_size,), TRASH_PAGE,
                                 np.int32)
+            # the slot past the last: a state layer's write is dropped
             return (self._params, self._buffers, self._pages,
                     jnp.asarray(ids), jnp.int32(1),
-                    jnp.asarray(pages_vec), self._rng)
+                    jnp.asarray(pages_vec), self._rng,
+                    *self._slot_arg(self.max_slots))
         raise ValueError(f"unknown serving program {name!r}")
 
     def _prime(self, name, fn):
@@ -1296,6 +1325,8 @@ class ServingEngine:
              "cancels_pending": len(self._cancel_pending),
              "admission_policy": self.admission_policy,
              "decode_attention": self.decode_attention,
+             # what the engine holds, by kind of layer cache
+             "cache_layers": dict(self.cache_layers),
              "dispatch_retries": int(self._m_retries.value),
              "deadline_misses": int(self._m_deadline.value),
              "evictions": int(self._m_evictions.value),
@@ -1313,6 +1344,8 @@ class ServingEngine:
                           "top_k": self.top_k,
                           "seed": self.sampling_seed},
              "compile_counts": self.compile_counts()}
+        if self._state_layers:
+            h["conv_state_prefill_writes"] = self.conv_state_prefill_writes
         if self.aux_counts:
             def named(v):
                 return dict(zip(AUX_COUNTERS, (int(x) for x in v)))
@@ -1420,10 +1453,16 @@ class ServingEngine:
         self._aot_programs[name] = (wrapped, kw)
         return self.tracer.jit(name, wrapped, **kw)
 
-    def _layer_caches(self, pages, page_table, positions):
-        return [self.cache_spec.view(arrays, page_table, positions,
-                                     use_flash=self.use_flash)
-                for arrays in pages]
+    def _layer_caches(self, pages, page_table, positions, live=None):
+        return [spec.view(arrays, page_table, positions,
+                          use_flash=self.use_flash, live=live)
+                for spec, arrays in zip(self.cache_specs, pages)]
+
+    def _slot_arg(self, slot):
+        """The prefill programs' last argument, for a model with state
+        layers only: the slot whose row of each state the prompt's is
+        written to. Other models' programs take none."""
+        return (jnp.int32(slot),) if self._state_layers else ()
 
     @staticmethod
     def _unwrap_pages(new_caches):
@@ -1441,11 +1480,13 @@ class ServingEngine:
         return jnp.stack(aux) if aux else None
 
     def _model_token_step(self, params, buffers, tokens, pages,
-                          page_table, positions):
+                          page_table, positions, live=None):
         """One batched single-token forward through the paged cache.
-        tokens [B] int32; returns (last_logits [B, V] f32, new pages,
-        the layers' counters or None)."""
-        caches = self._layer_caches(pages, page_table, positions)
+        tokens [B] int32; live [B] bool, the slots whose per-slot state
+        (where a layer has one) this step may overwrite; returns
+        (last_logits [B, V] f32, new pages, the layers' counters or
+        None)."""
+        caches = self._layer_caches(pages, page_table, positions, live)
         out = functional_call(
             self.model, params, buffers, Tensor(tokens[:, None]),
             use_cache=False, cache=caches,
@@ -1467,7 +1508,8 @@ class ServingEngine:
                 (pages, seq_lens, last, done, emitted) = carry
                 live = active & ~done
                 logits, pages, aux = self._model_token_step(
-                    params, buffers, last, pages, page_table, seq_lens)
+                    params, buffers, last, pages, page_table, seq_lens,
+                    live)
                 # token index e = emitted-so-far keys the draw:
                 # fold_in(base, e) — the stream is a function of the
                 # request and index, never of dispatch scheduling
@@ -1546,7 +1588,7 @@ class ServingEngine:
             return fn
 
         def prefill(params, buffers, pages, ids, true_len, pages_vec,
-                    key):
+                    key, *slot):
             s_b = ids.shape[1]
             mask = (jnp.arange(s_b, dtype=jnp.int32)[None, :]
                     < true_len).astype(jnp.int32)
@@ -1560,12 +1602,12 @@ class ServingEngine:
             def arr(x):
                 return x._value if isinstance(x, Tensor) else x
 
-            spec = self.cache_spec
             new_pages, dense_kv = [], []
-            for arrays, layer in zip(pages, caches):
+            for spec, arrays, layer in zip(self.cache_specs, pages,
+                                           caches):
                 rows = tuple(arr(r) for r in spec.prompt_rows(layer))
                 new_pages.append(spec.write_prompt(arrays, rows,
-                                                   pages_vec))
+                                                   pages_vec, *slot))
                 # the dense prompt K/V ride back out so the prefix
                 # index can pin host-side f32 copies of shareable
                 # pages — device buffers, no extra compute
@@ -2034,11 +2076,12 @@ class ServingEngine:
                 tok, new_pages, dense_kv, *aux = fn(
                     self._params, self._buffers, self._pages,
                     jnp.asarray(ids), jnp.int32(lp),
-                    jnp.asarray(pages_vec), key)
+                    jnp.asarray(pages_vec), key, *self._slot_arg(b))
             self._pages = new_pages
             tok = int(tok)  # host sync: the first token exists NOW
             if aux:
                 self._add_aux("prefill", aux[0])
+            self.conv_state_prefill_writes += self._state_layers
         self._m_ttft.observe(time.monotonic() - req.submitted_at)
         # the int(tok) sync above bounds the span at real prefill work
         self.spans.add(f"prefill_{bucket}", t_pre, tid=f"req{req.rid}",

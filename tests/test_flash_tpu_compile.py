@@ -95,3 +95,20 @@ def test_flash_decode_compiles_for_v5e(one_chip):
     text = jax.jit(lambda q, k, v, l: fa.flash_decode(
         q, k, v, l, interpret=False)).lower(q, k, k, lens).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+def test_the_paged_decode_kernel_compiles_at_the_lfm2_cell_s_shape(one_chip):
+    """`serve-lfm2-closed64`: 64 slots, 8 K/V heads serving 4 query heads
+    of 64 each, 1025 pages of 128, 16 table entries a slot, bf16 pages."""
+    from paddle_tpu.ops.pallas import flash_decode as fd
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((64, 8, 4, 64), jnp.float32)
+    pages = sds((8, 1025, 128, 64), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, pt, lens: fd.paged_flash_decode(
+        q, k, v, pt, lens, interpret=False)).lower(
+            q, pages, pages, sds((64, 16), jnp.int32),
+            sds((64,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
