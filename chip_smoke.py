@@ -67,7 +67,10 @@ SIZES = {
     "fit": dict(batch=256, epochs=2),           # the README quickstart
     "train": dict(cfg="gpt3-345M", batch=8, seq=1024, steps=5),
     "serve": dict(cfg="gpt2-en", page_size=128, max_seq_len=1024, slots=8,
-                  requests=16, prompt_lo=64, prompt_hi=700, new_tokens=64),
+                  requests=16, prompt_lo=64, prompt_hi=700, new_tokens=64,
+                  # serve-lfm2-closed64's expert layer at a decode step:
+                  # (token rows, h, m, experts, picks a token)
+                  experts=(64, 2048, 1792, 32, 4)),
     "kernels": dict(
         flash=[  # (b, s, h, d, causal, kv_lens, dropout)
             (8, 1024, 16, 64, True, False, 0.0),    # gpt3-345M train
@@ -765,7 +768,48 @@ def phase_serve(sz, ctx):
                              f"reference path by {err}")
     out["logits_err"] = err
     out["token_agreement"] = round(float(same), 4)
+    out["experts_err"] = _experts_paths_agree(sz["experts"], log)
     return out
+
+
+def _experts_paths_agree(shape, log):
+    """The held experts at one real width, both paths of nlp/moe.py on the
+    chip: the `grouped_experts` kernel (what `held_experts` takes here)
+    against the sorted `ragged_dot` products."""
+    from paddle_tpu.nlp import moe
+    t, h, m, experts, k = shape
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    u = _rand(ks[0], (t, h), jnp.float32)
+    w, idx = jax.lax.top_k(jax.random.uniform(ks[1], (t, experts)), k)
+    idx = idx.astype(jnp.int32)
+    w_gate_up = _rand(ks[2], (experts, h, 2 * m), jnp.bfloat16, h ** -0.5)
+    w_down = _rand(ks[3], (experts, m, h), jnp.bfloat16, m ** -0.5)
+    live = jnp.arange(t) < t - 3
+    if moe.experts_path(t, h, m) != "streamed":
+        raise AssertionError(f"serve: {t} rows at {h} x {m} do not take "
+                             f"the streaming kernel on this backend")
+    mark = len(log.calls)
+    with moe.recorded_paths() as seen:
+        got, aux = jax.jit(moe.held_experts)(u, idx, w, w_gate_up, w_down,
+                                             0, live)
+    _native_only(log.since(mark), "serve[experts]")
+    if seen != ["streamed"] or not log.since(mark):
+        raise AssertionError(f"serve: held_experts took {seen}, kernels "
+                             f"{log.since(mark)}")
+    key = jnp.where(live[:, None], idx, experts).reshape(-1)
+    want, sizes, _ = jax.jit(moe._ragged)(u, key, w, w_gate_up, w_down)
+    if [int(x) for x in aux] != [int(sizes.sum()), int((sizes > 0).sum()),
+                                 t - 3]:
+        raise AssertionError(f"serve: the kernel's path counted {aux}")
+    diff = float(jnp.max(jnp.abs(got - want)))
+    top = float(jnp.max(jnp.abs(want)))
+    print(f"serve: held experts at {t} rows, {experts} experts {h} x {m}, "
+          f"grouped_experts vs ragged_dot: largest difference {diff:.3e} "
+          f"of {top:.3f}", flush=True)
+    if not diff <= 1e-3 * top:
+        raise AssertionError(f"serve: grouped_experts differs from the "
+                             f"ragged_dot path by {diff} of {top}")
+    return diff / top
 
 
 # --------------------------------------------------------------------------

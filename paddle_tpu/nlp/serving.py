@@ -649,6 +649,8 @@ class ServingEngine:
         # routing: AUX_COUNTERS), summed per kind of program; stays empty
         # for a model whose layers count nothing
         self.aux_counts = {}
+        # program site -> "streamed" / "ragged", set when it is traced
+        self.experts_path = {}
 
     def _add_aux(self, program, aux):
         """Add one dispatch's per-layer counters ([layers, 3] int32, read
@@ -1351,7 +1353,8 @@ class ServingEngine:
                 return dict(zip(AUX_COUNTERS, (int(x) for x in v)))
             h["moe"] = dict(named(sum(self.aux_counts.values())),
                             by_program={k: named(v) for k, v in
-                                        self.aux_counts.items()})
+                                        self.aux_counts.items()},
+                            experts_path=dict(self.experts_path))
         if self.prefix is not None:
             st = self.prefix.stats()
             st["occupancy"] = self._g_prefix_occ.value
@@ -1445,8 +1448,14 @@ class ServingEngine:
         Steady-state host overhead is two dict reads per call."""
         def wrapped(*args):
             from ..autograd import no_grad
-            with no_grad():
-                return fn(*args)
+            from .moe import recorded_paths
+            with no_grad(), recorded_paths() as paths:
+                out = fn(*args)
+            if paths:
+                # runs when the program is traced: which way its expert
+                # layers went (moe.experts_path), health()["moe"]
+                self.experts_path[name] = "+".join(sorted(set(paths)))
+            return out
 
         kw = {"donate_argnums": donate_argnums} \
             if (self.donate and donate_argnums) else {}

@@ -100,7 +100,8 @@ def test_flash_decode_compiles_for_v5e(one_chip):
 def test_the_paged_decode_kernel_compiles_at_the_lfm2_cell_s_shape(one_chip):
     """`serve-lfm2-closed64`: 64 slots, 8 K/V heads serving 4 query heads
     of 64 each, 1025 pages of 128, 16 table entries a slot, bf16 pages."""
-    from paddle_tpu.ops.pallas import flash_decode as fd
+    # by name: the package re-exports a function under this module's name
+    fd = importlib.import_module("paddle_tpu.ops.pallas.flash_decode")
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -112,3 +113,36 @@ def test_the_paged_decode_kernel_compiles_at_the_lfm2_cell_s_shape(one_chip):
             q, pages, pages, sds((64, 16), jnp.int32),
             sds((64,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+# the held experts at both expert cells' real widths, a decode step's rows
+# and the widest prefill bucket the rule sends to the kernel
+# (moe.STREAMED_MAX_ROWS): (token rows, h, m, experts held)
+EXPERT_CELLS = {
+    "serve-lfm2-closed64": (64, 2048, 1792, 32),
+    "serve-axk1-closed32": (32, 7168, 2048, 12),
+    "lfm2_prefill_128": (128, 2048, 1792, 32),
+    "axk1_prefill_128": (128, 7168, 2048, 12),
+}
+
+
+@pytest.mark.parametrize("cell", list(EXPERT_CELLS))
+def test_grouped_experts_compiles_at_the_cell_s_widths(cell, one_chip):
+    """One kernel, within the VMEM limit it sets for itself (the compiler
+    refuses a kernel that needs more), with the chip's 128 MiB far off."""
+    from paddle_tpu.ops.pallas import grouped_experts as ge
+    t, h, m, held = EXPERT_CELLS[cell]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda *a: ge.grouped_experts(
+        *a, interpret=False)).lower(
+            sds((t, h), jnp.float32), sds((t, held), jnp.float32),
+            sds((held,), jnp.bool_), sds((held, h, 2 * m), jnp.bfloat16),
+            sds((held, m, h), jnp.bfloat16)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    limit = ge._plan(t, h, m, jnp.bfloat16, None, None)[3]
+    assert f'"vmem_limit_bytes":{limit}' in text.replace(" ", "") \
+        or str(limit) in text
+    assert limit <= 48 << 20

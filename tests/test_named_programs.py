@@ -22,10 +22,11 @@ from paddle_tpu.observability.trace import (  # noqa: E402
 
 # the package re-exports functions under its modules' names
 (_common, conv_bn_act, flash_attention, flash_decode, fused_adamw,
- fused_ln, latent_decode) = (
+ fused_ln, latent_decode, grouped_experts) = (
     importlib.import_module("paddle_tpu.ops.pallas." + m)
     for m in ("_common", "conv_bn_act", "flash_attention", "flash_decode",
-              "fused_adamw", "fused_ln", "latent_decode"))
+              "fused_adamw", "fused_ln", "latent_decode",
+              "grouped_experts"))
 
 
 # -- programs ----------------------------------------------------------------
@@ -92,6 +93,15 @@ def _latent_decode():
         q, p, table, lens, 32, 0.2, interpret=True)), (q, pages)
 
 
+def _grouped_experts():
+    x = jnp.ones((8, 128), jnp.float32)
+    w_gate_up = jnp.ones((2, 128, 256), jnp.float32)
+    w_down = jnp.ones((2, 128, 128), jnp.float32)
+    return (lambda x, a, b: grouped_experts.grouped_experts(
+        x, jnp.ones((8, 2)), jnp.ones((2,), bool), a, b,
+        interpret=True)), (x, w_gate_up, w_down)
+
+
 def _ln(entry, grad):
     x = jnp.ones((4, 32, 64), jnp.float32)
     g = jnp.ones((64,), jnp.float32)
@@ -125,6 +135,7 @@ KERNEL_ENTRIES = [
     ("flash_decode_dense", _dense_decode, {"flash_fwd"}),
     ("paged_flash_decode", _paged_decode, {"flash_decode"}),
     ("latent_flash_decode", _latent_decode, {"latent_decode"}),
+    ("grouped_experts", _grouped_experts, {"grouped_experts"}),
     ("fused_add_ln", lambda: _ln(fused_ln.fused_add_layer_norm, False),
      {"fused_add_ln_fwd"}),
     ("fused_add_ln_grad", lambda: _ln(fused_ln.fused_add_layer_norm, True),
@@ -161,7 +172,7 @@ def test_kernel_names_are_unique_over_the_call_sites():
     # backward's one site is called under its two names
     shared = [n for n in set(names) if names.count(n) > 1]
     assert shared == ["flash_fwd"] and names.count("flash_fwd") == 2
-    assert sites == 12 and len(names) == 13 and len(set(names)) == 12
+    assert sites == 13 and len(names) == 14 and len(set(names)) == 13
 
 
 def test_a_kernel_without_a_name_is_an_error():
