@@ -120,6 +120,8 @@ def artifact_fingerprint(engine):
         "steps_per_dispatch": engine.steps_per_dispatch,
         "pad_token_id": engine.pad_token_id,
         "use_flash": bool(engine.use_flash),
+        # the pools' layout, which the programs' signatures carry
+        "kv_heads_per_row": engine.kv_heads_per_row,
         "donate": bool(engine.donate),
         "sampling": {"temperature": engine.temperature,
                      "top_k": engine.top_k,

@@ -167,7 +167,9 @@ class ServingEngine:
         measured it faster, and the gathered form elsewhere.
         health()["decode_attention"] names the one built
         ("paged_kernel" | "in_place" | "gathered" |
-        "latent_paged_kernel" | "latent_gathered").
+        "latent_paged_kernel" | "latent_gathered"). The paged kernel
+        reads float K/V pools of heads narrower than a lane tile with
+        heads side by side in a row (health()["kv_heads_per_row"]).
     steps_per_dispatch: decode tokens per compiled call (the scan
         length) — admission/eviction happen at dispatch boundaries.
     admission_policy: what to do with the queue head when pages run
@@ -387,8 +389,14 @@ class ServingEngine:
 
         self._params, self._buffers = model.raw_state()
         self._pages = [spec.alloc(self.num_pages, self.page_size,
-                                  self.cache_dtype, self.max_slots)
+                                  self.cache_dtype, self.max_slots,
+                                  use_flash=self.use_flash)
                        for spec in specs]
+        # the K/V heads a row of the pools holds side by side, as the
+        # spec laid them out for the attention the decode program reads
+        # them with (KVCacheSpec.heads_per_row); None without K/V pools
+        self.kv_heads_per_row = None if latent else kv.heads_per_row(
+            self.cache_dtype, self.use_flash)
         # prefills that wrote a layer's per-slot state (one count a state
         # layer and admission): health()["conv_state_prefill_writes"]
         self.conv_state_prefill_writes = 0
@@ -1346,6 +1354,8 @@ class ServingEngine:
                           "top_k": self.top_k,
                           "seed": self.sampling_seed},
              "compile_counts": self.compile_counts()}
+        if self.kv_heads_per_row is not None:
+            h["kv_heads_per_row"] = self.kv_heads_per_row
         if self._state_layers:
             h["conv_state_prefill_writes"] = self.conv_state_prefill_writes
         if self.aux_counts:

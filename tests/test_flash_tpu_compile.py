@@ -115,6 +115,58 @@ def test_the_paged_decode_kernel_compiles_at_the_lfm2_cell_s_shape(one_chip):
     assert text.count("tpu_custom_call") == 1
 
 
+# (kv heads, query heads per kv head, head size): serve-lfm2-closed64's
+# attention layers, and a GPT's heads of 128
+DECODE_LOOPS = {"serve-lfm2-closed64": (8, 4, 64), "gpt_d128": (16, 1, 128)}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_LOOPS))
+def test_the_decode_loop_copies_no_pool(cell, one_chip):
+    """Four layers' token writes and paged kernel in a loop of 8 steps,
+    the pools as the engine lays them out for the kernel (64 slots, 1025
+    pages of 128, 16 table entries a slot, bf16). With rows of one head
+    of 64 the compiler kept each pool with the page's rows on the lanes
+    for the scatter and copied all eight back to row-major for the kernel
+    every step (537 MB of scratch); heads side by side in rows of 128
+    leave nothing to copy."""
+    from paddle_tpu.nlp import paged_cache as pc
+    fd = importlib.import_module("paddle_tpu.ops.pallas.flash_decode")
+    hkv, g, d = DECODE_LOOPS[cell]
+    b, layers = 64, 4
+    pool = jax.eval_shape(lambda: pc.KVCacheSpec(hkv, d).alloc(
+        1025, 128, "bfloat16", use_flash=True)[0])
+
+    def loop(pools, pt, pos, q, k):
+        def step(carry, _):
+            pools, pos = carry
+            new, outs = [], []
+            for kp, vp in pools:
+                cache = pc.PagedLayerCache(kp, vp, pt, pos)
+                kp, vp, _, _ = pc.write_token_kv(cache, k, k,
+                                                 jnp.ones((b,), bool))
+                outs.append(fd.paged_flash_decode(
+                    q, kp, vp, pt, pos + 1, interpret=False).sum())
+                new.append((kp, vp))
+            return (new, pos + 1), sum(outs)
+        return jax.lax.scan(step, (pools, pos), None, length=8)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = sds(pool.shape, pool.dtype)
+    compiled = jax.jit(loop, donate_argnums=(0,)).lower(
+        [(pages, pages)] * layers, sds((b, 16), jnp.int32),
+        sds((b,), jnp.int32), sds((b, hkv, g, d), jnp.float32),
+        sds((b, hkv, d), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == layers
+    shape = "[" + ",".join(map(str, pool.shape)) + "]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and shape in ln.split("copy(")[0]]
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 # the held experts at both expert cells' real widths, a decode step's rows
 # and the widest prefill bucket the rule sends to the kernel
 # (moe.STREAMED_MAX_ROWS): (token rows, h, m, experts held)

@@ -229,6 +229,22 @@ def test_the_paged_kernel_at_head_size_64_agrees_with_the_reference():
     assert _worst_gap(w, cfg, requests, _serve(eng, requests)) < F32_GAP
 
 
+def test_the_paged_kernel_on_paired_pools_agrees_with_the_reference():
+    """The cell's layout at a tiny size: 4 query heads over 2 K/V heads of
+    64, so that a row of each pool holds both K/V heads (rows of 128)."""
+    model, w, cfg = _model(hidden_size=256, num_attention_heads=4,
+                           num_key_value_heads=2, num_hidden_layers=3,
+                           layer_types=("conv", "full_attention", "conv"))
+    eng = ServingEngine(model, cache_dtype="float32", use_flash=True,
+                        **ENGINE)
+    h = eng.health()
+    assert h["decode_attention"] == "paged_kernel"
+    assert h["kv_heads_per_row"] == 2
+    assert eng._pages[1][0].shape == (1, eng.num_pages, 16, 128)
+    requests = list(zip(_prompts(8, 19, 6), (9, 5)))
+    assert _worst_gap(w, cfg, requests, _serve(eng, requests)) < F32_GAP
+
+
 def test_health_names_what_the_engine_holds_by_kind_of_layer():
     model, _, _ = _model()
     eng = ServingEngine(model, cache_dtype="bfloat16", **ENGINE)
@@ -244,6 +260,8 @@ def test_health_names_what_the_engine_holds_by_kind_of_layer():
     gpt = ServingEngine(GPTForCausalLM.from_config_name("gpt-tiny"),
                         **ENGINE)
     assert gpt.health()["cache_layers"] == {"kv": 2}
+    assert gpt.health()["kv_heads_per_row"] == 1
+    assert h["kv_heads_per_row"] == 1
     assert "conv_state_prefill_writes" not in gpt.health()
 
 
