@@ -1010,52 +1010,50 @@ class ServingEngine:
                                "a boot step, not a mid-traffic one")
         warmed = []
         norm = sorted({self._bucket_for(n) for n in buckets})
-        for n in norm:
-            if n in self._warmed_buckets:
-                continue
-            # the pool is donated to the program and the returned
-            # buffers adopted (contents untouched outside the trash
-            # page); the RNG rides along as a synthetic key only —
-            # host state is NOT advanced (see docstring)
-            self._prime(f"prefill_{n}", self._prefill_fn(n))
-            self._warmed_buckets.add(n)
-            warmed.append(n)
-        if self.prefix is not None and norm:
-            # tail-prefill ladder: a prefix HIT on a prompt of bucket n
-            # runs a tail of 1..n tokens, whose bucket is one of the
-            # pow2/whole-page values below n — trace them all now so a
-            # hit never compiles mid-traffic (the hit path is gated on
-            # exactly this set)
-            tails = set()
+        # the programs primed here name the phase as their parent in
+        # the tracer's staged records (set-up, program by program)
+        with self.tracer.phase("warmup"):
             for n in norm:
-                tails.update(self._bucket_for(t)
-                             for t in range(1, n + 1))
-            for t in sorted(tails):
-                if t in self._warmed_tail_buckets:
+                if n in self._warmed_buckets:
                     continue
-                self._prime(f"tail_prefill_{t}",
-                            self._tail_prefill_fn(t))
-                self._warmed_tail_buckets.add(t)
-            self._warm_eager_ladder(norm)
-        if decode and not self._warmed_decode:
-            self._prime("decode", self._decode_fn)
-            self._warmed_decode = True
-        if self._spec is not None and decode:
-            # speculative programs: the folded verify (all-trash table,
-            # inactive slots — writes land in the trash page) plus the
-            # proposer's own programs (draft prefill per warmed bucket
-            # + the propose scan for a model draft; nothing for ngram).
-            # _warmed_spec is the arming gate: until it flips, every
-            # dispatch takes the plain decode path
-            if not self._warmed_spec:
-                self._prime("spec_verify", self._spec_verify_fn)
-                self._warmed_spec = True
-            self._spec.warmup(self, norm)
-        from ..observability import flightrec
-        flightrec.note("serve_warmup", buckets=warmed,
-                       tail_buckets=sorted(self._warmed_tail_buckets),
-                       decode=self._warmed_decode,
-                       spec=self._warmed_spec)
+                # the pool is donated to the program and the returned
+                # buffers adopted (contents untouched outside the trash
+                # page); the RNG rides along as a synthetic key only —
+                # host state is NOT advanced (see docstring)
+                self._prime(f"prefill_{n}", self._prefill_fn(n))
+                self._warmed_buckets.add(n)
+                warmed.append(n)
+            if self.prefix is not None and norm:
+                # tail-prefill ladder: a prefix HIT on a prompt of bucket n
+                # runs a tail of 1..n tokens, whose bucket is one of the
+                # pow2/whole-page values below n — trace them all now so a
+                # hit never compiles mid-traffic (the hit path is gated on
+                # exactly this set)
+                tails = set()
+                for n in norm:
+                    tails.update(self._bucket_for(t)
+                                 for t in range(1, n + 1))
+                for t in sorted(tails):
+                    if t in self._warmed_tail_buckets:
+                        continue
+                    self._prime(f"tail_prefill_{t}",
+                                self._tail_prefill_fn(t))
+                    self._warmed_tail_buckets.add(t)
+                self._warm_eager_ladder(norm)
+            if decode and not self._warmed_decode:
+                self._prime("decode", self._decode_fn)
+                self._warmed_decode = True
+            if self._spec is not None and decode:
+                # speculative programs: the folded verify (all-trash table,
+                # inactive slots — writes land in the trash page) plus the
+                # proposer's own programs (draft prefill per warmed bucket
+                # + the propose scan for a model draft; nothing for ngram).
+                # _warmed_spec is the arming gate: until it flips, every
+                # dispatch takes the plain decode path
+                if not self._warmed_spec:
+                    self._prime("spec_verify", self._spec_verify_fn)
+                    self._warmed_spec = True
+                self._spec.warmup(self, norm)
         return warmed
 
     def _warm_args(self, name):

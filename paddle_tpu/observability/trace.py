@@ -23,17 +23,30 @@ Per-call steady-state overhead is two dict reads and a perf_counter —
 no device sync, no shape walking. Tracers register in a process-wide
 WeakSet; ``report_all()`` merges every live tracer's report into the
 run report exported next to metrics.json.
+
+A call that traced also gets a STAGED record of the program's build
+(the set-up a warm start still pays): ``t0``/``t1`` on perf_counter
+(``t1`` after the outputs are ready), the ``stages`` jax reported
+through ``jax.monitoring`` on this thread between them — trace,
+lowering (Pallas -> Mosaic lives there), backend compile or
+persistent-cache load, cache retrieval and hit, first run — the same
+for the introspection replay under ``introspect``, the Pallas call
+sites the trace met (``kernel_places``) and the enclosing build
+(``parent``: a ``phase`` such as ``ServingEngine.warmup``, or the outer
+site). The listeners only fire while jax builds something, and the
+wrapper reads what they kept only on a call that traced.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import re
 import threading
 import time
 
 __all__ = ["RecompileTracer", "get_tracer", "all_tracers", "report_all",
-           "program_name"]
+           "program_name", "kernel_place"]
 
 # REENTRANT: close() runs from GC finalizers (Engine's
 # weakref.finalize, ServingEngine.__del__), and a cyclic collection
@@ -53,6 +66,129 @@ _all_lock = threading.RLock()
 # list of individual reports would silently drop it).
 _all_tracers = []
 _closed_agg = {}
+
+# what jax reports while it builds a program (jax.monitoring), by the
+# stage each duration is summed into
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    # on a persistent-cache hit: key, read and deserialise
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_listening = False
+
+
+class _Local(threading.local):
+    """Per thread: `reported`, a bounded deque of (stage or event,
+    seconds, perf_counter at its end) — the newest matter, a build's
+    outermost stages end last; `building`, the stack of _Frames open;
+    `traced`, the frame of the trace that ended last."""
+
+    def __init__(self):
+        self.reported = collections.deque(maxlen=4096)
+        self.building = []
+        self.traced = None
+
+
+_local = _Local()
+
+
+class _Frame:
+    """A build open on this thread: a program being traced (`program`)
+    or a phase; `places` counts the Pallas call sites traced inside it."""
+    __slots__ = ("name", "program", "places")
+
+    def __init__(self, name, program):
+        self.name, self.program, self.places = name, program, {}
+
+
+def _on_duration(event, seconds, **_meta):
+    stage = _STAGE_EVENTS.get(event)
+    if stage is not None or event == _RETRIEVAL:
+        _local.reported.append((stage or event, seconds,
+                                time.perf_counter()))
+
+
+def _on_event(event, **_meta):
+    if event == _CACHE_ASKED or event == _CACHE_HIT:
+        _local.reported.append((event, 0.0, time.perf_counter()))
+
+
+def _listen():
+    """Register the two stage listeners with jax.monitoring, once per
+    process (the registry is jax's own, process-wide). They fire only
+    while jax builds something."""
+    global _listening
+    with _all_lock:
+        if _listening:
+            return
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _listening = True
+
+
+def _stages(t0, t1):
+    """The build stages jax reported on this thread that ended inside
+    [t0, t1]: seconds of `trace_s`, `lower_s`, `backend_s` and
+    `cache_retrieval_s` (inside `backend_s`), and `cache_hit`: whether
+    every persistent-cache lookup hit, None where none was made. A stage
+    that ran inside another (a nested jit traced inside the program's
+    trace, a nested site's replay) is part of that one and not added
+    again, so the stages never sum past the wall time. Also returns
+    where the last stage ended (t0 when none did)."""
+    out = dict.fromkeys(("trace_s", "lower_s", "backend_s",
+                         "cache_retrieval_s"), 0.0)
+    asked = hits = 0
+    end = t0
+    outer = []      # (start, end) of the stages kept, newest first
+    # an enclosing stage ends after what it encloses: newest first, so
+    # it is met before them (the slack absorbs the two clocks' skew)
+    for event, seconds, at in reversed(_local.reported):
+        if at < t0:
+            break
+        if at > t1:
+            continue
+        if event == _RETRIEVAL:
+            out["cache_retrieval_s"] += seconds
+        elif event == _CACHE_ASKED:
+            asked += 1
+        elif event == _CACHE_HIT:
+            hits += 1
+        elif not any(s - 1e-4 <= at - seconds and at <= e
+                     for s, e in outer):
+            outer.append((at - seconds, at))
+            out[event] += seconds
+            end = max(end, at)
+    out["cache_hit"] = hits >= asked if asked else None
+    return out, end
+
+
+def kernel_place(name):
+    """Count one call site of the Pallas kernel `name` in every build
+    open on this thread. Called by `ops.pallas._common.pallas_call` as
+    the kernel enters a trace, so a kernel in a `lax.scan` body counts
+    once, and one inside a nested `jax.jit` once for each trace of that
+    jit (not for the calls that reuse it); the introspection replay's
+    re-trace counts nothing."""
+    frames = _local.building
+    if not frames or _introspecting_fn()():
+        return
+    for f in frames:
+        f.places[name] = f.places.get(name, 0) + 1
+
+
+def _introspecting_fn():
+    """introspect.introspecting, or a False one where this file was
+    loaded on its own (tools/_obs.py)."""
+    try:
+        from .introspect import introspecting
+    except ImportError:
+        return lambda: False
+    return introspecting
 
 
 def program_name(site):
@@ -113,17 +249,23 @@ class RecompileTracer:
         skips the AOT replay — for user-facing one-shot compiles
         (to_static) where doubling the compile buys nothing."""
         import jax
-        try:
-            from .introspect import introspecting
-        except ImportError:  # standalone file-load (tools/_obs.py)
-            def introspecting():
-                return False
+        introspecting = _introspecting_fn()
+        _listen()
         counts = self._counts
 
         def traced(*args, **kw):
-            if not introspecting():
-                counts[site] = counts.get(site, 0) + 1
-            return fn(*args, **kw)
+            if introspecting():
+                return fn(*args, **kw)
+            counts[site] = counts.get(site, 0) + 1
+            frames = _local.building
+            frame = _Frame(site, True)
+            frames.append(frame)
+            try:
+                return fn(*args, **kw)
+            finally:
+                frames.pop()
+                # for the wrapper's record, read right after this trace
+                _local.traced = frame
 
         # the one place a compiled program gets its name (see
         # program_name): without it every site is `jit_traced`
@@ -138,10 +280,8 @@ class RecompileTracer:
             t0 = time.perf_counter()
             out = jfn(*args, **kw)
             if counts.get(site, 0) != before:
-                wall = time.perf_counter() - t0
-                tracer._note(site, args, kw, wall)
-                if introspect:
-                    tracer._introspect(site, jfn, args, kw, wall)
+                tracer._built(site, jfn if introspect else None, args, kw,
+                              out, t0)
             return out
 
         call.site = site
@@ -154,7 +294,55 @@ class RecompileTracer:
                 setattr(call, attr, getattr(jfn, attr))
         return call
 
-    def _note(self, site, args, kwargs, wall_s):
+    def _built(self, site, jfn, args, kwargs, out, t0):
+        """Record a call that traced, with its staged build; then (jfn
+        given) run the introspection replay and add its stages."""
+        wall = time.perf_counter() - t0
+        frame, _local.traced = _local.traced, None
+        frames = _local.building
+        if not any(f.program for f in frames):
+            # (inside an outer site's trace the outputs are tracers:
+            # nothing runs, and the outer record holds this build)
+            import jax
+            try:
+                jax.block_until_ready(out)
+            except Exception:  # noqa: BLE001 — the caller meets a failed run
+                pass
+        t1 = time.perf_counter()
+        stages, end = _stages(t0, t1)
+        stages["first_run_s"] = t1 - end
+        ev = self._note(site, args, kwargs, wall, {
+            "kind": "program", "t0": t0, "t1": t1, "stages": stages,
+            "introspect": None,
+            "kernel_places": dict(frame.places)
+            if frame is not None and frame.name == site else {},
+            "parent": frames[-1].name if frames else None})
+        if jfn is not None:
+            ev["introspect"] = self._introspect(site, jfn, args, kwargs,
+                                                wall)
+
+    @contextlib.contextmanager
+    def phase(self, name):
+        """A stretch of set-up that builds programs (ServingEngine.warmup):
+        the sites traced inside it name it as their `parent`, and it is
+        one record of `kind` "phase" in the event log, with its wall time
+        and the Pallas call sites of everything built inside it."""
+        frames = _local.building
+        frame = _Frame(name, False)
+        t0 = time.perf_counter()
+        frames.append(frame)
+        try:
+            yield
+        finally:
+            frames.remove(frame)
+            t1 = time.perf_counter()
+            self._events.append({
+                "site": name, "kind": "phase", "ts": round(time.time(), 6),
+                "t0": t0, "t1": t1, "wall_s": t1 - t0,
+                "kernel_places": frame.places,
+                "parent": frames[-1].name if frames else None})
+
+    def _note(self, site, args, kwargs, wall_s, staged):
         try:
             sig = _signature(args, kwargs)
         except Exception:  # noqa: BLE001 — accounting must never kill a step
@@ -164,12 +352,14 @@ class RecompileTracer:
         seen.add(sig)
         if unexpected:
             self._unexpected[site] = self._unexpected.get(site, 0) + 1
-        self._events.append({
+        ev = {
             "site": site, "signature": sig,
             "ts": round(time.time(), 6),
             "compile_s": round(wall_s, 6),
             "unexpected": unexpected,
-        })
+            **staged,
+        }
+        self._events.append(ev)
         reg = self._registry
         if reg is not None:
             reg.counter("recompile_traces_total",
@@ -184,17 +374,24 @@ class RecompileTracer:
             reg.histogram("recompile_wall_seconds",
                           help="wall time of calls that traced",
                           labels={"tracer": self.name}).observe(wall_s)
+        return ev
 
     def _introspect(self, site, jfn, args, kwargs, wall_s):
         """Capture the freshly-compiled executable's cost/memory
         analysis (introspect.capture_site). Failure-proof: a broken
-        AOT path records a skip reason, never kills the step."""
+        AOT path records a skip reason, never kills the step. Returns
+        the replay's stages (it runs nothing) and its `wall_s`."""
+        t0 = time.perf_counter()
         try:
             from .introspect import capture_site
             capture_site(self.name, site, jfn, args, kwargs,
                          wall_s=wall_s, registry=self._registry)
         except Exception:  # noqa: BLE001 — accounting must never kill a step
             pass
+        t1 = time.perf_counter()
+        stages, _ = _stages(t0, t1)
+        stages["wall_s"] = t1 - t0
+        return stages
 
     # -- manual accounting (sites not built via .jit) ----------------------
     def count_trace(self, site):

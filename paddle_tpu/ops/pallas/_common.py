@@ -1,6 +1,8 @@
 """The one `pallas_call` every kernel in this package goes through.
 
-Three decisions live here and nowhere else:
+Three decisions live here and nowhere else (and the one count every
+kernel gets: each call is a place in the programs being traced,
+`observability.trace.kernel_place`):
 
 - interpret mode: kernels compile natively (Mosaic) on a TPU backend
   and run in the Pallas interpreter on any other backend, which is how
@@ -22,6 +24,8 @@ from __future__ import annotations
 import jax
 from jax.experimental import pallas as pl
 
+from ...observability.trace import kernel_place
+
 
 def interpret_default() -> bool:
     """True iff kernels must run interpreted: the backend is not a TPU."""
@@ -40,6 +44,9 @@ def pallas_call(kernel, *, name, interpret=None, **kwargs):
     call = pl.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
 
     def run(*args):
+        # one place in each program being traced (the set-up record's
+        # `kernel_places`)
+        kernel_place(name)
         with jax.enable_x64(False):
             return call(*args)
     return run

@@ -261,6 +261,104 @@ class TestRecompileTracer:
         names = [t["tracer"] for t in rep["tracers"]]
         assert "zz-report-all-test" in names
 
+    def test_traced_call_gets_a_staged_record(self):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.observability import trace
+
+        def body(a):
+            # jits traced inside the program's trace: their trace events
+            # are part of its trace, not added to it again
+            for i in range(8):
+                a = jax.jit(lambda x, i=i: jnp.tanh(x @ x) + i)(a)
+            return a
+
+        tr = RecompileTracer(name="t")
+        f = tr.jit("mm", body)
+        a = jnp.ones((32, 32), jnp.float32)
+        f(a)
+        [e] = tr.events()
+        assert e["kind"] == "program" and e["parent"] is None
+        st = e["stages"]
+        parts = ("trace_s", "lower_s", "backend_s", "first_run_s")
+        assert all(st[k] >= 0 for k in parts + ("cache_retrieval_s",))
+        assert st["trace_s"] > 0 and st["lower_s"] > 0
+        assert sum(st[k] for k in parts) <= e["t1"] - e["t0"] + 1e-3
+        assert st["cache_retrieval_s"] <= st["backend_s"] + 1e-6
+        assert e["kernel_places"] == {}
+        # a call that does not trace adds no record and hears nothing
+        # from jax: the listeners fire only while a program is built
+        heard = len(trace._local.reported)
+        f(a)
+        assert tr.events() == [e]
+        assert len(trace._local.reported) == heard
+
+    def test_kernel_places_count_trace_sites(self):
+        """One place per pallas_call that entered the trace: twice in a
+        loop unrolled twice, once in a scan of two."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas._common import pallas_call
+
+        def body(x_ref, o_ref):
+            o_ref[...] = x_ref[...] * 2.0
+
+        def kern(x):
+            shape = jax.ShapeDtypeStruct(x.shape, x.dtype)
+            return pallas_call(body, name="double", out_shape=shape)(x)
+
+        def unrolled(x):
+            for _ in range(2):
+                x = kern(x)
+            return x
+
+        def scanned(x):
+            return jax.lax.scan(lambda c, _: (kern(c), None), x, None,
+                                length=2)[0]
+
+        tr = RecompileTracer(name="t")
+        x = jnp.ones((8, 128), jnp.float32)
+        out = tr.jit("unrolled", unrolled)(x)
+        assert float(out[0, 0]) == float(tr.jit("scanned", scanned)(x)[0, 0])
+        places = {e["site"]: e["kernel_places"] for e in tr.events()}
+        assert places == {"unrolled": {"double": 2}, "scanned": {"double": 1}}
+
+    def test_nested_site_has_its_own_record(self):
+        import jax.numpy as jnp
+        tr = RecompileTracer(name="t")
+        inner = tr.jit("inner", lambda x: x * 3.0, introspect=False)
+        tr.jit("outer", lambda x: inner(x) + 1.0)(jnp.ones((4,)))
+        inner_ev, outer_ev = tr.events()
+        assert (inner_ev["site"], inner_ev["parent"]) == ("inner", "outer")
+        assert (outer_ev["site"], outer_ev["parent"]) == ("outer", None)
+        # the inner trace runs inside the outer one and is part of it
+        assert outer_ev["stages"]["trace_s"] >= \
+            inner_ev["stages"]["trace_s"] > 0
+        assert inner_ev["introspect"] is None
+        assert outer_ev["introspect"] is not None
+
+    def test_serve_warmup_records_each_program_under_warmup(self):
+        from paddle_tpu.nlp.gpt import GPTForCausalLM, _resolve_config
+        from paddle_tpu.nlp.serving import ServingEngine
+        paddle.seed(0)
+        model = GPTForCausalLM(_resolve_config("gpt-tiny"))
+        eng = ServingEngine(model, max_slots=2, page_size=16,
+                            max_seq_len=48, steps_per_dispatch=2,
+                            prefix_cache=False)
+        eng.warmup(buckets=(5, 17))
+        evs = eng.tracer.events()
+        programs = [e for e in evs if e["kind"] == "program"]
+        assert sorted(e["site"] for e in programs) == sorted(
+            eng.compile_counts())
+        assert {e["parent"] for e in programs} == {"warmup"}
+        assert all(e["t1"] > e["t0"] and e["introspect"] is not None
+                   for e in programs)
+        [phase] = [e for e in evs if e["kind"] == "phase"]
+        assert (phase["site"], phase["parent"]) == ("warmup", None)
+        assert phase["t0"] <= min(e["t0"] for e in programs)
+        assert phase["t1"] >= max(e["t1"] for e in programs)
+        assert phase["wall_s"] == phase["t1"] - phase["t0"]
+
     def test_serve_wave_traces_warmup_only(self, tmp_path):
         """The acceptance shape: a zero-recompile serve wave records
         warmup traces and NOTHING after — and the instrumentation
@@ -822,6 +920,35 @@ class TestIntrospect:
         assert (g is None) == (e["flops"] is None)
         rep = introspect.cost_report()
         assert "intro_t/mm" in rep["sites"]
+        tr.close()
+
+    def test_replay_stages_sit_under_introspect(self):
+        """The AOT replay is timed apart from the call, under
+        `introspect`, and its re-trace counts no kernel place."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas._common import pallas_call
+
+        def body(x_ref, o_ref):
+            o_ref[...] = x_ref[...] + 1.0
+
+        def f(x):
+            shape = jax.ShapeDtypeStruct(x.shape, x.dtype)
+            return pallas_call(body, name="inc", out_shape=shape)(x) * 2.0
+
+        tr = RecompileTracer(name="intro_stages")
+        with tr.phase("boot"):
+            tr.jit("inc", f)(jnp.ones((8, 128), jnp.float32))
+        e, phase = tr.events()
+        rep = e["introspect"]
+        assert set(rep) == {"trace_s", "lower_s", "backend_s",
+                            "cache_retrieval_s", "cache_hit", "wall_s"}
+        assert all(rep[k] >= 0 for k in rep if k != "cache_hit")
+        assert rep["trace_s"] + rep["lower_s"] + rep["backend_s"] <= \
+            rep["wall_s"] + 1e-3
+        assert e["t1"] <= phase["t1"]
+        assert introspect.site_cost("inc", tracer="intro_stages") is not None
+        assert e["kernel_places"] == phase["kernel_places"] == {"inc": 1}
         tr.close()
 
     def test_compile_budget_skips_with_reason(self):
