@@ -101,6 +101,15 @@ def set_flags(flags: dict):
                               None if v == "default" else v)
 
 
+def products_round_to_bfloat16():
+    """The backend multiplies float32 operands as bfloat16, with float32
+    accumulation: a TPU at the default matmul precision. Only here may a
+    float32 weight be held in bfloat16 (`nlp.serving.hold_weights`): the
+    products come out as the float32 ones would."""
+    return (jax.default_backend() == "tpu"
+            and jax.config.jax_default_matmul_precision in (None, "default"))
+
+
 def get_flags(keys=None):
     if keys is None:
         return dict(_FLAGS)

@@ -122,6 +122,9 @@ def artifact_fingerprint(engine):
         "use_flash": bool(engine.use_flash),
         # the pools' layout, which the programs' signatures carry
         "kv_heads_per_row": engine.kv_heads_per_row,
+        # the weights' dtypes, which the programs' signatures carry
+        "held_weights": {k: engine.held_weights[k]
+                         for k in ("leaves", "dtype")},
         "donate": bool(engine.donate),
         "sampling": {"temperature": engine.temperature,
                      "top_k": engine.top_k,

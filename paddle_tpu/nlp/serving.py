@@ -57,7 +57,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import framework
+from ..distributed.fleet.mpu import ColumnParallelLinear, RowParallelLinear
 from ..nn.layer import functional_call
+from ..nn.layers_common import Linear
 from ..observability.metrics import MetricsRegistry
 from ..resilience import faults
 from ..resilience.retry import call_with_retries
@@ -129,6 +132,59 @@ class _Slot:
 
 def _next_pow2(n):
     return 1 << max(0, (int(n) - 1)).bit_length()
+
+
+_LINEAR_FAMILY = (Linear, ColumnParallelLinear, RowParallelLinear)
+
+
+def _held_names(model, params):
+    """The float32 matrices of rank 2 that only linear-family layers read
+    as their weight: what `F.linear` multiplies at bfloat16 on a TPU at
+    the default precision anyway. A parameter any other layer also reads
+    (a tied word embedding is gathered exactly) stays as it is."""
+    only_linear = {}
+    for _, layer in model.named_sublayers(include_self=True):
+        linear = isinstance(layer, _LINEAR_FAMILY)
+        for key, p in layer._parameters.items():
+            if p is not None:
+                only_linear[id(p)] = (only_linear.get(id(p), True)
+                                      and linear and key == "weight")
+    return sorted(n for n, p in model.named_parameters()
+                  if only_linear.get(id(p)) and params[n].ndim == 2
+                  and params[n].dtype == jnp.float32)
+
+
+def hold_weights(model, params):
+    """(params, {"leaves", "gb", "dtype"}): params with each leaf of
+    `_held_names` replaced by its bfloat16 rounding where the backend
+    would round it so at every product anyway, made by one program, run
+    once. The decode scan reads these: with float32 leaves its compiler
+    makes the bfloat16 copy outside the loop at every dispatch; with
+    held ones it makes none (`F.linear` widens a held matrix to the
+    float32 input, and the compiler folds that into the product, which
+    reads the bfloat16 matrix as it is). The single-pass programs keep
+    the float32 leaves: they round them inside their products, and on
+    the chip their executables grow several times over with held ones,
+    to be loaded again at every start. The model's Parameters keep
+    float32.
+
+    A held leaf keeps the sharding and the committed-ness of the leaf it
+    replaces (no `out_shardings`): one committed argument commits every
+    program's returned page pool, and the programs warmed on the
+    uncommitted pool would then compile again at their first real call."""
+    names = _held_names(model, params) \
+        if framework.products_round_to_bfloat16() else []
+    if names:
+        # run once at construction, before any serving program: not a site
+        # the zero-recompile accounting watches
+        # tpulint: disable-next-line=TRC01
+        narrow = jax.jit(lambda t: jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16), t))(
+                {n: params[n] for n in names})
+        params = {**params, **jax.block_until_ready(narrow)}
+    nbytes = sum(params[n].nbytes for n in names)
+    return params, {"leaves": len(names), "gb": nbytes / 1e9,
+                    "dtype": "bfloat16"}
 
 
 class ServingEngine:
@@ -388,6 +444,10 @@ class ServingEngine:
         self._mem_capacity_bytes = mem_capacity_bytes
 
         self._params, self._buffers = model.raw_state()
+        # what the decode scan reads (hold_weights); the single-pass
+        # programs (prefills, verify) read the model's own leaves
+        self._decode_params, self.held_weights = hold_weights(
+            model, self._params)
         self._pages = [spec.alloc(self.num_pages, self.page_size,
                                   self.cache_dtype, self.max_slots,
                                   use_flash=self.use_flash)
@@ -589,7 +649,10 @@ class ServingEngine:
                 registry=reg, name="engine",
                 capacity_bytes=self._mem_capacity_bytes)
             model_tag = type(model).__name__
-            self.ledger.track("weights", (self._params, self._buffers),
+            # the held copies beside the model's own leaves; a leaf
+            # both trees share counts once
+            self.ledger.track("weights", (self._params, self._decode_params,
+                                          self._buffers),
                               label=f"model={model_tag}")
             self.ledger.track(
                 "kv_pages", self._pages,
@@ -1076,7 +1139,7 @@ class ServingEngine:
                      np.ones((b,), np.int32),       # max_new
                      np.full((b,), -1, np.int32),   # eos
                      np.zeros((b, 2), np.uint32))   # key_base
-            return (self._params, self._buffers, self._pages,
+            return (self._decode_params, self._buffers, self._pages,
                     *(jnp.asarray(a) for a in sched))
         if name == "spec_verify":
             b = self.max_slots
@@ -1335,6 +1398,9 @@ class ServingEngine:
              "decode_attention": self.decode_attention,
              # what the engine holds, by kind of layer cache
              "cache_layers": dict(self.cache_layers),
+             # the weights its decode scan reads at bfloat16
+             # (hold_weights)
+             "held_weights": dict(self.held_weights),
              "dispatch_retries": int(self._m_retries.value),
              "deadline_misses": int(self._m_deadline.value),
              "evictions": int(self._m_evictions.value),
@@ -2256,7 +2322,7 @@ class ServingEngine:
             # re-submits a page pool that was never donated away
             faults.maybe_raise("dispatch_error", self._rounds)
             return self._decode_fn(
-                self._params, self._buffers, self._pages,
+                self._decode_params, self._buffers, self._pages,
                 pt_d, sl_d, lt_d, ac_d, dn_d, em_d, mn_d, eos_d, kb_d)
 
         from ..resilience.retry import retryable_for

@@ -31,8 +31,8 @@ def decode_jaxpr(eng):
     fn, _ = eng._aot_programs["decode"]
     sched = (eng._page_table, eng._seq_lens, eng._last_tokens, eng._active,
              eng._done, eng._emitted, eng._max_new, eng._eos, eng._key_base)
-    return jax.make_jaxpr(fn)(eng._params, eng._buffers, eng._pages,
-                              *sched).jaxpr
+    return jax.make_jaxpr(fn)(eng._decode_params, eng._buffers,
+                              eng._pages, *sched).jaxpr
 
 
 def jaxpr_eqns(jaxpr):

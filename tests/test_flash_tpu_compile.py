@@ -198,3 +198,44 @@ def test_grouped_experts_compiles_at_the_cell_s_widths(cell, one_chip):
     assert f'"vmem_limit_bytes":{limit}' in text.replace(" ", "") \
         or str(limit) in text
     assert limit <= 48 << 20
+
+
+@pytest.mark.parametrize("weights,most_mb", [("bfloat16", 64),
+                                             ("float32", None)])
+def test_the_decode_loop_reads_held_weights_in_place(weights, most_mb,
+                                                     one_chip):
+    """Four GPT-1.3B layers' projections (q, k, v, out, fc1, fc2 at width
+    2048) in a loop of 8 steps over 12 rows, float32 activations, through
+    `F.linear`. With the matrices held in bfloat16 the loop reads them as
+    they are: `F.linear` widens the weight to the input's float32, and
+    the compiler folds that into the product, which reads the bfloat16
+    matrix (3 MB of scratch). Float32 matrices it rounds outside the
+    loop, into a bfloat16 copy made at every call (292 MB of scratch for
+    the 403 MB the four layers hold at bfloat16)."""
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.tensor import Tensor
+    h, m, rows, layers = 2048, 8192, 12, 4
+    shapes = [(h, h)] * 4 + [(h, m), (m, h)]
+
+    def loop(ws, x):
+        def lin(a, w):
+            return F.linear(Tensor(a), Tensor(w))._value
+
+        def step(x, _):
+            for q, k, v, o, fc1, fc2 in ws:
+                x = x + lin(lin(x, q) * lin(x, k) + lin(x, v), o)
+                x = x + lin(jax.nn.gelu(lin(x, fc1)), fc2)
+            return x, x.sum()
+        return jax.lax.scan(step, x, None, length=8)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(loop).lower(
+        [[sds(s, weights) for s in shapes]] * layers,
+        sds((rows, h), jnp.float32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if most_mb is not None:
+        assert temp < most_mb << 20
+    else:
+        assert temp > 128 << 20

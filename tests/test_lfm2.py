@@ -299,7 +299,8 @@ def test_a_model_may_not_name_fewer_specs_than_layers():
 # sha256 (16 hex digits) of `str(jax.make_jaxpr(program)(*warm arguments))`
 # at the parent of this change (commit db72838), object addresses blanked:
 # engine of 3 slots, pages of 16, 64 positions, bfloat16 cache, no prefix
-# cache; made by the same `_program_digests` below in a checkout of it
+# cache; made by the same `_program_digests` below in a checkout of it.
+# lfm2-tiny's were recorded so at commit 9da9ec8.
 PARENT_PROGRAMS = {
     "gpt-tiny": {"decode": "20c74b91f6482067",
                  "prefill_32": "7d3ef6e68c8a51d5"},
@@ -307,7 +308,19 @@ PARENT_PROGRAMS = {
                    "prefill_32": "41b1905f26484f61"},
     "axk1-tiny": {"decode": "7476c1d7d6f2c3fd",
                   "prefill_32": "19d6ae16da613172"},
+    "lfm2-tiny": {"decode": "022e4a965bbf09ea",
+                  "prefill_32": "0a9b48ff0ad447e6"},
 }
+
+
+def _tiny(name):
+    from paddle_tpu.nlp.axk1 import AXK1ForCausalLM
+    from paddle_tpu.nlp.gpt import GPTForCausalLM
+    from paddle_tpu.nlp.llama import LlamaForCausalLM
+    cls = {"gpt-tiny": GPTForCausalLM, "llama-tiny": LlamaForCausalLM,
+           "axk1-tiny": AXK1ForCausalLM, "lfm2-tiny": LFM2ForCausalLM}[name]
+    paddle.seed(0)
+    return cls.from_config_name(name)
 
 
 def _program_digests(model):
@@ -327,15 +340,14 @@ def _program_digests(model):
 
 @pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))
 def test_the_accepted_models_answer_and_trace_what_they_did(name):
-    from paddle_tpu.nlp.axk1 import AXK1ForCausalLM
-    from paddle_tpu.nlp.gpt import GPTForCausalLM
-    from paddle_tpu.nlp.llama import LlamaForCausalLM
-    cls = {"gpt-tiny": GPTForCausalLM, "llama-tiny": LlamaForCausalLM,
-           "axk1-tiny": AXK1ForCausalLM}[name]
-    paddle.seed(0)
-    eng, digests = _program_digests(cls.from_config_name(name))
+    eng, digests = _program_digests(_tiny(name))
     assert digests == PARENT_PROGRAMS[name]
+    # nothing is held where products keep float32, as on this CPU
+    assert eng.health()["held_weights"]["leaves"] == 0
     spec = eng.cache_spec
+    if name == "lfm2-tiny":
+        assert eng.cache_layers == {"conv_state": 4, "kv": 2}
+        return
     if name == "axk1-tiny":
         assert type(spec) is paged_cache.LatentCacheSpec
         assert eng.cache_layers == {"latent": 3}
@@ -347,3 +359,27 @@ def test_the_accepted_models_answer_and_trace_what_they_did(name):
             or cfg.num_attention_heads, cfg.head_dim)
         assert eng.cache_layers == {"kv": cfg.num_hidden_layers}
     assert eng.cache_specs == [spec] * eng.num_layers
+
+
+# the linear-family matrices held where a TPU's products would round them:
+# 2 layers of q, k, v, out, fc1, fc2; 2 layers of q, k, v, o, gate, up,
+# down and the untied head
+HELD = {"gpt-tiny": 12, "llama-tiny": 15, "axk1-tiny": 0, "lfm2-tiny": 0}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))
+def test_with_weights_held_only_the_dense_models_programs_change(
+        monkeypatch, name):
+    """The expert models multiply through products of their own, no
+    linear-family layer's: nothing is held and their programs stay the
+    parent's. The dense ones hold their projections for the decode scan,
+    whose program reads them at bfloat16 by design; the prefills keep
+    the parent's."""
+    from paddle_tpu import framework
+    monkeypatch.setattr(framework, "products_round_to_bfloat16",
+                        lambda: True)
+    eng, digests = _program_digests(_tiny(name))
+    assert eng.health()["held_weights"]["leaves"] == HELD[name]
+    assert digests["prefill_32"] == PARENT_PROGRAMS[name]["prefill_32"]
+    assert (digests["decode"] != PARENT_PROGRAMS[name]["decode"]) \
+        == bool(HELD[name])
