@@ -70,7 +70,10 @@ SIZES = {
                   requests=16, prompt_lo=64, prompt_hi=700, new_tokens=64,
                   # serve-lfm2-closed64's expert layer at a decode step:
                   # (token rows, h, m, experts, picks a token)
-                  experts=(64, 2048, 1792, 32, 4)),
+                  experts=(64, 2048, 1792, 32, 4),
+                  # serve-olmohybrid-closed64's Gated DeltaNet layer over
+                  # one prompt of this many positions
+                  delta_rule_tokens=1024),
     "kernels": dict(
         flash=[  # (b, s, h, d, causal, kv_lens, dropout)
             (8, 1024, 16, 64, True, False, 0.0),    # gpt3-345M train
@@ -782,7 +785,82 @@ def phase_serve(sz, ctx):
     out["logits_err"] = err
     out["token_agreement"] = round(float(same), 4)
     out["experts_err"] = _experts_paths_agree(sz["experts"], log)
+    out["delta_rule_err"] = _delta_rule_paths_agree(sz["delta_rule_tokens"])
     return out
+
+
+# the chunked scan against the recurrence, both float32 with products at
+# HIGHEST: they differ in the order of their sums and in the chip's
+# float32 exp and log, which the recurrence compounds over its 1024
+# positions: 1.2e-6 of the state on the CPU, 1.7e-4 on a v5e. The scan's
+# products on bfloat16-rounded operands are off by 5.8e-3 (on the CPU)
+DELTA_STATE_TOL = 1e-3
+
+
+def _delta_rule_paths_agree(tokens):
+    """Olmo-Hybrid's Gated DeltaNet layer at its published widths (hidden
+    3840, 30 heads, dk 96, dv 192), bf16 weights from a seed with the
+    decays spread as the configuration's `assumed` draws them: one prompt
+    through the chunked prefill against the same prompt a token at a time
+    through the decode step (the recurrence), on the chip. Judged on the
+    state both leave (float32 throughout); the layer's outputs, whose
+    gated norm feeds a bf16 product, are reported beside it."""
+    import numpy as np
+    from paddle_tpu.nlp import olmo_hybrid as oh
+    from paddle_tpu.nlp.paged_cache import DeltaStateCache
+    from paddle_tpu.nn.layer import functional_call
+    from paddle_tpu.tensor import Tensor
+    import paddle_tpu as paddle
+    paddle.seed(3)
+    cfg = oh.OlmoHybridConfig(num_hidden_layers=1, dtype="bfloat16",
+                              layer_types=("linear_attention",))
+    layer = oh.OlmoHybridGatedDeltaNet(cfg)
+    heads = cfg.linear_num_key_heads
+    dt = np.exp(np.linspace(np.log(1e-3), np.log(0.1), heads))
+    layer.A_log._value = jnp.log(jnp.linspace(1.0, 16.0, heads)).astype(
+        jnp.bfloat16)
+    layer.dt_bias._value = jnp.asarray(dt + np.log(-np.expm1(-dt)),
+                                       jnp.bfloat16)
+    params, _ = layer.raw_state()
+    x = _rand(jax.random.PRNGKey(4), (1, tokens, cfg.hidden_size),
+              jnp.float32)
+
+    @jax.jit
+    def chunked(params, x):
+        out, kept = functional_call(layer, params, {}, Tensor(x),
+                                    kv_lens=jnp.full((1,), tokens,
+                                                     jnp.int32))
+        return out._value[0], kept.state[0]
+
+    @jax.jit
+    def recurrent(params, x):
+        # the convolution's rows carried in float32, as the prefill
+        # holds them (the engine stores them at the cache's width)
+        conv = jnp.zeros((1, cfg.linear_conv_kernel_dim - 1,
+                          cfg.conv_channels), jnp.float32)
+        state = jnp.zeros((1, heads, cfg.linear_key_head_dim,
+                           cfg.linear_value_head_dim), jnp.float32)
+
+        def token(carry, xt):
+            out, kept = functional_call(layer, params, {},
+                                        Tensor(xt[None, None]),
+                                        cache=DeltaStateCache(*carry))
+            return kept, out._value[0, 0]
+        (_, state), outs = jax.lax.scan(token, (conv, state), x[0])
+        return outs, state[0]
+
+    got_o, got_s = chunked(params, x)
+    want_o, want_s = recurrent(params, x)
+    err_s = _nerr(got_s, want_s)
+    err_o = _nerr(got_o, want_o)
+    print(f"serve: Gated DeltaNet at published widths over {tokens} "
+          f"positions, chunked prefill vs the recurrence: state normalised "
+          f"max error {err_s:.3e} (tol {DELTA_STATE_TOL}), outputs "
+          f"{err_o:.3e}", flush=True)
+    if not err_s <= DELTA_STATE_TOL:
+        raise AssertionError(f"serve: the chunked scan's state differs "
+                             f"from the recurrence's by {err_s}")
+    return {"state": err_s, "outputs": err_o}
 
 
 def _experts_paths_agree(shape, log):

@@ -207,7 +207,8 @@ def export_artifact(engine, root, prune=True):
     other = sorted(set(engine.cache_layers) - {"kv"})
     if other:
         what = {"latent": "serves from a latent paged cache",
-                "conv_state": "has layers with a per-slot state"}
+                "conv_state": "has layers with a per-slot state",
+                "delta_state": "has layers with a per-slot state"}
         raise ValueError(
             f"{type(engine.model).__name__} "
             f"{' and '.join(what[k] for k in other)}, which has no AOT "

@@ -35,7 +35,10 @@ the rows every head shares and no value pool (`LatentCacheSpec`,
 `PagedLatentCache`: nlp/axk1.py), or NO pages at all: a state of fixed
 size per serving slot, `[slots, taps, C]`, overwritten in place as the
 slot's sequence grows (`ConvStateSpec`, `ConvStateCache`: the short
-convolutions of nlp/lfm2.py, beside that model's K/V layers). Page table,
+convolutions of nlp/lfm2.py, beside that model's K/V layers), or such a
+state and beside it a float32 matrix `[slots, heads, dk, dv]` a slot
+(`DeltaStateSpec`, `DeltaStateCache`: the Gated DeltaNet layers of
+nlp/olmo_hybrid.py). Page table,
 trash page, positions and the engine's page accounting are the same for
 the paged kinds and count those layers alone.
 
@@ -62,7 +65,8 @@ prefix cache's private-tail pages safe to re-prefill after failover).
 A spec verify dispatch writes K+1 rows per slot into already-owned
 pages; committing j of them is one host-side integer add.
 
-None of that holds for a slot state (`ConvStateSpec`): it is overwritten
+None of that holds for a slot state (`ConvStateSpec`, `DeltaStateSpec`):
+it is overwritten
 in place, so a rejected write cannot be taken back and a prefix's pages
 say nothing of the state at the prefix's end. The engine refuses
 speculative verify, the prefix cache, an int8 state and AOT export by
@@ -79,10 +83,12 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["PagedLayerCache", "PagedLatentCache", "ConvStateCache",
-           "KVCacheSpec", "LatentCacheSpec", "ConvStateSpec", "LatentRows",
-           "PromptKV", "ConvStateRows", "AUX_COUNTERS", "cache_spec_of",
-           "cache_specs_of", "conv_state_step", "conv_state_at",
-           "write_prompt_state",
+           "DeltaStateCache", "KVCacheSpec", "LatentCacheSpec",
+           "ConvStateSpec", "DeltaStateSpec", "LatentRows", "PromptKV",
+           "ConvStateRows", "DeltaStateRows", "AUX_COUNTERS",
+           "cache_spec_of", "cache_specs_of", "conv_state_step",
+           "conv_state_at", "write_prompt_state",
+           "write_prompt_delta_state",
            "PrefixIndex", "alloc_pages",
            "prefix_fingerprints", "quantize_rows", "write_token_latent",
            "write_prompt_latent", "latent_paged_attention",
@@ -207,6 +213,27 @@ class ConvStateCache:
 
     def arrays(self):
         return (self.state,)
+
+
+class DeltaStateCache:
+    """One Gated DeltaNet layer's view of its state in the decode step:
+    `conv` [B, taps - 1, C], the last inputs of the layer's causal
+    convolution (the newest last), `state` [B, H, dk, dv] float32, the
+    recurrence's matrix, and `live` [B] bool as ConvStateCache's. Not a
+    pytree."""
+
+    __slots__ = ("conv", "state", "live", "aux")
+
+    def __init__(self, conv, state, live=None, aux=None):
+        self.conv, self.state = conv, state
+        self.live = live
+        self.aux = aux
+
+    def replaced(self, conv, state, aux=None):
+        return DeltaStateCache(conv, state, self.live, aux)
+
+    def arrays(self):
+        return (self.conv, self.state)
 
 
 def alloc_pages(num_pages, page_size, kv_heads, head_dim, cache_dtype,
@@ -339,6 +366,40 @@ class ConvStateSpec:
 
     def write_prompt(self, arrays, rows, pages_vec, slot=None):
         return (write_prompt_state(arrays[0], rows[0], slot),)
+
+
+class DeltaStateSpec:
+    """A Gated DeltaNet layer's cache: no pages, per serving slot the
+    convolution's last `taps - 1` inputs `[max_slots, taps - 1, channels]`
+    in the engine's cache dtype, and the recurrence's state `[max_slots,
+    heads, key_dim, value_dim]` in float32 whatever the cache dtype: the
+    state is summed into at every token, and bfloat16 would round each
+    sum (no int8 form). A prefill writes both rows of the slot it admits,
+    as ConvStateSpec's; the decode step updates the rows of live slots."""
+
+    kind, latent, paged = "delta_state", False, False
+
+    def __init__(self, channels, taps, heads, key_dim, value_dim):
+        self.channels, self.taps = int(channels), int(taps)
+        self.heads, self.key_dim, self.value_dim = \
+            int(heads), int(key_dim), int(value_dim)
+
+    def alloc(self, num_pages, page_size, cache_dtype, max_slots=None,
+              use_flash=False):
+        return (jnp.zeros((max_slots, self.taps - 1, self.channels),
+                          jnp.dtype(cache_dtype)),
+                jnp.zeros((max_slots, self.heads, self.key_dim,
+                           self.value_dim), jnp.float32))
+
+    def view(self, arrays, page_table, positions, use_flash=False,
+             live=None):
+        return DeltaStateCache(arrays[0], arrays[1], live)
+
+    def prompt_rows(self, layer):
+        return (layer.conv, layer.state)
+
+    def write_prompt(self, arrays, rows, pages_vec, slot=None):
+        return write_prompt_delta_state(*arrays, *rows, slot)
 
 
 def cache_spec_of(model):
@@ -487,6 +548,18 @@ class ConvStateRows:
         self.state, self.aux = state, aux
 
 
+class DeltaStateRows:
+    """What a Gated DeltaNet layer's cached (prefill) forward hands back:
+    the convolution's inputs `[B, taps - 1, C]` and the recurrence's state
+    `[B, H, dk, dv]`, both as they stand after each row's last true
+    token."""
+
+    __slots__ = ("conv", "state", "aux")
+
+    def __init__(self, conv, state, aux=None):
+        self.conv, self.state, self.aux = conv, state, aux
+
+
 def conv_state_step(cache: ConvStateCache, g):
     """One decode step of a slot state. g [B, C] float32, this token's
     input to the convolution. Returns (window [B, taps, C] float32: the
@@ -522,6 +595,16 @@ def write_prompt_state(state, rows, slot):
     of the slot it is admitted to. A `slot` past the last one (the
     engine's warm-up) writes nothing."""
     return state.at[slot].set(rows[0].astype(state.dtype), mode="drop")
+
+
+@jax.named_scope("delta_state_write")
+def write_prompt_delta_state(conv, state, conv_rows, state_rows, slot):
+    """Prefill write of one prompt's rows ([1, taps - 1, C] and [1, H, dk,
+    dv]) into the slot it is admitted to, whatever was there; a `slot`
+    past the last one (the engine's warm-up) writes nothing."""
+    return (conv.at[slot].set(conv_rows[0].astype(conv.dtype), mode="drop"),
+            state.at[slot].set(state_rows[0].astype(state.dtype),
+                               mode="drop"))
 
 
 def _to_width(x, width):

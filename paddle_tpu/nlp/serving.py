@@ -330,7 +330,9 @@ class ServingEngine:
         self.num_layers = cfg.num_hidden_layers
         kinds = [s.kind for s in specs]
         self.cache_layers = {k: kinds.count(k) for k in dict.fromkeys(kinds)}
-        self._state_layers = kinds.count("conv_state")
+        # layers with a per-slot state and no pages, and their kinds
+        self._state_layers = sum(not s.paged for s in specs)
+        state_kinds = [s.kind for s in specs if not s.paged]
         latent = "latent" in kinds
         if not latent:
             kv = specs[kinds.index("kv")]
@@ -457,9 +459,9 @@ class ServingEngine:
         # them with (KVCacheSpec.heads_per_row); None without K/V pools
         self.kv_heads_per_row = None if latent else kv.heads_per_row(
             self.cache_dtype, self.use_flash)
-        # prefills that wrote a layer's per-slot state (one count a state
-        # layer and admission): health()["conv_state_prefill_writes"]
-        self.conv_state_prefill_writes = 0
+        # prefills that wrote a layer's per-slot state, by kind (one count
+        # a state layer and admission): health()["<kind>_prefill_writes"]
+        self.state_prefill_writes = dict.fromkeys(state_kinds, 0)
         self._quantized = self.cache_dtype == "int8"
 
         b = self.max_slots
@@ -1420,8 +1422,14 @@ class ServingEngine:
              "compile_counts": self.compile_counts()}
         if self.kv_heads_per_row is not None:
             h["kv_heads_per_row"] = self.kv_heads_per_row
-        if self._state_layers:
-            h["conv_state_prefill_writes"] = self.conv_state_prefill_writes
+        for kind, n in self.state_prefill_writes.items():
+            h[f"{kind}_prefill_writes"] = n
+        if "delta_state" in self.cache_layers:
+            # the recurrent layers' rows the engine holds, in GB
+            h["delta_state_gb"] = sum(
+                a.nbytes for spec, arrays in zip(self.cache_specs,
+                                                 self._pages)
+                if spec.kind == "delta_state" for a in arrays) / 1e9
         if self.aux_counts:
             def named(v):
                 return dict(zip(AUX_COUNTERS, (int(x) for x in v)))
@@ -2164,7 +2172,8 @@ class ServingEngine:
             tok = int(tok)  # host sync: the first token exists NOW
             if aux:
                 self._add_aux("prefill", aux[0])
-            self.conv_state_prefill_writes += self._state_layers
+            for kind in self.state_prefill_writes:
+                self.state_prefill_writes[kind] += self.cache_layers[kind]
         self._m_ttft.observe(time.monotonic() - req.submitted_at)
         # the int(tok) sync above bounds the span at real prefill work
         self.spans.add(f"prefill_{bucket}", t_pre, tid=f"req{req.rid}",

@@ -116,8 +116,10 @@ def test_the_paged_decode_kernel_compiles_at_the_lfm2_cell_s_shape(one_chip):
 
 
 # (kv heads, query heads per kv head, head size): serve-lfm2-closed64's
-# attention layers, and a GPT's heads of 128
-DECODE_LOOPS = {"serve-lfm2-closed64": (8, 4, 64), "gpt_d128": (16, 1, 128)}
+# attention layers, a GPT's heads of 128, and serve-olmohybrid-closed64's
+# 30 heads of 128, every one a K/V head
+DECODE_LOOPS = {"serve-lfm2-closed64": (8, 4, 64), "gpt_d128": (16, 1, 128),
+                "serve-olmohybrid-closed64": (30, 1, 128)}
 
 
 @pytest.mark.parametrize("cell", list(DECODE_LOOPS))
@@ -239,3 +241,82 @@ def test_the_decode_loop_reads_held_weights_in_place(weights, most_mb,
         assert temp < most_mb << 20
     else:
         assert temp > 128 << 20
+
+
+def _gated_delta_layer(one_chip):
+    """Olmo-Hybrid's Gated DeltaNet layer at its published head widths (30
+    heads, dk 96, dv 192, 4 taps over 11,520 channels) with its leaves as
+    shapes of the published hidden size 3840: the layer is built at a
+    hidden size of 30 (nothing of width is made) and handed the full-size
+    leaves as arguments."""
+    from paddle_tpu.nlp import olmo_hybrid as oh
+    cfg = oh.OlmoHybridConfig(hidden_size=30, intermediate_size=8,
+                              num_hidden_layers=1,
+                              layer_types=("linear_attention",),
+                              dtype="bfloat16")
+    layer = oh.OlmoHybridGatedDeltaNet(cfg)
+    full = {"q_proj": (3840, 2880), "k_proj": (3840, 2880),
+            "v_proj": (3840, 5760), "z_proj": (3840, 5760),
+            "a_proj": (3840, 30), "b_proj": (3840, 30), "conv": (4, 11520),
+            "A_log": (30,), "dt_bias": (30,), "o_norm.weight": (192,),
+            "o_proj": (5760, 3840)}
+    names = {n for n, _ in layer.named_parameters()}
+    assert names == set(full)
+    params = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for n, s in full.items()}
+    return layer, params
+
+
+def test_the_delta_state_decode_loop_updates_the_state_in_place(one_chip):
+    """Four Gated DeltaNet layers' decode step in a loop of 8 steps over 64
+    slots, each layer's float32 state (142 MB) and convolution rows carried
+    and donated as the engine's decode scan carries them: the loop reads
+    and writes each state where it lies, so the scratch stays far below
+    one layer's state."""
+    from paddle_tpu.nlp.paged_cache import DeltaStateCache
+    from paddle_tpu.nn.layer import functional_call
+    from paddle_tpu.tensor import Tensor
+    layer, params = _gated_delta_layer(one_chip)
+    b, layers = 64, 4
+
+    def loop(params, states, x, live):
+        def step(states, _):
+            new, outs = [], []
+            for conv, state in states:
+                out, kept = functional_call(
+                    layer, params, {}, Tensor(x),
+                    cache=DeltaStateCache(conv, state, live))
+                outs.append(out._value.sum())
+                new.append(kept)
+            return new, sum(outs)
+        return jax.lax.scan(step, states, None, length=8)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    states = [(sds((b, 3, 11520), jnp.bfloat16),
+               sds((b, 30, 96, 192), jnp.float32))] * layers
+    compiled = jax.jit(loop, donate_argnums=(1,)).lower(
+        params, states, sds((b, 1, 3840), jnp.float32),
+        sds((b,), jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_chunked_prefill_compiles_at_the_longest_bucket(one_chip):
+    """One Gated DeltaNet layer over a prompt of 1024 positions: the
+    chunked scan's triangular solve and products at HIGHEST compile for
+    the chip, with the scratch of a prompt's activations only."""
+    layer, params = _gated_delta_layer(one_chip)
+    from paddle_tpu.nn.layer import functional_call
+    from paddle_tpu.tensor import Tensor
+
+    def prefill(params, x, lens):
+        out, kept = functional_call(layer, params, {}, Tensor(x),
+                                    kv_lens=lens)
+        return out._value, kept.conv, kept.state
+
+    compiled = jax.jit(prefill).lower(
+        params, jax.ShapeDtypeStruct((1, 1024, 3840), jnp.float32,
+                                     sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

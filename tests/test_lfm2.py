@@ -323,14 +323,15 @@ def _tiny(name):
     return cls.from_config_name(name)
 
 
-def _program_digests(model):
+def _program_digests(model, cache_dtype="bfloat16", buckets=(32,), **kw):
     import hashlib
     import re
     eng = ServingEngine(model, max_slots=3, page_size=16, max_seq_len=64,
-                        cache_dtype="bfloat16", prefix_cache=False)
-    eng._prefill_fn(32)
+                        cache_dtype=cache_dtype, prefix_cache=False, **kw)
+    for bucket in buckets:
+        eng._prefill_fn(bucket)
     out = {}
-    for site in ("decode", "prefill_32"):
+    for site in ("decode",) + tuple(f"prefill_{b}" for b in buckets):
         fn, _ = eng._aot_programs[site]
         text = str(jax.make_jaxpr(fn)(*eng._warm_args(site)))
         text = re.sub(r"0x[0-9a-f]+", "0x", text)
@@ -359,6 +360,33 @@ def test_the_accepted_models_answer_and_trace_what_they_did(name):
             or cfg.num_attention_heads, cfg.head_dim)
         assert eng.cache_layers == {"kv": cfg.num_hidden_layers}
     assert eng.cache_specs == [spec] * eng.num_layers
+
+
+# the same digests of another engine: float32 pages, 4 steps a dispatch,
+# the prefill buckets 16 and 64 beside the decode scan; recorded so at
+# commit a599bf9 with `_program_digests(..., cache_dtype="float32",
+# steps_per_dispatch=4, buckets=(16, 64))`
+PARENT_PROGRAMS_F32 = {
+    "gpt-tiny": {"decode": "55082eb1f70a3d63",
+                 "prefill_16": "30abb7ac950b64a3",
+                 "prefill_64": "dd07695b012e6efc"},
+    "llama-tiny": {"decode": "1ae0d444b33d7241",
+                   "prefill_16": "1cc0fd910160c71e",
+                   "prefill_64": "a5f342dbcf7f084e"},
+    "axk1-tiny": {"decode": "7f940db96306cb3a",
+                  "prefill_16": "4a7160d579a4f8c5",
+                  "prefill_64": "048824d10827ee4f"},
+    "lfm2-tiny": {"decode": "49c61182c585d6e9",
+                  "prefill_16": "9c3d56c28ff7d26c",
+                  "prefill_64": "34d8471e0bc9348b"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS_F32))
+def test_the_accepted_models_trace_what_they_did_in_a_float32_engine(name):
+    _, digests = _program_digests(_tiny(name), cache_dtype="float32",
+                                  steps_per_dispatch=4, buckets=(16, 64))
+    assert digests == PARENT_PROGRAMS_F32[name]
 
 
 # the linear-family matrices held where a TPU's products would round them:
