@@ -72,8 +72,9 @@ SIZES = {
                   # (token rows, h, m, experts, picks a token)
                   experts=(64, 2048, 1792, 32, 4),
                   # serve-olmohybrid-closed64's Gated DeltaNet layer over
-                  # one prompt of this many positions
-                  delta_rule_tokens=1024),
+                  # one prompt of this many positions, and its decode
+                  # step over this many slots
+                  delta_rule_tokens=1024, delta_slots=64),
     "kernels": dict(
         flash=[  # (b, s, h, d, causal, kv_lens, dropout)
             (8, 1024, 16, 64, True, False, 0.0),    # gpt3-345M train
@@ -785,7 +786,9 @@ def phase_serve(sz, ctx):
     out["logits_err"] = err
     out["token_agreement"] = round(float(same), 4)
     out["experts_err"] = _experts_paths_agree(sz["experts"], log)
-    out["delta_rule_err"] = _delta_rule_paths_agree(sz["delta_rule_tokens"])
+    out["delta_rule_err"] = _delta_rule_paths_agree(sz["delta_rule_tokens"],
+                                                    log)
+    out["delta_step_err"] = _delta_step_kernel_agrees(sz["delta_slots"], log)
     return out
 
 
@@ -795,19 +798,25 @@ def phase_serve(sz, ctx):
 # positions: 1.2e-6 of the state on the CPU, 1.7e-4 on a v5e. The scan's
 # products on bfloat16-rounded operands are off by 5.8e-3 (on the CPU)
 DELTA_STATE_TOL = 1e-3
+# one decode step, the kernel against gated_delta_step: the same float32
+# operations, the sums over dk in another order
+DELTA_STEP_TOL = 1e-5
 
 
-def _delta_rule_paths_agree(tokens):
+def _delta_rule_paths_agree(tokens, log):
     """Olmo-Hybrid's Gated DeltaNet layer at its published widths (hidden
     3840, 30 heads, dk 96, dv 192), bf16 weights from a seed with the
     decays spread as the configuration's `assumed` draws them: one prompt
     through the chunked prefill against the same prompt a token at a time
-    through the decode step (the recurrence), on the chip. Judged on the
-    state both leave (float32 throughout); the layer's outputs, whose
-    gated norm feeds a bf16 product, are reported beside it."""
+    through the decode step (the recurrence: the `gated_delta_decode`
+    kernel over the state stored as the engine stores it, two heads a
+    row), on the chip. Judged on the state both leave (float32
+    throughout); the layer's outputs, whose gated norm feeds a bf16
+    product, are reported beside it."""
     import numpy as np
     from paddle_tpu.nlp import olmo_hybrid as oh
-    from paddle_tpu.nlp.paged_cache import DeltaStateCache
+    from paddle_tpu.nlp.paged_cache import (DeltaStateCache, DeltaStateSpec,
+                                            delta_heads_unpaired)
     from paddle_tpu.nn.layer import functional_call
     from paddle_tpu.tensor import Tensor
     import paddle_tpu as paddle
@@ -832,14 +841,17 @@ def _delta_rule_paths_agree(tokens):
                                                      jnp.int32))
         return out._value[0], kept.state[0]
 
+    spec = DeltaStateSpec(cfg.conv_channels, cfg.linear_conv_kernel_dim,
+                          heads, cfg.linear_key_head_dim,
+                          cfg.linear_value_head_dim)
+
     @jax.jit
     def recurrent(params, x):
         # the convolution's rows carried in float32, as the prefill
         # holds them (the engine stores them at the cache's width)
         conv = jnp.zeros((1, cfg.linear_conv_kernel_dim - 1,
                           cfg.conv_channels), jnp.float32)
-        state = jnp.zeros((1, heads, cfg.linear_key_head_dim,
-                           cfg.linear_value_head_dim), jnp.float32)
+        state = spec.alloc(None, None, "float32", 1)[1]
 
         def token(carry, xt):
             out, kept = functional_call(layer, params, {},
@@ -847,10 +859,15 @@ def _delta_rule_paths_agree(tokens):
                                         cache=DeltaStateCache(*carry))
             return kept, out._value[0, 0]
         (_, state), outs = jax.lax.scan(token, (conv, state), x[0])
-        return outs, state[0]
+        return outs, delta_heads_unpaired(state, spec.heads_per_row)[0]
 
     got_o, got_s = chunked(params, x)
+    mark = len(log.calls)
     want_o, want_s = recurrent(params, x)
+    _native_only(log.since(mark), "serve[delta_rule]")
+    if [n for n, _ in log.since(mark)] != ["_gated_delta_kernel"]:
+        raise AssertionError(f"serve: the decode step built kernels "
+                             f"{log.since(mark)}, not gated_delta_decode")
     err_s = _nerr(got_s, want_s)
     err_o = _nerr(got_o, want_o)
     print(f"serve: Gated DeltaNet at published widths over {tokens} "
@@ -861,6 +878,59 @@ def _delta_rule_paths_agree(tokens):
         raise AssertionError(f"serve: the chunked scan's state differs "
                              f"from the recurrence's by {err_s}")
     return {"state": err_s, "outputs": err_o}
+
+
+def _delta_step_kernel_agrees(slots, log):
+    """One decode step of Olmo-Hybrid's Gated DeltaNet recurrence at its
+    published widths over `slots` slots, a few of them not live: the
+    `gated_delta_decode` kernel over the state stored two heads a row
+    against `gated_delta_step`, both float32 on the chip, with decays over
+    the model's range and beta across (0, 2). Reports the largest
+    normalised difference of the new state and of the output."""
+    from paddle_tpu.nlp import olmo_hybrid as oh
+    from paddle_tpu.nlp.paged_cache import (DeltaStateSpec,
+                                            delta_heads_paired,
+                                            delta_heads_unpaired)
+    from paddle_tpu.ops.pallas.gated_delta_decode import gated_delta_decode
+    cfg = oh.OlmoHybridConfig()
+    h, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    r = DeltaStateSpec(cfg.conv_channels, 4, h, dk, dv).heads_per_row
+    if oh.gated_delta_path() != "kernel" or r != 2:
+        raise AssertionError(f"serve: the decode step at published widths "
+                             f"takes {oh.gated_delta_path()!r} with {r} "
+                             f"heads a row")
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+
+    def unit(key, shape):
+        x = jax.random.normal(key, shape, jnp.float32)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+    q = unit(ks[0], (slots, h, dk)) * dk ** -0.5
+    k = unit(ks[1], (slots, h, dk))
+    v = jax.random.normal(ks[2], (slots, h, dv), jnp.float32)
+    g = -jnp.exp(jax.random.uniform(ks[3], (slots, h), jnp.float32,
+                                    jnp.log(1e-4), jnp.log(8.0)))
+    beta = jax.random.uniform(ks[4], (slots, h), jnp.float32, 0.0, 2.0)
+    state = 0.5 * jax.random.normal(ks[5], (slots, h, dk, dv), jnp.float32)
+    live = jnp.arange(slots) % 9 != 4
+    want_o, want_s = jax.jit(oh.gated_delta_step)(q, k, v, g, beta, state,
+                                                  live)
+    mark = len(log.calls)
+    got_o, got_s = jax.jit(gated_delta_decode)(
+        q, k, v, g, beta, delta_heads_paired(state, r), live)
+    _native_only(log.since(mark), "serve[delta_step]")
+    got_s = delta_heads_unpaired(got_s, r)
+    err = {"state": _nerr(got_s, want_s), "outputs": _nerr(got_o, want_o)}
+    kept = bool(jnp.array_equal(got_s[~live], state[~live]))
+    print(f"serve: Gated DeltaNet decode step at published widths over "
+          f"{slots} slots, gated_delta_decode vs gated_delta_step: state "
+          f"normalised max error {err['state']:.3e}, outputs "
+          f"{err['outputs']:.3e} (tol {DELTA_STEP_TOL}); dead slots' rows "
+          f"kept: {kept}", flush=True)
+    if not (max(err.values()) <= DELTA_STEP_TOL and kept):
+        raise AssertionError(f"serve: the decode kernel differs from the "
+                             f"step by {err}, dead rows kept {kept}")
+    return err
 
 
 def _experts_paths_agree(shape, log):

@@ -29,10 +29,14 @@ configuration file's `assumed` list:
   A prompt runs the chunkwise form (`chunked_gated_delta`): chunks of 64
   positions, the WY/UT transform within a chunk (one unit-lower
   triangular solve), a `lax.scan` over the chunks for the state. A
-  decode step runs the recurrence on each slot's row (`gated_delta_step`).
+  decode step runs the recurrence on each slot's row: on a TPU one Pallas
+  kernel that reads and writes each slot's state once
+  (ops/pallas/gated_delta_decode.py), elsewhere `gated_delta_step`, the
+  written specification the kernel is held to (`gated_delta_path`).
   What a sequence carries from token to token is the convolution's last
   `taps - 1` inputs and S, one fixed-size state per serving slot and no
-  pages (`paged_cache.DeltaStateSpec`).
+  pages, stored with two heads side by side in a row where dv = 192
+  (`paged_cache.DeltaStateSpec`).
 
 Weights are stored in `dtype`; products with them take operands in that
 dtype and accumulate in float32; the residual stream, norms, softmax and
@@ -55,10 +59,12 @@ from .axk1 import _mm, _param, _swiglu
 from .llama import _repeat_kv
 from .paged_cache import (DeltaStateCache, DeltaStateRows, DeltaStateSpec,
                           KVCacheSpec, PagedLayerCache, conv_state_at,
-                          paged_update_and_attend)
+                          delta_heads_paired, delta_heads_unpaired,
+                          paged_update_and_attend, record_delta_path)
 
 __all__ = ["OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM",
-           "OLMO_HYBRID_CONFIGS", "chunked_gated_delta", "gated_delta_step"]
+           "OLMO_HYBRID_CONFIGS", "chunked_gated_delta", "gated_delta_step",
+           "gated_delta_path"]
 
 _PUBLISHED_LAYERS = tuple(
     "full_attention" if i % 4 == 3 else "linear_attention"
@@ -246,6 +252,28 @@ def gated_delta_step(q, k, v, g, beta, state, live=None):
     return o, new
 
 
+def gated_delta_path():
+    """How a decode step runs: "kernel" (ops/pallas/gated_delta_decode.py)
+    where Pallas kernels compile natively, else "jnp" (`gated_delta_step`,
+    on the same storage)."""
+    from ..ops.pallas import _common
+    return "jnp" if _common.interpret_default() else "kernel"
+
+
+def _decode_step(q, k, v, g, beta, state, live):
+    """One token per slot over the stored state [B, H / r, dk, r * dv]
+    (r read off its width): (o [B, H, dv], the new state as stored)."""
+    r = state.shape[-1] // v.shape[-1]
+    path = gated_delta_path()
+    record_delta_path(path, r)
+    if path == "kernel":
+        from ..ops.pallas.gated_delta_decode import gated_delta_decode
+        return gated_delta_decode(q, k, v, g, beta, state, live)
+    o, new = gated_delta_step(q, k, v, g, beta,
+                              delta_heads_unpaired(state, r), live)
+    return o, delta_heads_paired(new, r)
+
+
 class OlmoHybridGatedDeltaNet(Layer):
     def __init__(self, cfg: OlmoHybridConfig):
         super().__init__()
@@ -328,8 +356,8 @@ class OlmoHybridGatedDeltaNet(Layer):
                     new_conv = jnp.where(cache.live[:, None, None],
                                          new_conv, old)
                 q, k, v, g, beta = self._heads(conv, a[:, 0], b[:, 0])
-                o, state = gated_delta_step(q, k, v, g, beta, cache.state,
-                                            cache.live)
+                o, state = _decode_step(q, k, v, g, beta, cache.state,
+                                        cache.live)
                 o = o[:, None]
                 kept = (new_conv, state)
             o = self._gated_norm(o, z)

@@ -36,7 +36,9 @@ the rows every head shares and no value pool (`LatentCacheSpec`,
 size per serving slot, `[slots, taps, C]`, overwritten in place as the
 slot's sequence grows (`ConvStateSpec`, `ConvStateCache`: the short
 convolutions of nlp/lfm2.py, beside that model's K/V layers), or such a
-state and beside it a float32 matrix `[slots, heads, dk, dv]` a slot
+state and beside it a float32 matrix `[slots, heads, dk, dv]` a slot,
+stored with r heads side by side in a row where a row of one head would
+not fill whole lane tiles: `[slots, heads / r, dk, r * dv]`
 (`DeltaStateSpec`, `DeltaStateCache`: the Gated DeltaNet layers of
 nlp/olmo_hybrid.py). Page table,
 trash page, positions and the engine's page accounting are the same for
@@ -75,6 +77,7 @@ updates the rows of live slots only.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import math
 
@@ -88,7 +91,9 @@ __all__ = ["PagedLayerCache", "PagedLatentCache", "ConvStateCache",
            "ConvStateRows", "DeltaStateRows", "AUX_COUNTERS",
            "cache_spec_of", "cache_specs_of", "conv_state_step",
            "conv_state_at", "write_prompt_state",
-           "write_prompt_delta_state",
+           "write_prompt_delta_state", "delta_heads_paired",
+           "delta_heads_unpaired", "recorded_delta_paths",
+           "record_delta_path",
            "PrefixIndex", "alloc_pages",
            "prefix_fingerprints", "quantize_rows", "write_token_latent",
            "write_prompt_latent", "latent_paged_attention",
@@ -218,9 +223,10 @@ class ConvStateCache:
 class DeltaStateCache:
     """One Gated DeltaNet layer's view of its state in the decode step:
     `conv` [B, taps - 1, C], the last inputs of the layer's causal
-    convolution (the newest last), `state` [B, H, dk, dv] float32, the
-    recurrence's matrix, and `live` [B] bool as ConvStateCache's. Not a
-    pytree."""
+    convolution (the newest last), `state` [B, H / r, dk, r * dv] float32,
+    the recurrence's matrix with r heads side by side in a row
+    (`DeltaStateSpec.heads_per_row`), and `live` [B] bool as
+    ConvStateCache's. Not a pytree."""
 
     __slots__ = ("conv", "state", "live", "aux")
 
@@ -371,11 +377,13 @@ class ConvStateSpec:
 class DeltaStateSpec:
     """A Gated DeltaNet layer's cache: no pages, per serving slot the
     convolution's last `taps - 1` inputs `[max_slots, taps - 1, channels]`
-    in the engine's cache dtype, and the recurrence's state `[max_slots,
-    heads, key_dim, value_dim]` in float32 whatever the cache dtype: the
-    state is summed into at every token, and bfloat16 would round each
-    sum (no int8 form). A prefill writes both rows of the slot it admits,
-    as ConvStateSpec's; the decode step updates the rows of live slots."""
+    in the engine's cache dtype, and the recurrence's state in float32
+    whatever the cache dtype: the state is summed into at every token, and
+    bfloat16 would round each sum (no int8 form). The state is stored
+    `[max_slots, heads / r, key_dim, r * value_dim]`, r heads side by side
+    in a row (`heads_per_row`). A prefill writes both rows of the slot it
+    admits, as ConvStateSpec's; the decode step updates the rows of live
+    slots."""
 
     kind, latent, paged = "delta_state", False, False
 
@@ -383,13 +391,23 @@ class DeltaStateSpec:
         self.channels, self.taps = int(channels), int(taps)
         self.heads, self.key_dim, self.value_dim = \
             int(heads), int(key_dim), int(value_dim)
+        # r, the heads a row of the state holds side by side: two where a
+        # row of one head is not whole lane tiles and a row of two is
+        # (dv = 192: rows of 384, where one head's row of 192 float32
+        # would occupy 256 lanes, a third more than it holds), else 1. The
+        # decode kernel reads and writes a slot's rows where they lie
+        # (ops/pallas/gated_delta_decode.py)
+        dv = self.value_dim
+        self.heads_per_row = 2 if (dv % _LANES and not 2 * dv % _LANES
+                                   and not self.heads % 2) else 1
 
     def alloc(self, num_pages, page_size, cache_dtype, max_slots=None,
               use_flash=False):
+        r = self.heads_per_row
         return (jnp.zeros((max_slots, self.taps - 1, self.channels),
                           jnp.dtype(cache_dtype)),
-                jnp.zeros((max_slots, self.heads, self.key_dim,
-                           self.value_dim), jnp.float32))
+                jnp.zeros((max_slots, self.heads // r, self.key_dim,
+                           r * self.value_dim), jnp.float32))
 
     def view(self, arrays, page_table, positions, use_flash=False,
              live=None):
@@ -597,14 +615,58 @@ def write_prompt_state(state, rows, slot):
     return state.at[slot].set(rows[0].astype(state.dtype), mode="drop")
 
 
+def delta_heads_paired(state, r):
+    """[..., H, dk, dv] -> [..., H / r, dk, r * dv]: row i of a group holds
+    row i of heads `r*g .. r*g + r - 1` side by side (DeltaStateSpec's
+    layout)."""
+    if r == 1:
+        return state
+    *lead, h, dk, dv = state.shape
+    x = state.reshape(*lead, h // r, r, dk, dv)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, h // r, dk, r * dv)
+
+
+def delta_heads_unpaired(state, r):
+    """The inverse of `delta_heads_paired`."""
+    if r == 1:
+        return state
+    *lead, rows, dk, w = state.shape
+    x = state.reshape(*lead, rows, dk, r, w // r)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, rows * r, dk, w // r)
+
+
 @jax.named_scope("delta_state_write")
 def write_prompt_delta_state(conv, state, conv_rows, state_rows, slot):
     """Prefill write of one prompt's rows ([1, taps - 1, C] and [1, H, dk,
-    dv]) into the slot it is admitted to, whatever was there; a `slot`
-    past the last one (the engine's warm-up) writes nothing."""
+    dv]) into the slot it is admitted to, whatever was there, the state's
+    heads laid out as the stored state holds them (r read off its width);
+    a `slot` past the last one (the engine's warm-up) writes nothing."""
+    r = state.shape[-1] // state_rows.shape[-1]
     return (conv.at[slot].set(conv_rows[0].astype(conv.dtype), mode="drop"),
-            state.at[slot].set(state_rows[0].astype(state.dtype),
-                               mode="drop"))
+            state.at[slot].set(delta_heads_paired(state_rows[0], r)
+                               .astype(state.dtype), mode="drop"))
+
+
+# the lists that `recorded_delta_paths` blocks open on this process
+_delta_recorders = []
+
+
+@contextlib.contextmanager
+def recorded_delta_paths():
+    """Collects, while a program is traced inside it, what each Gated
+    DeltaNet decode step took: a list of ("kernel" | "jnp", heads a row of
+    the state)."""
+    seen = []
+    _delta_recorders.append(seen)
+    try:
+        yield seen
+    finally:
+        _delta_recorders.remove(seen)
+
+
+def record_delta_path(path, heads_per_row):
+    for seen in _delta_recorders:
+        seen.append((path, int(heads_per_row)))
 
 
 def _to_width(x, width):

@@ -267,17 +267,52 @@ def _gated_delta_layer(one_chip):
     return layer, params
 
 
-def test_the_delta_state_decode_loop_updates_the_state_in_place(one_chip):
+def test_gated_delta_decode_compiles_at_the_cell_s_widths(one_chip):
+    """`serve-olmohybrid-closed64`'s state: 64 slots of 30 heads of
+    96 x 192, two heads a row of 384; one kernel, the state aliased to the
+    new state and no scratch beside it."""
+    from paddle_tpu.ops.pallas import gated_delta_decode as gdd
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, h = 64, 30
+    compiled = jax.jit(lambda *a: gdd.gated_delta_decode(
+        *a, interpret=False), donate_argnums=(5,)).lower(
+            sds((b, h, 96)), sds((b, h, 96)), sds((b, h, 192)),
+            sds((b, h)), sds((b, h)), sds((b, 15, 96, 384)),
+            sds((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    kernel = [ln for ln in text.splitlines() if "%gated_delta_decode" in ln
+              and "custom-call(" in ln]
+    assert len(kernel) == 1 and "output_to_operand_aliasing" in kernel[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_delta_state_decode_loop_updates_the_state_in_place(
+        one_chip, monkeypatch):
     """Four Gated DeltaNet layers' decode step in a loop of 8 steps over 64
-    slots, each layer's float32 state (142 MB) and convolution rows carried
-    and donated as the engine's decode scan carries them: the loop reads
-    and writes each state where it lies, so the scratch stays far below
-    one layer's state."""
-    from paddle_tpu.nlp.paged_cache import DeltaStateCache
+    slots, each layer's float32 state (142 MB, two heads a row of 384 as
+    the engine stores it) and convolution rows carried and donated as the
+    engine's decode scan carries them, through the kernel as on a TPU: one
+    `gated_delta_decode` a layer, under the `gated_delta` scope, reads and
+    writes each state where it lies. No fusion or copy makes a whole
+    state, so the scratch stays far below one layer's state."""
+    from paddle_tpu.nlp import olmo_hybrid as oh
+    from paddle_tpu.nlp.paged_cache import DeltaStateCache, DeltaStateSpec
     from paddle_tpu.nn.layer import functional_call
+    from paddle_tpu.observability.introspect import parse_scopes
+    from paddle_tpu.ops.pallas import _common
     from paddle_tpu.tensor import Tensor
+    # what a TPU backend answers: the kernel, compiled natively
+    monkeypatch.setattr(_common, "interpret_default", lambda: False)
+    assert oh.gated_delta_path() == "kernel"
     layer, params = _gated_delta_layer(one_chip)
     b, layers = 64, 4
+    state_shape = jax.eval_shape(lambda: DeltaStateSpec(
+        11520, 4, 30, 96, 192).alloc(None, None, "bfloat16", b)[1]).shape
+    assert state_shape == (b, 15, 96, 384)
 
     def loop(params, states, x, live):
         def step(states, _):
@@ -295,11 +330,23 @@ def test_the_delta_state_decode_loop_updates_the_state_in_place(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     states = [(sds((b, 3, 11520), jnp.bfloat16),
-               sds((b, 30, 96, 192), jnp.float32))] * layers
+               sds(state_shape, jnp.float32))] * layers
     compiled = jax.jit(loop, donate_argnums=(1,)).lower(
         params, states, sds((b, 1, 3840), jnp.float32),
         sds((b,), jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "%gated_delta_decode" in ln]
+    assert len(kernels) == layers
+    scopes = parse_scopes(text)
+    for ln in kernels:
+        name = ln.split("=", 1)[0].split()[-1].lstrip("%")
+        assert "gated_delta" in scopes[name].split("/")
+    whole = "f32[" + ",".join(map(str, state_shape)) + "]"
+    made = [ln for ln in text.splitlines() for op in (" fusion(", " copy(")
+            if op in ln and whole in ln.split("=", 1)[1].split(op, 1)[0]]
+    assert made == []
 
 
 def test_the_chunked_prefill_compiles_at_the_longest_bucket(one_chip):

@@ -22,11 +22,11 @@ from paddle_tpu.observability.trace import (  # noqa: E402
 
 # the package re-exports functions under its modules' names
 (_common, conv_bn_act, flash_attention, flash_decode, fused_adamw,
- fused_ln, latent_decode, grouped_experts) = (
+ fused_ln, latent_decode, grouped_experts, gated_delta_decode) = (
     importlib.import_module("paddle_tpu.ops.pallas." + m)
     for m in ("_common", "conv_bn_act", "flash_attention", "flash_decode",
               "fused_adamw", "fused_ln", "latent_decode",
-              "grouped_experts"))
+              "grouped_experts", "gated_delta_decode"))
 
 
 # -- programs ----------------------------------------------------------------
@@ -102,6 +102,14 @@ def _grouped_experts():
         interpret=True)), (x, w_gate_up, w_down)
 
 
+def _gated_delta():
+    q = jnp.ones((3, 4, 16), jnp.float32)
+    state = jnp.ones((3, 2, 16, 128), jnp.float32)
+    return (lambda q, s: gated_delta_decode.gated_delta_decode(
+        q, q, jnp.ones((3, 4, 64)), -jnp.ones((3, 4)), jnp.ones((3, 4)), s,
+        interpret=True)), (q, state)
+
+
 def _ln(entry, grad):
     x = jnp.ones((4, 32, 64), jnp.float32)
     g = jnp.ones((64,), jnp.float32)
@@ -136,6 +144,7 @@ KERNEL_ENTRIES = [
     ("paged_flash_decode", _paged_decode, {"flash_decode"}),
     ("latent_flash_decode", _latent_decode, {"latent_decode"}),
     ("grouped_experts", _grouped_experts, {"grouped_experts"}),
+    ("gated_delta_decode", _gated_delta, {"gated_delta_decode"}),
     ("fused_add_ln", lambda: _ln(fused_ln.fused_add_layer_norm, False),
      {"fused_add_ln_fwd"}),
     ("fused_add_ln_grad", lambda: _ln(fused_ln.fused_add_layer_norm, True),
@@ -172,7 +181,7 @@ def test_kernel_names_are_unique_over_the_call_sites():
     # backward's one site is called under its two names
     shared = [n for n in set(names) if names.count(n) > 1]
     assert shared == ["flash_fwd"] and names.count("flash_fwd") == 2
-    assert sites == 13 and len(names) == 14 and len(set(names)) == 13
+    assert sites == 14 and len(names) == 15 and len(set(names)) == 14
 
 
 def test_a_kernel_without_a_name_is_an_error():

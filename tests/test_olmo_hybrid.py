@@ -289,7 +289,34 @@ def test_bfloat16_weights_and_a_float32_state():
     assert gap < 0.5
     conv, state = eng._pages[0]
     assert conv.shape == (3, 3, 128) and conv.dtype == jnp.bfloat16
+    # two heads of 32 fill no lane tile together: one head a row
     assert state.shape == (3, 2, 16, 32) and state.dtype == jnp.float32
+    assert eng.health()["delta_state"] == {"path": "jnp", "heads_per_row": 1}
+
+
+def _slot_reused_by_two_requests(model):
+    """One slot: a prompt then twenty decode steps, then a second request
+    through the same slot; (tokens of both, health())."""
+    eng = ServingEngine(model, cache_dtype="float32",
+                        **dict(ENGINE, max_slots=1))
+    served = [_serve(eng, [(p, 21)])[0] for p in _prompts(11, 37, 13)]
+    return served, eng.health()
+
+
+def test_the_decode_kernel_serves_the_tokens_of_the_jnp_step(monkeypatch):
+    """Value heads of 64, so that the state is stored two heads a row of
+    128 (`DeltaStateSpec.heads_per_row`); the decode program built with
+    the kernel (interpreted here) against the one with `gated_delta_step`
+    over the same storage."""
+    model, w, cfg = _model(linear_value_head_dim=64)
+    plain, h = _slot_reused_by_two_requests(model)
+    assert h["delta_state"] == {"path": "jnp", "heads_per_row": 2}
+    monkeypatch.setattr(olmo_hybrid, "gated_delta_path", lambda: "kernel")
+    kernel, h = _slot_reused_by_two_requests(model)
+    assert h["delta_state"] == {"path": "kernel", "heads_per_row": 2}
+    assert kernel == plain and [len(t) for t in kernel] == [21, 21]
+    requests = [(p, 21) for p in _prompts(11, 37, 13)]
+    assert _worst_gap(w, cfg, requests, kernel) < F32_GAP
 
 
 def test_the_paged_kernel_at_head_size_128_agrees_with_the_reference():
@@ -374,3 +401,7 @@ def test_the_configuration_file_counts_its_parameters():
     assert (st.channels, st.taps, st.heads, st.key_dim, st.value_dim) == \
         (11520, 4, 30, 96, 192)
     assert 64 * 30 * 96 * 192 * 4 * 12 == 1_698_693_120   # 1.70 GB
+    # stored two heads a row of 384: three whole lane tiles, no padding
+    assert st.heads_per_row == 2
+    assert jax.eval_shape(lambda: st.alloc(None, None, "bfloat16", 64)[1]
+                          ).shape == (64, 15, 96, 384)
